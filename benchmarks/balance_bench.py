@@ -49,7 +49,7 @@ def main() -> int:
         args.block_size, args.n_cols = 16, 32
 
     from repro.runtime.platform import set_host_device_count
-    set_host_device_count(DEVICES, overlap=True)
+    set_host_device_count(DEVICES)
     import jax.numpy as jnp  # noqa: E402  (after flag setup)
     import numpy as np
 

@@ -250,10 +250,43 @@ def run_json(smoke: bool = False) -> Dict:
     }
 
 
-def main(smoke: bool = False):
+def _obs_smoke() -> bool:
+    """Traced obs pass for ``--smoke``: exports a Chrome trace,
+    schema-validates it, reports per-algorithm drift ratios, and checks
+    that tracing disabled leaves per-multiply timings within noise."""
+    import os
+    import tempfile
+    with tempfile.NamedTemporaryFile(suffix=".json", delete=False) as tf:
+        trace_path = tf.name
+    try:
+        sec = obs_drift_section(smoke=True, trace_path=trace_path)
+    finally:
+        os.unlink(trace_path)
+    ok = sec["trace_valid"] and sec["disabled_overhead_ok"] \
+        and bool(sec["drift"])
+    ratios = ";".join(f"{a}={d['ratio']:.1f}"
+                      for a, d in sorted(sec["drift"].items()))
+    print(f"smoke,obs_trace,{'ok' if ok else 'FAILED'};"
+          f"events={sec['trace_events']};{ratios}")
+    return ok
+
+
+def main(argv=None) -> int:
+    """``--json`` prints one JSON object (the ``kernels`` and
+    ``obs_drift`` sections of BENCH_kernels.json); otherwise CSV rows,
+    plus the traced obs check under ``--smoke``."""
+    import json
+    import sys
+    argv = sys.argv[1:] if argv is None else argv
+    smoke = "--smoke" in argv
+    if "--json" in argv:
+        print(json.dumps({"kernels": run_json(smoke=smoke),
+                          "obs_drift": obs_drift_section(smoke=smoke)}))
+        return 0
     for name, val, unit in run(smoke=smoke):
         print(f"{name},{val:.1f},{unit}")
+    return 0 if not smoke or _obs_smoke() else 1
 
 
 if __name__ == "__main__":
-    main()
+    raise SystemExit(main())

@@ -1,8 +1,9 @@
 """Benchmark harness — one module per paper table/figure.
 
-Prints ``name,value,unit`` CSV.  Figures needing multiple device counts are
-run in subprocesses (jax locks the host device count at first init); the
-rest run in-process.
+Prints ``name,value,unit`` CSV.  Every experiment runs in a subprocess
+with its own device count (jax locks the host device count at first
+init); this parent process never touches jax, so on a host with a chip
+no child finds the chip already held.
 
   PYTHONPATH=src python -m benchmarks.run            # everything
   PYTHONPATH=src python -m benchmarks.run fig1 fig2  # subset
@@ -41,7 +42,7 @@ REPO_ROOT = os.path.abspath(os.path.join(os.path.dirname(__file__), ".."))
 
 def _subprocess_env(devices: int) -> dict:
     from repro.runtime.platform import subprocess_env
-    env = subprocess_env(devices, overlap=True)
+    env = subprocess_env(devices)
     src = os.path.join(REPO_ROOT, "src")
     env["PYTHONPATH"] = src + os.pathsep + env.get("PYTHONPATH", "")
     return env
@@ -108,19 +109,16 @@ def _machine_fit_section(payload: dict) -> dict:
 
 
 def _write_json(smoke: bool) -> None:
-    from benchmarks import kernels_bench
     # "smoke" marks reduced-scale payloads so trajectory comparisons never
     # mistake a quick CI pass for the full baseline.
-    payload = {"smoke": smoke,
-               "kernels": kernels_bench.run_json(smoke=smoke)}
-    # The balance, spgemm and steal experiments configure 16 fake devices
-    # before importing jax, so each runs in its own process printing one
-    # JSON object.
+    payload = {"smoke": smoke}
     extra = ("--smoke",) if smoke else ()
     all_ok = True
-    # serve_bench drives the single-device serving engine; the rest are
-    # 16-device grid experiments.
+    # kernels_bench prints the "kernels" and "obs_drift" sections in one
+    # object; serve_bench drives the single-device serving engine; the
+    # rest are 16-device grid experiments printing one section each.
     for module, section, devices in (
+            ("benchmarks.kernels_bench", None, 1),
             ("benchmarks.balance_bench", "balance_rmat_4x4", 16),
             ("benchmarks.spgemm_bench", "spgemm_rmat_4x4", 16),
             ("benchmarks.steal_bench", "steal_rmat_4x4", 16),
@@ -129,21 +127,17 @@ def _write_json(smoke: bool) -> None:
             ("benchmarks.analysis_bench", "analysis", 16),
             ("benchmarks.elastic_bench", "elastic", 9),
             ("benchmarks.serve_bench", "serve_trace", 1)):
-        raw = _run_subprocess(module, devices, *extra, quiet=True)
+        args = ("--json",) + extra if section is None else extra
+        raw = _run_subprocess(module, devices, *args, quiet=True)
         try:
-            payload[section] = json.loads(raw) if raw else {
-                "error": f"{module} failed"}
-        except json.JSONDecodeError as e:
-            payload[section] = {"error": f"unparseable output: {e}"}
-            raw = ""   # degrade like the empty-output case (exit 1 below)
+            result = json.loads(raw) if raw else {}
+        except json.JSONDecodeError:
+            result, raw = {}, ""   # degrade like empty output (exit 1 below)
+        sections = result if section is None else {section: result}
+        for key in (("kernels", "obs_drift") if section is None
+                    else (section,)):
+            payload[key] = sections.get(key) or {"error": f"{module} failed"}
         all_ok = all_ok and bool(raw)
-    # traced pass: per-algorithm predicted-vs-measured drift ratios from
-    # the live obs registry + Chrome-trace schema check (in-process, g=1)
-    try:
-        payload["obs_drift"] = kernels_bench.obs_drift_section(smoke=smoke)
-    except Exception as e:                     # noqa: BLE001 (diagnostic)
-        payload["obs_drift"] = {"error": f"{type(e).__name__}: {e}"}
-        all_ok = False
     # every baseline refresh re-fits the cost model's network constants
     # from its own records and records the drift
     payload["machine_fit"] = _machine_fit_section(payload)
@@ -183,12 +177,10 @@ def main() -> None:
         return
     if smoke:
         # Quick self-contained pass for tools/run_tier1.sh: kernel oracle
-        # rows + one scale-8 balance check + one scale-9 sparse-output
-        # spgemm check + one scale-8 steal3d check, no multi-minute figure
-        # sweeps.
-        from benchmarks import kernels_bench
-        kernels_bench.main(smoke=True)
-        ok = True
+        # rows + traced obs check (kernels_bench), one scale-8 balance
+        # check, one scale-9 sparse-output spgemm check, one scale-8
+        # steal3d check, no multi-minute figure sweeps.
+        ok = bool(_run_subprocess("benchmarks.kernels_bench", 1, "--smoke"))
         # analysis_bench asserts the static verifier finds nothing on
         # healthy plans and validate="fast" stays under the 5% cached
         # plan-build overhead budget;
@@ -213,25 +205,6 @@ def main() -> None:
             name = module.rsplit(".", 1)[1]
             print(f"smoke,{name},{'ok' if raw else 'FAILED'}")
             ok = ok and bool(raw)
-        # traced obs pass: exports a Chrome trace, schema-validates it,
-        # reports per-algorithm drift ratios, and asserts tracing
-        # disabled leaves per-multiply timings within noise of untraced
-        import tempfile
-        from benchmarks import kernels_bench as kb
-        with tempfile.NamedTemporaryFile(suffix=".json",
-                                         delete=False) as tf:
-            trace_path = tf.name
-        try:
-            sec = kb.obs_drift_section(smoke=True, trace_path=trace_path)
-            obs_ok = sec["trace_valid"] and sec["disabled_overhead_ok"] \
-                and bool(sec["drift"])
-            ratios = ";".join(f"{a}={d['ratio']:.1f}"
-                              for a, d in sorted(sec["drift"].items()))
-            print(f"smoke,obs_trace,{'ok' if obs_ok else 'FAILED'};"
-                  f"events={sec['trace_events']};{ratios}")
-        finally:
-            os.unlink(trace_path)
-        ok = ok and obs_ok
         # exercise the machine-fit wiring against the committed baseline
         # (a full refresh re-fits from its own fresh records)
         baseline = os.path.join(REPO_ROOT, "BENCH_kernels.json")
@@ -249,25 +222,16 @@ def main() -> None:
         return
     which = which or {"fig1", "fig2", "fig34", "fig5", "table2", "kernels"}
     # fall through: full figure sweep (optionally filtered by name)
-    if "fig1" in which:
-        from benchmarks import fig1_load_imbalance
-        fig1_load_imbalance.main()
-    if "fig2" in which:
-        from benchmarks import fig2_roofline
-        fig2_roofline.main()
-    if "fig34" in which:
-        for p in SCALING_DEVICE_COUNTS:
-            _run_subprocess("benchmarks.fig34_spmm_scaling", p)
-    if "fig5" in which:
-        for p in SCALING_DEVICE_COUNTS:
-            _run_subprocess("benchmarks.fig5_spgemm_scaling", p)
-    if "table2" in which:
-        from benchmarks import table2_breakdown
-        table2_breakdown.main()
-    if "kernels" in which:
-        from benchmarks import kernels_bench
-        kernels_bench.main()
-
+    for name, module in (("fig1", "benchmarks.fig1_load_imbalance"),
+                         ("fig2", "benchmarks.fig2_roofline"),
+                         ("fig34", "benchmarks.fig34_spmm_scaling"),
+                         ("fig5", "benchmarks.fig5_spgemm_scaling"),
+                         ("table2", "benchmarks.table2_breakdown"),
+                         ("kernels", "benchmarks.kernels_bench")):
+        if name in which:
+            for p in (SCALING_DEVICE_COUNTS if name in ("fig34", "fig5")
+                      else (1,)):
+                _run_subprocess(module, p)
 
 if __name__ == "__main__":
     main()

@@ -53,7 +53,7 @@ def main() -> int:
         args.scale, args.repeats = 9, 2
 
     from repro.runtime.platform import set_host_device_count
-    set_host_device_count(DEVICES, overlap=True)
+    set_host_device_count(DEVICES)
     import numpy as np
 
     from repro.core import api

@@ -48,7 +48,7 @@ COMPUTE_PRIMS = ("dot_general", "pallas_call", "conv_general_dilated")
 # ---------------------------------------------------------------------------
 def subjaxprs(v) -> Iterator:
     """Yield every Jaxpr reachable from an eqn-param value."""
-    from jax import core as jcore
+    from jax.extend import core as jcore
     if isinstance(v, jcore.ClosedJaxpr):
         yield v.jaxpr
     elif isinstance(v, jcore.Jaxpr):
@@ -224,7 +224,7 @@ def check_collective_count(plan, jaxpr) -> List[Finding]:
 def check_overlap_carry(plan, jaxpr) -> List[Finding]:
     if not plan.geom.overlap:
         return []
-    from jax import core as jcore
+    from jax.extend import core as jcore
     findings = []
     for scan in scan_eqns(jaxpr):
         body = scan.params["jaxpr"].jaxpr

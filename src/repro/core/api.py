@@ -85,7 +85,6 @@ from jax import lax
 from jax.sharding import PartitionSpec as P
 
 from .. import obs as _obs
-from ..compat import pvary, shard_map
 from ..kernels import ops as kops
 from ..kernels import ref as kref
 from . import roofline as _roofline
@@ -186,7 +185,7 @@ def _tree_bcast(tree: Dict, axis: str, root, my_idx) -> Dict:
 
 
 def _pvary(x, geom: _Geom):
-    return pvary(x, (geom.axr, geom.axc))
+    return lax.pcast(x, (geom.axr, geom.axc), to="varying")
 
 
 # ---------------------------------------------------------------------------
@@ -1379,6 +1378,15 @@ def _body_steal3d(a, b, aux, geom: _Geom, splan: "_steal3d.StealPlan"):
 # ---------------------------------------------------------------------------
 # Distributed-matrix handles
 # ---------------------------------------------------------------------------
+def _canonical_placement(placement: str, g: int) -> str:
+    """On a 1x1 grid every placement holds the one tile where it is, so
+    all placements share the natural tree (no copy of the operand)."""
+    if placement not in PLACEMENTS:
+        raise ValueError(
+            f"unknown placement {placement!r}; one of {PLACEMENTS}")
+    return NATURAL if g == 1 else placement
+
+
 def _place_bsr(t: TiledBSR, placement: str) -> TiledBSR:
     if placement == NATURAL:
         return t
@@ -1438,7 +1446,15 @@ class DistMatrix:
         s = self.shape
         return s[0] // self.g, s[1] // self.g
 
-    def placed(self, placement: str) -> Dict[str, jnp.ndarray]:
+    def placed(self, placement: str, commit: Optional[Callable] = None
+               ) -> Dict[str, jnp.ndarray]:
+        """The operand tree for ``placement``, cached on the handle.
+
+        ``commit`` (tree -> tree) replaces the cached tree with its
+        result, once: plans pass their mesh placement, so the operand
+        then lives sharded over the devices instead of on the default
+        device, and later calls move no operand data.
+        """
         raise NotImplementedError
 
     def abstract_key(self) -> tuple:
@@ -1610,15 +1626,19 @@ class DistBSR(DistMatrix):
             self._packed_operand = po
         return po
 
-    def packed_wire(self, placement: str) -> Dict[str, jnp.ndarray]:
+    def packed_wire(self, placement: str,
+                    commit: Optional[Callable] = None
+                    ) -> Dict[str, jnp.ndarray]:
         """Packed wire blocks for a placement: ``{"blocks": [g, g, wc,
         bs, bs]}`` — each tile's real blocks gathered into the packed
         prefix, trailing slots guaranteed zero.  Cached per placement,
-        like :meth:`placed` (one gather per handle x placement lifetime).
+        like :meth:`placed` (one gather per handle x placement lifetime),
+        and ``commit`` as there.
         """
         cache = getattr(self, "_packed_placed", None)
         if cache is None:
             cache = self._packed_placed = {}
+        placement = _canonical_placement(placement, self.g)
         tree = cache.get(placement)
         if tree is None:
             po = self.packed_operand()
@@ -1630,6 +1650,8 @@ class DistBSR(DistMatrix):
             jj = jnp.arange(g)[None, :, None]
             tree = {"blocks": placed[ii, jj, jnp.asarray(pidx)]}
             cache[placement] = tree
+        if commit is not None:
+            tree = cache[placement] = commit(tree)
         return tree
 
     def densify(self) -> jnp.ndarray:
@@ -1655,12 +1677,16 @@ class DistBSR(DistMatrix):
         return int(t.blocks.nbytes + t.rows.nbytes + t.cols.nbytes
                    + t.counts.nbytes)
 
-    def placed(self, placement: str) -> Dict[str, jnp.ndarray]:
+    def placed(self, placement: str, commit: Optional[Callable] = None
+               ) -> Dict[str, jnp.ndarray]:
+        placement = _canonical_placement(placement, self.g)
         tree = self._placed.get(placement)
         if tree is None:
             t = _place_bsr(self.tiled, placement)
             tree = {"blocks": t.blocks, "rows": t.rows, "cols": t.cols}
             self._placed[placement] = tree
+        if commit is not None:
+            tree = self._placed[placement] = commit(tree)
         return tree
 
     def abstract_key(self) -> tuple:
@@ -1742,11 +1768,15 @@ class DistDense(DistMatrix):
     def dtype(self):
         return self.data.dtype
 
-    def placed(self, placement: str) -> Dict[str, jnp.ndarray]:
+    def placed(self, placement: str, commit: Optional[Callable] = None
+               ) -> Dict[str, jnp.ndarray]:
+        placement = _canonical_placement(placement, self._g)
         tree = self._placed.get(placement)
         if tree is None:
             tree = {"dense": _place_dense(self.data, self._g, placement)}
             self._placed[placement] = tree
+        if commit is not None:
+            tree = self._placed[placement] = commit(tree)
         return tree
 
     def abstract_key(self) -> tuple:
@@ -2272,7 +2302,7 @@ class MatmulPlan:
             with jax.named_scope(scope_label):
                 return inner_fn(*operands)
 
-        self._exec = jax.jit(shard_map(
+        self._exec = jax.jit(jax.shard_map(
             fn, mesh=mesh,
             in_specs=in_specs,
             out_specs=out_specs,
@@ -2336,6 +2366,8 @@ class MatmulPlan:
                 f"(plan: {self._a_key} @ {self._b_key}, got "
                 f"{a_h.abstract_key()} @ {b_h.abstract_key()}); build a new "
                 "plan with plan_matmul")
+        on_mesh = self._on_mesh
+        pl_a, pl_b = self.algorithm.a_placement, self.algorithm.b_placement
         if self.steal is not None:
             if self._a_key[0] == "bsr":
                 if a_h.structure_key() != self.steal.a_fingerprint:
@@ -2345,14 +2377,12 @@ class MatmulPlan:
                         "lists are specialized to the structure); build a "
                         "new plan with plan_matmul")
                 if self.steal.wire == "packed":
-                    a_tree = a_h.packed_wire(self.algorithm.a_placement)
+                    a_tree = a_h.packed_wire(pl_a, on_mesh)
                 else:
-                    a_tree = {"blocks": a_h.placed(
-                        self.algorithm.a_placement)["blocks"]}
+                    a_tree = {"blocks": a_h.placed(pl_a, on_mesh)["blocks"]}
             else:
-                a_tree = a_h.placed(self.algorithm.a_placement)
-            return (a_tree, b_h.placed(self.algorithm.b_placement),
-                    self._aux)
+                a_tree = a_h.placed(pl_a, on_mesh)
+            return (a_tree, b_h.placed(pl_b, on_mesh), self._aux)
         packed = self.wire == "packed"
         if self.symbolic is not None:
             sym = self.symbolic
@@ -2362,12 +2392,10 @@ class MatmulPlan:
                     "operands' sparsity structure does not match this "
                     "sparse-output plan (pair lists are specialized to the "
                     "structure); build a new plan with plan_matmul")
-            pl_a, pl_b = self.algorithm.a_placement, \
-                self.algorithm.b_placement
-            a_tree = a_h.packed_wire(pl_a) if packed \
-                else {"blocks": a_h.placed(pl_a)["blocks"]}
-            b_tree = b_h.packed_wire(pl_b) if packed \
-                else {"blocks": b_h.placed(pl_b)["blocks"]}
+            a_tree = a_h.packed_wire(pl_a, on_mesh) if packed \
+                else {"blocks": a_h.placed(pl_a, on_mesh)["blocks"]}
+            b_tree = b_h.packed_wire(pl_b, on_mesh) if packed \
+                else {"blocks": b_h.placed(pl_b, on_mesh)["blocks"]}
             return (a_tree, b_tree, self._pairs)
         if packed:
             for who, h in (("a", a_h), ("b", b_h)):
@@ -2378,15 +2406,31 @@ class MatmulPlan:
                         "sparsity structure does not match this packed-wire "
                         "plan (the consume maps are specialized to the "
                         "structure); build a new plan with plan_matmul")
-            a_tree = a_h.packed_wire(self.algorithm.a_placement) \
-                if "a" in self._packs \
-                else a_h.placed(self.algorithm.a_placement)
-            b_tree = b_h.packed_wire(self.algorithm.b_placement) \
-                if "b" in self._packs \
-                else b_h.placed(self.algorithm.b_placement)
+            a_tree = a_h.packed_wire(pl_a, on_mesh) if "a" in self._packs \
+                else a_h.placed(pl_a, on_mesh)
+            b_tree = b_h.packed_wire(pl_b, on_mesh) if "b" in self._packs \
+                else b_h.placed(pl_b, on_mesh)
             return (a_tree, b_tree, self._aux)
-        return (a_h.placed(self.algorithm.a_placement),
-                b_h.placed(self.algorithm.b_placement))
+        return (a_h.placed(pl_a, on_mesh), b_h.placed(pl_b, on_mesh))
+
+    def _on_mesh(self, tree: Dict[str, jnp.ndarray]) -> Dict[str, jnp.ndarray]:
+        """An operand tree committed to this plan's mesh: tile (i, j) on
+        device (i, j), the layout the executable's in_specs ask for."""
+        want = {k: jax.sharding.NamedSharding(
+                    self.mesh,
+                    P(self.geom.axr, self.geom.axc, *(None,) * (v.ndim - 2)))
+                for k, v in tree.items()}
+        if all(v.sharding.is_equivalent_to(want[k], v.ndim)
+               for k, v in tree.items()):
+            return tree
+        return jax.device_put(tree, want)
+
+    def lower(self, a, b):
+        """The plan's executable lowered for these operands (``.compile()``
+        it for ``as_text()`` / ``memory_analysis()``); traces if needed."""
+        a_h, b_h = _coerce_pair(a, b, g=self.geom.g,
+                                allow_pad=self._allow_pad)
+        return self._exec.lower(*self._operands(a_h, b_h))
 
     def _execute(self, a, b):
         a_h, b_h = _coerce_pair(a, b, g=self.geom.g,

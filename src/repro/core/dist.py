@@ -13,10 +13,10 @@ from __future__ import annotations
 
 from typing import Tuple
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ..compat import make_mesh
 from .bsr import TiledBSR
 
 __all__ = [
@@ -26,8 +26,9 @@ __all__ = [
 
 
 def make_grid_mesh(g: int, axis_row: str = "row", axis_col: str = "col"):
-    """A g x g device mesh with Auto axis types (stable across jax versions)."""
-    return make_mesh((g, g), (axis_row, axis_col))
+    """A g x g device mesh with Auto axis types."""
+    return jax.make_mesh((g, g), (axis_row, axis_col),
+                         axis_types=(jax.sharding.AxisType.Auto,) * 2)
 
 
 def tileize(x: jnp.ndarray, g: int) -> jnp.ndarray:

@@ -23,7 +23,7 @@ from typing import Dict
 
 __all__ = [
     "Machine", "SUMMIT_V100", "DGX2_V100", "TPU_V5E", "HOST_CPU",
-    "save_machine", "load_machine",
+    "MACHINES_BY_DEVICE_KIND", "machine_for", "save_machine", "load_machine",
     "spmm_local_ai", "spmm_internode_ai", "spgemm_local_ai",
     "spgemm_internode_ai", "local_peak", "internode_roofline",
     "spmm_model", "spgemm_model",
@@ -66,6 +66,22 @@ TPU_V5E = Machine("tpu-v5e", 197e12, 819e9, 50e9, 2)
 # work-stealing schedule's flop saving decides (on the net-bound nominal
 # v5e constants, shipping extra tiles to steal work can never pay).
 HOST_CPU = Machine("host-cpu", 5e10, 2e10, 2e10, 4, hop_latency=2e-5)
+
+# The one table from a device's ``device_kind`` (as JAX reports it) to the
+# cost model's constants.  TPU v5e reports "TPU v5 lite"; its peaks are
+# Google Cloud's published v5e figures (197 TFLOP/s bf16, 819 GB/s HBM).
+MACHINES_BY_DEVICE_KIND: Dict[str, Machine] = {"TPU v5 lite": TPU_V5E}
+
+
+def machine_for(device_kind: str) -> Machine:
+    """The :class:`Machine` for a device kind; unknown kinds are an error,
+    never a silent default."""
+    try:
+        return MACHINES_BY_DEVICE_KIND[device_kind]
+    except KeyError:
+        raise ValueError(
+            f"no cost-model constants for device kind {device_kind!r}; "
+            f"known kinds: {sorted(MACHINES_BY_DEVICE_KIND)}") from None
 
 
 def save_machine(m: Machine, path: str) -> None:

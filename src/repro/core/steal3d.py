@@ -22,7 +22,9 @@ data the ``steal3d`` algorithm body (``repro.core.api``) consumes:
   list (A pool block, B pool row-chunk, output slot) in the style of the
   symbolic-phase machinery (slot-sorted, coverage pair per slot, inert
   zero-block padding to the uniform capacity — the LPT makespan is the
-  list length, so skew shrinks executed work instead of padding it);
+  list length, so skew shrinks executed work instead of padding it —
+  and the chunked layout of ``symbolic.chunk_pair_lists`` past the
+  kernel's SMEM budget);
 * **reduce rounds** — partial C tiles computed off-owner ride static
   ``ppermute`` rounds back to their owners, who accumulate them before
   the shared unskew/crop epilogue.
@@ -40,11 +42,12 @@ from typing import Dict, Optional, Tuple
 import numpy as np
 
 from .. import obs as _obs
+from ..kernels.bsr_spmm import list_chunk
 from . import roofline as _roofline
 from . import wire as _wire
 from .grid import bucket_capacity
 from .schedule import Assignment3D, assign_3d_lpt
-from .symbolic import extract_structure
+from .symbolic import chunk_pair_lists, extract_structure
 
 __all__ = ["StealPlan", "build_steal_plan", "validate_assignment"]
 
@@ -501,17 +504,11 @@ def build_steal_plan(a_h, b_h, geom, *, locality: str = "locality",
                 order = np.argsort(ps, kind="stable")
                 pa, pb, ps = pa[order], pb[order], ps[order]
             per_dev_pairs.append((pa, pb, ps))
-        cap = bucket_capacity(max(len(p[0]) for p in per_dev_pairs))
-        pa_arr = np.full((g, g, cap), z_a, dtype=np.int32)
-        pb_arr = np.zeros((g, g, cap), dtype=np.int32)
-        ps_arr = np.full((g, g, cap), n_slots - 1, dtype=np.int32)
-        for d, (pa, pb, ps) in enumerate(per_dev_pairs):
-            r, c = divmod(d, g)
-            n = len(pa)
-            pa_arr[r, c, :n] = pa
-            pb_arr[r, c, :n] = pb
-            ps_arr[r, c, :n] = ps
-        return cap, pa_arr, pb_arr, ps_arr
+        # inert padding: the zero A block against B chunk 0
+        pa_arr, pb_arr, ps_arr = (
+            arr.reshape(g, g, -1) for arr in chunk_pair_lists(
+                per_dev_pairs, [(z_a, 0)] * n_dev, list_chunk(2)))
+        return pa_arr.shape[2], pa_arr, pb_arr, ps_arr
 
     pair_cap, pa_arr, pb_arr, ps_arr = _pair_arrays(items, zero_a)
     if overlap:

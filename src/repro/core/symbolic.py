@@ -41,6 +41,7 @@ from typing import Callable, Dict, Optional, Tuple
 
 import numpy as np
 
+from ..kernels.bsr_spmm import list_chunk
 from ..kernels.ops import match_block_pairs
 from .bsr import TiledBSR
 from .grid import bucket_capacity
@@ -48,6 +49,7 @@ from .grid import bucket_capacity
 __all__ = [
     "GridStructure", "SymbolicProduct", "extract_structure",
     "structure_fingerprint", "predicted_density", "symbolic_spgemm",
+    "chunk_pair_lists",
 ]
 
 
@@ -117,7 +119,9 @@ class SymbolicProduct:
     :meth:`scheduled_pairs`).  Each list is sorted by output slot
     (nondecreasing, the kernel's first-visit-zeroing contract), contains
     one coverage pair per output slot, and is padded with inert pairs
-    (both operands' zero slots, repeating the last output slot).
+    (both operands' zero slots, repeating the last output slot).  Lists
+    longer than the kernel's SMEM budget are in the chunked layout of
+    :func:`chunk_pair_lists`.
     """
     g: int
     block_size: int
@@ -291,7 +295,6 @@ def symbolic_spgemm(a: TiledBSR, b: TiledBSR,
     c_cols = np.zeros((g, g, store), dtype=np.int32)
     c_real = np.zeros((g, g, store), dtype=bool)
     raw_pairs: Dict[Tuple[int, int, int], tuple] = {}
-    max_pairs = 0
     for i in range(g):
         for j in range(g):
             rr, cc = real_rc[i, j]
@@ -328,24 +331,16 @@ def symbolic_spgemm(a: TiledBSR, b: TiledBSR,
                     np.concatenate([pa, np.full(store, za)])[merge],
                     np.concatenate([pb, np.full(store, zb)])[merge],
                     ps_all[merge], len(pa))
-                max_pairs = max(max_pairs, len(pa) + store)
 
-    pair_cap = bucket_capacity(max_pairs)
-    pair_a = np.zeros((g, g, g, pair_cap), dtype=np.int32)
-    pair_b = np.zeros((g, g, g, pair_cap), dtype=np.int32)
-    pair_slot = np.zeros((g, g, g, pair_cap), dtype=np.int32)
-    n_real = np.zeros((g, g, g), dtype=np.int64)
-    for (i, j, k), (pa, pb, ps, nr) in raw_pairs.items():
-        n = len(pa)
-        pair_a[i, j, k, :n] = pa
-        pair_b[i, j, k, :n] = pb
-        pair_slot[i, j, k, :n] = ps
-        # inert padding: zero slots of both operands, last output slot
-        # (keeps pair_slot nondecreasing)
-        pair_a[i, j, k, n:] = sa.zero_slot[i, k]
-        pair_b[i, j, k, n:] = sb.zero_slot[k, j]
-        pair_slot[i, j, k, n:] = store - 1
-        n_real[i, j, k] = nr
+    keys = [(i, j, k) for i in range(g) for j in range(g) for k in range(g)]
+    # inert padding: zero slots of both operands
+    pair_a, pair_b, pair_slot = (
+        arr.reshape(g, g, g, -1) for arr in chunk_pair_lists(
+            [raw_pairs[key][:3] for key in keys],
+            [(sa.zero_slot[i, k], sb.zero_slot[k, j]) for i, j, k in keys],
+            list_chunk(3)))
+    n_real = np.array([raw_pairs[key][3] for key in keys],
+                      dtype=np.int64).reshape(g, g, g)
 
     return SymbolicProduct(
         g=g, block_size=bs, tile_nbr=nbr, tile_nbc=nbc,
@@ -355,3 +350,79 @@ def symbolic_spgemm(a: TiledBSR, b: TiledBSR,
         pair_a=pair_a, pair_b=pair_b, pair_slot=pair_slot,
         n_real_pairs=n_real,
         a_fingerprint=sa.fingerprint, b_fingerprint=sb.fingerprint)
+
+
+def _split_at_slots(ps: np.ndarray, n_chunks: int, chunk: int):
+    """Cut a slot-sorted list into exactly ``n_chunks`` non-empty pieces
+    of at most ``chunk`` entries, only where the slot changes.
+
+    Greedy longest-first fill, then the piece holding the most entries
+    is halved (at its slot boundary nearest the middle) until the count
+    is reached.  Returns the cut positions, ``[0, ..., len(ps)]``.
+    """
+    bounds = np.flatnonzero(np.r_[True, ps[1:] != ps[:-1], True])
+    cuts = [0]
+    while cuts[-1] < len(ps):
+        nxt = bounds[np.searchsorted(bounds, cuts[-1] + chunk,
+                                     side="right") - 1]
+        if nxt == cuts[-1]:
+            raise ValueError(
+                f"output block {int(ps[nxt])} has more than {chunk} pairs, "
+                "the SMEM budget of one kernel call; use a larger grid")
+        cuts.append(int(nxt))
+    while len(cuts) - 1 < n_chunks:
+        inner = [(cuts[t + 1] - cuts[t], t) for t in range(len(cuts) - 1)
+                 if np.searchsorted(bounds, cuts[t], side="right")
+                 < np.searchsorted(bounds, cuts[t + 1], side="left")]
+        if not inner:
+            raise ValueError(f"cannot cut {len(ps)} pairs into {n_chunks} "
+                             "chunks at output-block boundaries")
+        _, t = max(inner)
+        mid = (cuts[t] + cuts[t + 1]) / 2
+        lo = np.searchsorted(bounds, cuts[t], side="right")
+        hi = np.searchsorted(bounds, cuts[t + 1], side="left")
+        cand = bounds[lo:hi]
+        cuts.insert(t + 1, int(cand[np.argmin(np.abs(cand - mid))]))
+    return cuts
+
+
+def chunk_pair_lists(lists, pads, chunk: int):
+    """Pad slot-sorted pair lists to one uniform length for the kernels.
+
+    ``lists`` holds one ``(pair_a, pair_b, pair_slot)`` triple per list,
+    each sorted by slot (nondecreasing); ``pads`` one ``(a, b)`` pair of
+    inert operand indices (zero blocks) per list.  Returns three int32
+    arrays ``[len(lists), L]``.
+
+    Where every list fits in ``chunk`` entries (the SMEM budget of one
+    kernel call), ``L`` is the longest list rounded up to a 1.25x bucket
+    (capped at ``chunk``) and each list is padded at its end.  Otherwise
+    each list is cut, only where its slot changes, into the same number of
+    chunks of exactly ``chunk`` entries, each padded with inert pairs that
+    repeat the chunk's last slot: the *chunked layout* of
+    ``repro.kernels.bsr_spmm``.  No slot then spans two chunks, so the
+    kernel calls of one product never re-zero each other's output blocks.
+    Either way every list stays slot-sorted.
+    """
+    longest = max((len(ps) for _, _, ps in lists), default=0)
+    if longest <= chunk:
+        length = min(bucket_capacity(longest), chunk)
+        cuts = [[0, len(ps)] for _, _, ps in lists]
+        width = length
+    else:
+        greedy = [len(_split_at_slots(ps, 1, chunk)) - 1
+                  for _, _, ps in lists]
+        n_chunks = max(greedy)
+        cuts = [_split_at_slots(ps, n_chunks, chunk) for _, _, ps in lists]
+        length, width = n_chunks * chunk, chunk
+    out = np.zeros((3, len(lists), length), dtype=np.int32)
+    for row, ((pa, pb, ps), (za, zb), cut) in enumerate(
+            zip(lists, pads, cuts)):
+        for t in range(len(cut) - 1):
+            lo, hi = cut[t], cut[t + 1]
+            dst = out[:, row, t * width:(t + 1) * width]
+            dst[0, :hi - lo], dst[0, hi - lo:] = pa[lo:hi], za
+            dst[1, :hi - lo], dst[1, hi - lo:] = pb[lo:hi], zb
+            dst[2, :hi - lo] = ps[lo:hi]
+            dst[2, hi - lo:] = ps[hi - 1] if hi > lo else 0
+    return out[0], out[1], out[2]

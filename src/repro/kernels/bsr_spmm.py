@@ -17,6 +17,17 @@ Three kernels:
   pre-matched pairs accumulated into *packed* output block slots (the
   symbolic phase's capacity-bounded layout), never materializing a dense C
   tile.
+
+Scalar-prefetch lists live in SMEM, which holds 1 MiB on a TPU v5e, so
+one ``pallas_call`` takes at most :func:`list_chunk` entries per list.
+Longer lists arrive in the *chunked layout*: consecutive chunks of
+exactly ``list_chunk(n)`` entries, each starting at a new output block
+and padded with inert entries that repeat its last output block.  The
+plan-time list builders (``core.symbolic``, ``core.steal3d``) lay lists
+out this way; the SpMM and pair-accumulate kernels run one
+``pallas_call`` per chunk, all writing into one aliased output buffer.
+Because no output block spans two chunks, no chunk re-zeroes a block
+that another chunk wrote.
 """
 from __future__ import annotations
 
@@ -24,17 +35,63 @@ import functools
 
 import jax
 import jax.numpy as jnp
+from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-__all__ = ["bsr_spmm_pallas", "bsr_pair_matmul_pallas",
-           "bsr_pair_accumulate_pallas"]
+__all__ = ["SMEM_LIST_BYTES", "list_chunk", "bsr_spmm_pallas",
+           "bsr_pair_matmul_pallas", "bsr_pair_accumulate_pallas"]
+
+# Bytes of int32 scalar-prefetch lists one pallas_call may hold in SMEM
+# (1 MiB on v5e); the rest is left to the kernel's own scalars.
+SMEM_LIST_BYTES = 512 * 1024
+
+
+def list_chunk(n_lists: int) -> int:
+    """Most entries per chunk when ``n_lists`` int32 lists share SMEM."""
+    return SMEM_LIST_BYTES // (4 * n_lists)
+
+
+def _chunked(call, lists, chunk: int):
+    """Run ``call(chunk_lists, offset, out)`` over the chunked layout.
+
+    ``offset`` is the chunk's first list position.  The first chunk
+    allocates the output (``out=None``); every later chunk aliases it, so
+    all chunks write into one buffer.
+    """
+    total = lists[0].shape[0]
+    if total <= chunk:
+        return call(lists, 0, None)
+    if total % chunk:
+        raise ValueError(
+            f"list of {total} entries exceeds the SMEM budget of {chunk} "
+            "entries per kernel call and is not in the chunked layout (a "
+            f"multiple of {chunk}, each chunk starting at a new output "
+            "block); build it with repro.core.symbolic.chunk_pair_lists")
+    out = call(tuple(x[:chunk] for x in lists), 0, None)
+
+    def body(c, out):
+        off = c * chunk
+        return call(tuple(lax.dynamic_slice_in_dim(x, off, chunk)
+                          for x in lists), off, out)
+
+    return lax.fori_loop(1, total // chunk, body, out)
+
+
+def _with_out(in_specs, operands, out, n_prefetch: int):
+    """Append the aliased output buffer (left in HBM, never read) to a
+    call's inputs; returns ``(in_specs, operands, input_output_aliases)``."""
+    if out is None:
+        return in_specs, operands, {}
+    return (in_specs + [pl.BlockSpec(memory_space=pl.ANY)],
+            operands + [out], {n_prefetch + len(in_specs): 0})
 
 
 # ---------------------------------------------------------------------------
-# SpMM: C[rows[s]] += A_blocks[s] @ B[cols[s], :]
+# SpMM: C[rows[s]] += A_blocks[off + s] @ B[cols[s], :]
 # ---------------------------------------------------------------------------
-def _spmm_kernel(rows_ref, cols_ref, a_ref, b_ref, c_ref):
+def _spmm_kernel(off_ref, rows_ref, cols_ref, a_ref, b_ref, *refs):
+    c_ref = refs[-1]                  # refs[0] is the aliased input, if any
     s = pl.program_id(1)  # stored-block step (innermost)
     prev = rows_ref[jnp.maximum(s - 1, 0)]
     is_first = jnp.logical_or(s == 0, rows_ref[s] != prev)
@@ -50,10 +107,11 @@ def _spmm_kernel(rows_ref, cols_ref, a_ref, b_ref, c_ref):
 
 @functools.partial(
     jax.jit,
-    static_argnames=("n_block_rows", "block_n", "interpret"),
+    static_argnames=("n_block_rows", "block_n", "chunked", "interpret"),
 )
 def bsr_spmm_pallas(blocks, rows, cols, dense, *, n_block_rows: int,
-                    block_n: int = 256, interpret: bool = False):
+                    block_n: int = 256, chunked: bool = False,
+                    interpret: bool = False):
     """C = BSR @ dense via pallas_call.
 
     blocks : f[cap, bs, bs] — zero-padded stored blocks, ``rows`` sorted
@@ -63,29 +121,44 @@ def bsr_spmm_pallas(blocks, rows, cols, dense, *, n_block_rows: int,
                  ``ops.bsr_spmm_raw(augment=True)`` establishes this per
                  call; ``TiledBSR`` stores tiles pre-augmented.
     dense  : f[n_block_cols*bs, n] with n % block_n == 0
+    chunked : the lists are in the chunked layout (module docstring);
+              without it, lists longer than ``list_chunk(2)`` are refused.
     """
     cap, bs, _ = blocks.shape
     n = dense.shape[1]
     if n % block_n:
         raise ValueError(f"n={n} not a multiple of block_n={block_n}")
     nj = n // block_n
+    chunk = list_chunk(2)
+    if cap > chunk and not chunked:
+        raise ValueError(
+            f"{cap} stored blocks exceed the SMEM budget of {chunk} list "
+            "entries per kernel call, and a tile's stored lists are not in "
+            "the chunked layout; use a larger grid")
 
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,        # rows, cols
-        grid=(nj, cap),               # cap innermost => consecutive row visits
-        in_specs=[
-            pl.BlockSpec((1, bs, bs), lambda j, s, rows, cols: (s, 0, 0)),
-            pl.BlockSpec((bs, block_n), lambda j, s, rows, cols: (cols[s], j)),
-        ],
-        out_specs=pl.BlockSpec(
-            (bs, block_n), lambda j, s, rows, cols: (rows[s], j)),
-    )
-    out = pl.pallas_call(
-        _spmm_kernel,
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((n_block_rows * bs, n), jnp.float32),
-        interpret=interpret,
-    )(rows, cols, blocks, dense)
+    def call(lists, off, out):
+        rows_c, cols_c = lists
+        in_specs, operands, aliases = _with_out(
+            [pl.BlockSpec((1, bs, bs),
+                          lambda j, s, o, r, c: (o[0] + s, 0, 0)),
+             pl.BlockSpec((bs, block_n), lambda j, s, o, r, c: (c[s], j))],
+            [blocks, dense], out, 3)
+        return pl.pallas_call(
+            _spmm_kernel,
+            grid_spec=pltpu.PrefetchScalarGridSpec(
+                num_scalar_prefetch=3,    # chunk offset, rows, cols
+                # steps innermost => consecutive row visits
+                grid=(nj, rows_c.shape[0]),
+                in_specs=in_specs,
+                out_specs=pl.BlockSpec(
+                    (bs, block_n), lambda j, s, o, r, c: (r[s], j))),
+            out_shape=jax.ShapeDtypeStruct((n_block_rows * bs, n),
+                                           jnp.float32),
+            input_output_aliases=aliases,
+            interpret=interpret,
+        )(jnp.full((1,), off, jnp.int32), rows_c, cols_c, *operands)
+
+    out = _chunked(call, (rows, cols), chunk)
     return out.astype(jnp.promote_types(blocks.dtype, dense.dtype))
 
 
@@ -145,7 +218,8 @@ def bsr_pair_matmul_pallas(a_blocks, b_blocks, pair_a, pair_b, pair_rows,
 # ---------------------------------------------------------------------------
 # Sparse-output SpGEMM inner: C_blocks[ps[s]] += A_blocks[pa[s]] @ B_blocks[pb[s]]
 # ---------------------------------------------------------------------------
-def _pair_acc_kernel(pa_ref, pb_ref, ps_ref, a_ref, b_ref, c_ref):
+def _pair_acc_kernel(pa_ref, pb_ref, ps_ref, a_ref, b_ref, *refs):
+    c_ref = refs[-1]                  # refs[0] is the aliased input, if any
     s = pl.program_id(0)
     prev = ps_ref[jnp.maximum(s - 1, 0)]
     is_first = jnp.logical_or(s == 0, ps_ref[s] != prev)
@@ -171,24 +245,28 @@ def bsr_pair_accumulate_pallas(a_blocks, b_blocks, pair_a, pair_b, pair_slot,
                 phase emits one coverage pair per slot), because an output
                 block is zeroed on its first visit only.
     Padding pairs must reference zero blocks and repeat the final slot.
+    Lists longer than ``list_chunk(3)`` must be in the chunked layout
+    (module docstring).
     Returns f32[n_slots, bs, bs]; the caller casts to the output dtype.
     """
-    npairs = pair_a.shape[0]
     bs = a_blocks.shape[1]
 
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=3,        # pair_a, pair_b, pair_slot
-        grid=(npairs,),
-        in_specs=[
-            pl.BlockSpec((1, bs, bs), lambda s, pa, pb, ps: (pa[s], 0, 0)),
-            pl.BlockSpec((1, bs, bs), lambda s, pa, pb, ps: (pb[s], 0, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, bs, bs),
-                               lambda s, pa, pb, ps: (ps[s], 0, 0)),
-    )
-    return pl.pallas_call(
-        _pair_acc_kernel,
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((n_slots, bs, bs), jnp.float32),
-        interpret=interpret,
-    )(pair_a, pair_b, pair_slot, a_blocks, b_blocks)
+    def call(lists, off, out):
+        in_specs, operands, aliases = _with_out(
+            [pl.BlockSpec((1, bs, bs), lambda s, pa, pb, ps: (pa[s], 0, 0)),
+             pl.BlockSpec((1, bs, bs), lambda s, pa, pb, ps: (pb[s], 0, 0))],
+            [a_blocks, b_blocks], out, 3)
+        return pl.pallas_call(
+            _pair_acc_kernel,
+            grid_spec=pltpu.PrefetchScalarGridSpec(
+                num_scalar_prefetch=3,    # pair_a, pair_b, pair_slot
+                grid=(lists[0].shape[0],),
+                in_specs=in_specs,
+                out_specs=pl.BlockSpec((1, bs, bs),
+                                       lambda s, pa, pb, ps: (ps[s], 0, 0))),
+            out_shape=jax.ShapeDtypeStruct((n_slots, bs, bs), jnp.float32),
+            input_output_aliases=aliases,
+            interpret=interpret,
+        )(*lists, *operands)
+
+    return _chunked(call, (pair_a, pair_b, pair_slot), list_chunk(3))

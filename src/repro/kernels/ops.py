@@ -40,7 +40,7 @@ def _resolve(impl: Optional[str]) -> str:
 # ---------------------------------------------------------------------------
 def bsr_spmm_raw(blocks, rows, cols, dense, *, n_block_rows: int,
                  impl: Optional[str] = None, block_n: int = 256,
-                 augment: bool = True):
+                 augment: bool = True, chunked: bool = False):
     """C = BSR(blocks, rows, cols) @ dense — raw-array form (shard_map-safe).
 
     ``augment=False`` asserts the caller's arrays are already
@@ -48,6 +48,8 @@ def bsr_spmm_raw(blocks, rows, cols, dense, *, n_block_rows: int,
     the :class:`repro.core.bsr.TiledBSR` storage contract), skipping the
     concat + stable-argsort below.  The distributed ring bodies rely on
     this: augmentation must not be re-traced into every scanned step.
+    ``chunked=True`` asserts the lists are in the kernels' chunked layout
+    (``repro.kernels.bsr_spmm``), as plan-built pair lists are.
     """
     impl = _resolve(impl)
     bs = blocks.shape[1]
@@ -76,7 +78,7 @@ def bsr_spmm_raw(blocks, rows, cols, dense, *, n_block_rows: int,
         rows = rows_aug[order]
     return bsr_spmm_pallas(blocks, rows, cols, dense,
                            n_block_rows=n_block_rows, block_n=max(bn, 1),
-                           interpret=(impl == "interpret"))
+                           chunked=chunked, interpret=(impl == "interpret"))
 
 
 def bsr_spmm(a_bsr, dense, *, impl: Optional[str] = None, block_n: int = 256):
@@ -234,11 +236,11 @@ def steal_pair_accumulate(a_pool, b_rows, pair_a, pair_b, pair_slot, *,
     tile's stored structure, so every impl path (ref / interpret / pallas)
     is reused unchanged.  Contract: ``pair_slot`` nondecreasing, every
     slot visited at least once (coverage pairs), dummy pairs reference the
-    zero block.
+    zero block, and lists past the SMEM budget in the chunked layout.
     """
     return bsr_spmm_raw(a_pool[pair_a], pair_slot, pair_b, b_rows,
                         n_block_rows=n_slots, impl=impl, block_n=block_n,
-                        augment=False)
+                        augment=False, chunked=True)
 
 
 def densify(blocks, rows, cols, *, n_block_rows: int, n_block_cols: int):

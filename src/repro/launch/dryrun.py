@@ -145,8 +145,9 @@ def run_cell(arch: str, shape_name: str, mesh_kind: str,
         import jax as _jax
         shape_ = tuple(int(x) for x in debug_mesh.split(","))
         axes_ = ("pod", "data", "model")[-len(shape_):]
-        from repro.compat import make_mesh as _make_mesh
-        mesh = _make_mesh(shape_, axes_)
+        mesh = _jax.make_mesh(
+            shape_, axes_,
+            axis_types=(_jax.sharding.AxisType.Auto,) * len(axes_))
     else:
         mesh = mesh_mod.make_production_mesh(multi_pod=(mesh_kind == "multi"))
     n_chips = mesh.size
@@ -156,11 +157,9 @@ def run_cell(arch: str, shape_name: str, mesh_kind: str,
                             is_leaf=lambda x: isinstance(
                                 x, jax.ShapeDtypeStruct))
 
-    from repro.compat import set_mesh
-
     t0 = time.time()
     try:
-        with set_mesh(mesh):
+        with jax.sharding.set_mesh(mesh):
             if shape.kind == "train":
                 opt = AdamW(lr=1e-4)
                 opt_specs = AdamW.state_specs(pspecs)

@@ -10,27 +10,17 @@ from typing import Optional, Tuple
 import jax
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-from ..compat import make_mesh
-from ..runtime import platform as _platform
 
 __all__ = ["make_production_mesh", "filter_spec", "shardings_for",
            "batch_partition_spec"]
 
 
-def make_production_mesh(*, multi_pod: bool = False, overlap: bool = True):
-    """Build the production device mesh.
-
-    ``overlap=True`` (default) first plants the async-collective /
-    latency-hiding XLA flags through ``repro.runtime.platform`` — the
-    runtime half of the split-step double-buffered schedule bodies.
-    Safe mid-process: skipped silently once a jax backend has
-    initialized (flags could no longer take effect).
-    """
-    if overlap and not _platform.jax_initialized():
-        _platform.set_platform(overlap=True)
+def make_production_mesh(*, multi_pod: bool = False):
+    """Build the production device mesh (Auto axis types)."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return make_mesh(shape, axes)
+    return jax.make_mesh(shape, axes,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def filter_spec(spec: P, mesh) -> P:

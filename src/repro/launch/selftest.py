@@ -34,8 +34,10 @@ def _parse():
 
 def main() -> int:
     args = _parse()
-    from repro.runtime.platform import set_host_device_count
-    set_host_device_count(args.devices, overlap=True)
+    from repro.runtime.platform import (enable_compile_cache,
+                                        set_host_device_count)
+    set_host_device_count(args.devices)
+    enable_compile_cache()
     import jax  # noqa: E402  (after flag setup)
     import jax.numpy as jnp
     import numpy as np

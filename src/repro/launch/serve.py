@@ -61,6 +61,8 @@ def main(argv=None) -> int:
                         "ui.perfetto.dev; summarize with "
                         "tools/trace_view.py)")
     args = p.parse_args(argv)
+    from repro.runtime.platform import enable_compile_cache
+    enable_compile_cache()
 
     from repro import obs
     from repro.configs import get_config

@@ -21,12 +21,12 @@ import numpy as np
 
 def build_mesh(n_devices: Optional[int] = None):
     import jax
-    from repro.compat import make_mesh
     from repro.runtime.elastic import choose_mesh_shape
 
     n = n_devices or len(jax.devices())
     data, model = choose_mesh_shape(n, max_model=16)
-    return make_mesh((data, model), ("data", "model"))
+    return jax.make_mesh((data, model), ("data", "model"),
+                         axis_types=(jax.sharding.AxisType.Auto,) * 2)
 
 
 def train(cfg, *, steps: int, batch: int, seq: int, lr: float = 3e-4,
@@ -48,7 +48,6 @@ def train(cfg, *, steps: int, batch: int, seq: int, lr: float = 3e-4,
     from repro.optim import AdamW, cosine_schedule
     from repro.runtime import PreemptionSignal, RestartableLoop, StragglerDetector
 
-    from repro.compat import set_mesh
     from repro.launch.mesh import sanitized_shardings
 
     mesh = mesh or build_mesh()
@@ -61,7 +60,7 @@ def train(cfg, *, steps: int, batch: int, seq: int, lr: float = 3e-4,
         AdamW.state_specs(pspecs),
         jax.eval_shape(opt.init, abstract), mesh)
 
-    with set_mesh(mesh):
+    with jax.sharding.set_mesh(mesh):
         params = jax.jit(
             lambda k: tf.init_params(cfg, k),
             out_shardings=param_sh)(jax.random.PRNGKey(seed))
@@ -142,7 +141,6 @@ def selftest_parallel_equivalence(n_devices: int) -> bool:
     import jax
     import jax.numpy as jnp
 
-    from repro.compat import make_mesh, set_mesh
     from repro.configs import get_config
     from repro.data.pipeline import SyntheticLM
     from repro.launch.mesh import shardings_for
@@ -155,8 +153,9 @@ def selftest_parallel_equivalence(n_devices: int) -> bool:
     loss_ref, _ = lm.loss_fn(params, batch, cfg)
 
     data = max(1, n_devices // 2)
-    mesh = make_mesh((data, n_devices // data), ("data", "model"))
-    with set_mesh(mesh):
+    mesh = jax.make_mesh((data, n_devices // data), ("data", "model"),
+                         axis_types=(jax.sharding.AxisType.Auto,) * 2)
+    with jax.sharding.set_mesh(mesh):
         param_sh = shardings_for(tf.param_specs(cfg), mesh)
         p_sh = jax.device_put(params, param_sh)
         loss_sh, _ = jax.jit(
@@ -176,6 +175,8 @@ def main(argv=None) -> int:
     p.add_argument("--ckpt-every", type=int, default=50)
     p.add_argument("--seed", type=int, default=0)
     args = p.parse_args(argv)
+    from repro.runtime.platform import enable_compile_cache
+    enable_compile_cache()
 
     from repro.configs import get_config
     cfg = get_config(args.arch, smoke=args.smoke)

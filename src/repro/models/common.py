@@ -85,8 +85,7 @@ def constrain(x, *spec):
     are dropped — the constraint degrades gracefully across mesh shapes.
     """
     try:
-        from ..compat import get_abstract_mesh
-        mesh = get_abstract_mesh()
+        mesh = jax.sharding.get_abstract_mesh()
         if mesh is None or mesh.empty:
             return x
         sizes = dict(mesh.shape)

@@ -32,7 +32,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from ..compat import get_abstract_mesh, make_mesh, pvary, set_mesh, shard_map
 from .common import BATCH_AXES, MODEL_AXIS, constrain, dense_init
 from .config import ModelConfig
 
@@ -217,12 +216,13 @@ def selftest_distributed(n_devices: int) -> bool:
 
     y_ref, _ = moe_forward(p, x, cfg)
 
-    mesh = make_mesh((1, n_devices), ("data", MODEL_AXIS))
+    mesh = jax.make_mesh((1, n_devices), ("data", MODEL_AXIS),
+                         axis_types=(jax.sharding.AxisType.Auto,) * 2)
     specs = moe_specs(cfg)
     # EP-only for the test: experts over the model axis, rest replicated
     specs = {k: P(MODEL_AXIS, None, None) if k != "router" else P(None, None)
              for k in specs}
-    with set_mesh(mesh):
+    with jax.sharding.set_mesh(mesh):
         p_sh = {k: jax.device_put(v, NamedSharding(mesh, specs[k]))
                 for k, v in p.items()}
         x_sh = jax.device_put(x, NamedSharding(mesh, P(None, None, None)))
@@ -249,7 +249,7 @@ def ring_moe_forward(p: Dict, x, cfg: ModelConfig) -> Tuple[jnp.ndarray, Dict]:
     n_experts, and T divisible by that size; falls back to
     :func:`moe_forward` otherwise (e.g. single-device smoke tests).
     """
-    mesh = get_abstract_mesh()
+    mesh = jax.sharding.get_abstract_mesh()
     m = cfg.moe
     b, t, d = x.shape
     if (mesh is None or mesh.empty or MODEL_AXIS not in mesh.axis_names):
@@ -311,7 +311,8 @@ def ring_moe_forward(p: Dict, x, cfg: ModelConfig) -> Tuple[jnp.ndarray, Dict]:
                 acc_out, MODEL_AXIS, perm)
             return tuple(nxt), None
 
-        acc0 = pvary(jnp.zeros((n_loc, d), xs.dtype), all_axes)
+        acc0 = jax.lax.pcast(jnp.zeros((n_loc, d), xs.dtype), all_axes,
+                             to="varying")
         (xc, te, tp, acc), _ = jax.lax.scan(
             step, (xf, top_e, top_p, acc0), None, length=R)
         # aux losses, reduced over the whole mesh
@@ -326,7 +327,7 @@ def ring_moe_forward(p: Dict, x, cfg: ModelConfig) -> Tuple[jnp.ndarray, Dict]:
         return acc.reshape(bl, tl, d), aux_vec
 
     from jax.sharding import PartitionSpec as _P
-    f = shard_map(
+    f = jax.shard_map(
         body, mesh=mesh,
         in_specs=(_P(batch_axes or None, MODEL_AXIS, None),
                   _P(None, None),
@@ -364,8 +365,9 @@ def selftest_ring(n_devices: int) -> bool:
     x = jax.random.normal(jax.random.PRNGKey(1), (2, n_devices * 4, 16))
     y_ref, _ = moe_forward(p, x, cfg)
 
-    mesh = make_mesh((1, n_devices), ("data", MODEL_AXIS))
-    with set_mesh(mesh):
+    mesh = jax.make_mesh((1, n_devices), ("data", MODEL_AXIS),
+                         axis_types=(jax.sharding.AxisType.Auto,) * 2)
+    with jax.sharding.set_mesh(mesh):
         p_sh = {k: jax.device_put(
             v, NamedSharding(mesh, P(MODEL_AXIS, None, None)
                              if k != "router" else P(None, None)))

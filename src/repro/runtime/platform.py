@@ -1,50 +1,40 @@
-"""XLA platform / flag configuration — the repo's ONLY XLA_FLAGS writer.
+"""XLA platform configuration — the repo's ONLY XLA_FLAGS writer.
 
 XLA reads ``XLA_FLAGS`` exactly once, when the first backend initializes;
-any write after that is silently dead.  Every entry point that needs
-flags (fake host device counts for the multi-device suites, the
-async-collective + latency-hiding-scheduler set that makes the
-double-buffered schedule bodies actually overlap on GPU) therefore
-routes through this module, which
+any write after that is silently dead.  The one flag this repository
+needs is the fake host device count of the multi-device CPU test and
+bench harness, so every entry point that sets it routes through this
+module, which
 
-* merges new flags into ``os.environ["XLA_FLAGS"]`` without clobbering
-  caller-provided ones,
+* merges the flag into ``os.environ["XLA_FLAGS"]`` without clobbering
+  caller-provided flags,
 * is a guarded **no-op once jax is initialized** (returns ``False`` and
-  warns instead of planting flags that can never take effect), and
+  warns instead of planting a flag that can never take effect), and
 * is the single allowed ``XLA_FLAGS`` write site, enforced by
   ``tools/check_api.py`` (the ``set_platform`` idiom from SNIPPETS.md).
 
-Overlap flags: the engine's split-step bodies issue step i+1's
-``ppermute`` before step i's accumulate, so the *program* has the slack;
-these flags let XLA's GPU runtime actually use it (async collectives on
-their own stream, latency-hiding scheduler to sink the ``-done`` past
-independent compute).  On TPU and CPU backends they are inert but
-harmless.
+It also places JAX's persistent compilation cache
+(:func:`enable_compile_cache`), so that repeated runs on a chip reuse
+compiled programs.
 """
 from __future__ import annotations
 
 import os
 import sys
 import warnings
-from typing import Iterable, Optional
+from typing import Optional
 
 __all__ = [
-    "OVERLAP_XLA_FLAGS", "jax_initialized", "host_device_count_flag",
-    "set_platform", "set_host_device_count", "subprocess_env",
+    "jax_initialized", "host_device_count_flag", "set_platform",
+    "set_host_device_count", "subprocess_env", "enable_compile_cache",
+    "COMPILE_CACHE_DIR",
 ]
 
-# Overlap set (jax GPU performance tips + the set_platform idiom): the
-# latency-hiding scheduler separates collective starts from their waits
-# across independent compute, collectives get a dedicated high-priority
-# stream, and back-to-back ring steps pipeline.  Async collectives
-# themselves are default-on in current XLA — the old
-# ``--xla_gpu_enable_async_collectives`` knob no longer exists (XLA
-# aborts on unknown flags, so it must NOT be planted).
-OVERLAP_XLA_FLAGS = (
-    "--xla_gpu_enable_latency_hiding_scheduler=true",
-    "--xla_gpu_enable_highest_priority_async_stream=true",
-    "--xla_gpu_enable_pipelined_collectives=true",
-)
+# Fixed in-repo cache location: the path is part of the cache key, so it
+# must not move between runs (no temporary, per-process or dated dirs).
+COMPILE_CACHE_DIR = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))))), ".jax_cache")
 
 
 def jax_initialized() -> bool:
@@ -52,13 +42,8 @@ def jax_initialized() -> bool:
     mod = sys.modules.get("jax")
     if mod is None:
         return False
-    try:
-        from jax._src import xla_bridge
-        return bool(xla_bridge._backends)
-    except Exception:                       # noqa: BLE001 (version drift)
-        # can't introspect this jax version; assume live (be conservative:
-        # callers then know their flags may be dead)
-        return True
+    from jax._src import xla_bridge
+    return bool(xla_bridge._backends)
 
 
 def host_device_count_flag(n: int) -> str:
@@ -66,30 +51,26 @@ def host_device_count_flag(n: int) -> str:
     return f"--xla_force_host_platform_device_count={int(n)}"
 
 
-def _merge_flags(flags: Iterable[str], env: Optional[dict] = None) -> str:
-    """Append flags to the env's XLA_FLAGS, dropping exact duplicates and
-    replacing older settings of the same ``--flag=`` stem."""
-    env = os.environ if env is None else env
-    current = env.get("XLA_FLAGS", "").split()
-    stems = {f.split("=", 1)[0] for f in flags}
-    kept = [f for f in current if f.split("=", 1)[0] not in stems]
-    env["XLA_FLAGS"] = " ".join(kept + list(flags)).strip()
+def _merge_flag(flag: str, env) -> str:
+    """Append a flag to the env's XLA_FLAGS, replacing an older setting
+    of the same ``--flag=`` stem."""
+    stem = flag.split("=", 1)[0]
+    kept = [f for f in env.get("XLA_FLAGS", "").split()
+            if f.split("=", 1)[0] != stem]
+    env["XLA_FLAGS"] = " ".join(kept + [flag])
     return env["XLA_FLAGS"]
 
 
 def set_platform(platform: Optional[str] = None, *,
-                 host_device_count: Optional[int] = None,
-                 overlap: bool = True) -> bool:
+                 host_device_count: Optional[int] = None) -> bool:
     """Configure the XLA platform before jax initializes.
 
-    ``platform`` ("cpu" | "gpu" | "tpu") sets ``jax_platform_name``;
+    ``platform`` ("cpu" | "tpu") sets ``jax_platform_name``;
     ``host_device_count`` plants the fake-CPU-device flag (the
-    multi-device test/bench harness); ``overlap=True`` (default) adds
-    :data:`OVERLAP_XLA_FLAGS`.  Returns ``True`` if the flags were
-    planted while they can still take effect, ``False`` (with a warning,
+    multi-device test/bench harness).  Returns ``True`` if the flag was
+    planted while it can still take effect, ``False`` (with a warning,
     and without touching the environment) once jax is already
-    initialized — the guard that makes wiring this into
-    ``launch/mesh.py`` safe mid-process.
+    initialized.
     """
     if jax_initialized():
         warnings.warn(
@@ -97,40 +78,45 @@ def set_platform(platform: Optional[str] = None, *,
             "initialized; XLA flags would be ignored (no-op)",
             RuntimeWarning, stacklevel=2)
         return False
-    flags = []
     if host_device_count is not None:
-        flags.append(host_device_count_flag(host_device_count))
-    if overlap:
-        flags.extend(OVERLAP_XLA_FLAGS)
-    if flags:
-        _merge_flags(flags)
+        _merge_flag(host_device_count_flag(host_device_count), os.environ)
     if platform is not None:
         import jax
         jax.config.update("jax_platform_name", platform)
     return True
 
 
-def set_host_device_count(n: int, *, overlap: bool = False) -> bool:
+def set_host_device_count(n: int) -> bool:
     """Fake-device entry point for benches/selftests (pre-jax-init only)."""
-    return set_platform(host_device_count=n, overlap=overlap)
+    return set_platform(host_device_count=n)
 
 
 def subprocess_env(host_device_count: Optional[int] = None, *,
-                   overlap: bool = False,
                    base: Optional[dict] = None) -> dict:
-    """A child-process environment with the requested XLA flags merged in.
+    """A child-process environment with the fake-device flag merged in.
 
     Unlike :func:`set_platform` this never touches the current process
     (the child's jax is by definition uninitialized), so it needs no
-    init guard — it is how ``benchmarks/run.py`` and the distributed
-    test suite launch their fixed-device-count workers.
+    init guard — it is how ``benchmarks/run.py`` launches its
+    fixed-device-count workers.
     """
     env = dict(os.environ if base is None else base)
-    flags = []
     if host_device_count is not None:
-        flags.append(host_device_count_flag(host_device_count))
-    if overlap:
-        flags.extend(OVERLAP_XLA_FLAGS)
-    if flags:
-        _merge_flags(flags, env)
+        _merge_flag(host_device_count_flag(host_device_count), env)
     return env
+
+
+def enable_compile_cache() -> str:
+    """Turn on JAX's persistent compilation cache; returns its directory.
+
+    Where ``JAX_COMPILATION_CACHE_DIR`` is set, JAX reads it itself and
+    this sets nothing.  Otherwise the cache goes to the fixed in-repo
+    :data:`COMPILE_CACHE_DIR` (listed in ``.gitignore``), the same path
+    on every call, so a later run finds what an earlier one compiled.
+    """
+    path = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if path:
+        return path
+    import jax
+    jax.config.update("jax_compilation_cache_dir", COMPILE_CACHE_DIR)
+    return COMPILE_CACHE_DIR

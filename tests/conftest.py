@@ -1,15 +1,3 @@
-import importlib.util
-
-import pytest
-
-# Property-based test modules need hypothesis; skip collecting them (instead
-# of erroring the whole run) on containers that don't ship it.
-collect_ignore = []
-if importlib.util.find_spec("hypothesis") is None:
-    collect_ignore = ["test_data_optim.py", "test_property.py",
-                      "test_schedule.py"]
-
-
 def pytest_configure(config):
     config.addinivalue_line(
         "markers", "slow: long-running integration tests")

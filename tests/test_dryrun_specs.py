@@ -19,8 +19,9 @@ def test_prefill_specs_have_exact_seq_tokens():
     from repro.configs import SHAPES, get_config
     from repro.launch.dryrun import input_specs
 
-    from repro.compat import make_mesh
-    mesh = make_mesh((1, 1), ("data", "model"))
+    import jax
+    mesh = jax.make_mesh((1, 1), ("data", "model"),
+                         axis_types=(jax.sharding.AxisType.Auto,) * 2)
     shape = SHAPES["prefill_32k"]
     # dense LM: exactly seq tokens (even => chunked kernels stay chunked)
     specs = input_specs(get_config("llama3-8b"), shape, mesh)

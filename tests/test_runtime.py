@@ -13,7 +13,8 @@ without touching jax or a device mesh (those paths run in
 * PreemptionSignal handler chaining, uninstall restore, and the context
   manager;
 * determinism of the seeded fault injectors (StragglerInjector,
-  TransientFailure, DeviceLoss).
+  TransientFailure, DeviceLoss);
+* where the persistent compilation cache goes (``platform``).
 """
 import signal
 
@@ -233,3 +234,36 @@ def test_device_loss_seeded_and_partitioned():
     assert DeviceLoss(9, 5, seed=1).lost() != loss.lost() or True
     with pytest.raises(ValueError, match="n_lost"):
         DeviceLoss(4, 4)
+
+
+# ---------------------------------------------------------------------------
+# Persistent compilation cache placement
+# ---------------------------------------------------------------------------
+def test_compile_cache_honours_env_else_fixed_repo_path(monkeypatch,
+                                                        tmp_path):
+    """With JAX_COMPILATION_CACHE_DIR set the helper sets nothing and
+    returns that directory; without it the cache goes to one fixed
+    in-repo path, the same on every call."""
+    import os
+
+    import jax
+
+    from repro.runtime import platform
+
+    old = jax.config.jax_compilation_cache_dir
+    try:
+        env_dir = str(tmp_path / "cache")
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", env_dir)
+        jax.config.update("jax_compilation_cache_dir", None)
+        assert platform.enable_compile_cache() == env_dir
+        assert jax.config.jax_compilation_cache_dir is None
+        assert not os.path.exists(env_dir)
+
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+        first = platform.enable_compile_cache()
+        second = platform.enable_compile_cache()
+        repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+        assert first == second == os.path.join(repo, ".jax_cache")
+        assert jax.config.jax_compilation_cache_dir == first
+    finally:
+        jax.config.update("jax_compilation_cache_dir", old)

@@ -254,6 +254,30 @@ def test_steal3d_allclose_ring_c_spmm(operands, impl):
     np.testing.assert_allclose(got, a_d @ b, atol=1e-5)
 
 
+@pytest.mark.parametrize("overlap", ["auto", "on"])
+def test_steal3d_chunked_pair_lists_match_ref(operands, monkeypatch,
+                                              overlap):
+    """Steal pair lists past the SMEM budget (here shrunk to 16 entries
+    per list) are laid out in chunks at output-row boundaries and run as
+    several kernel calls into one output."""
+    import jax
+    from repro.kernels import bsr_spmm
+    a_d, b, _, a_h, b_h, _ = operands
+    monkeypatch.setattr(bsr_spmm, "SMEM_LIST_BYTES", 2 * 4 * 16)
+    api.clear_plan_cache()
+    jax.clear_caches()
+    try:
+        plan = api.plan_matmul(a_h, b_h, algorithm="steal3d",
+                               impl="interpret", overlap=overlap)
+        pa = plan.steal.aux["pa" if overlap == "auto" else "pa0"]
+        assert pa.shape[-1] > 16 and pa.shape[-1] % 16 == 0
+        got = np.asarray(plan(a_h, b_h))
+    finally:
+        api.clear_plan_cache()
+        jax.clear_caches()
+    np.testing.assert_allclose(got, a_d @ b, atol=1e-5)
+
+
 def test_steal3d_allclose_ring_c_spgemm(operands):
     a_d, _, b_sp, a_h, _, b_sph = operands
     got = np.asarray(matmul(a_h, b_sph, algorithm="steal3d", impl="ref"))

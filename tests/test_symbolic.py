@@ -148,6 +148,80 @@ def test_sparse_output_allclose_dense_output(sparse_operands, alg, impl):
                                atol=1e-5)
 
 
+@pytest.fixture
+def small_smem(monkeypatch):
+    """Shrink the kernels' SMEM list budget to 16 entries per list (three
+    lists) so small products take the chunked multi-call path."""
+    import jax
+    from repro.kernels import bsr_spmm
+    monkeypatch.setattr(bsr_spmm, "SMEM_LIST_BYTES", 3 * 4 * 16)
+    api.clear_plan_cache()
+    jax.clear_caches()
+    yield 16
+    api.clear_plan_cache()
+    jax.clear_caches()
+
+
+def test_chunk_pair_lists_cut_only_at_slot_boundaries():
+    """Long lists become equal chunks that each start at a new slot, keep
+    the list slot-sorted, and pad with inert pairs on the chunk's last
+    slot; short lists keep the bucketed single-call layout."""
+    chunk_pair_lists = api._symbolic.chunk_pair_lists
+    rng = np.random.default_rng(0)
+    lists, pads = [], []
+    for n_slots in (30, 7, 41):
+        ps = np.repeat(np.arange(n_slots), rng.integers(1, 5, n_slots))
+        lists.append((np.arange(len(ps)) + 100, np.arange(len(ps)) + 200,
+                      ps))
+        pads.append((-1, -2))
+    pa, pb, ps = chunk_pair_lists(lists, pads, chunk=16)
+    assert pa.shape[1] % 16 == 0 and pa.shape[1] > 16
+    for row, (la, lb, lps) in enumerate(lists):
+        assert (np.diff(ps[row]) >= 0).all()
+        chunks = ps[row].reshape(-1, 16)
+        real = (pa[row] >= 0).reshape(-1, 16)
+        for t in range(1, len(chunks)):
+            assert chunks[t][0] > chunks[t - 1][-1]   # no slot spans two
+        assert real[:, 0].all()                       # no empty chunk
+        np.testing.assert_array_equal(pa[row][pa[row] >= 0], la)
+        np.testing.assert_array_equal(pb[row][pb[row] >= 0], lb)
+        np.testing.assert_array_equal(ps[row][pa[row] >= 0], lps)
+        assert (pb[row][pa[row] < 0] == -2).all()
+    short = chunk_pair_lists(lists[1:2], pads[1:2], chunk=64)
+    assert short[0].shape[1] == bucket_capacity(len(lists[1][0]))
+    with pytest.raises(ValueError, match="more than 3 pairs"):
+        chunk_pair_lists(lists, pads, chunk=3)
+
+
+@pytest.mark.parametrize("alg", api.sparse_algorithms())
+def test_chunked_sparse_output_interpret_matches_ref(small_smem,
+                                                     sparse_operands, alg):
+    """Pair lists past the SMEM budget run as several kernel calls into
+    one output buffer and still match the ref path and the dense product."""
+    a_d, b_d, a_h, b_h = sparse_operands
+    plan = plan_matmul(a_h, b_h, algorithm=alg, impl="interpret",
+                       output="sparse")
+    cap = plan.symbolic.pair_capacity
+    assert cap > small_smem and cap % small_smem == 0
+    got = np.asarray(plan(a_h, b_h).densify())
+    want = np.asarray(matmul(a_h, b_h, algorithm=alg, impl="ref",
+                             output="sparse").densify())
+    np.testing.assert_allclose(got, want, atol=1e-5)
+    np.testing.assert_allclose(got, a_d @ b_d, atol=1e-5)
+
+
+def test_unchunked_long_spmm_list_is_refused(small_smem):
+    """A stored tile longer than the SMEM budget is refused before Mosaic
+    would run out of SMEM, with the budget in the message."""
+    from repro.core.bsr import BSR
+    from repro.kernels import ops
+    a = BSR.from_dense(random_sparse(64, 64, 0.5, seed=0), 4)
+    assert a.capacity > 24
+    with pytest.raises(ValueError, match="SMEM budget"):
+        ops.bsr_spmm(a, jnp.ones((64, 8), jnp.float32), impl="interpret",
+                     block_n=8)
+
+
 def test_chained_cube_stays_packed(sparse_operands):
     """A @ A @ A chains through DistBSR handles — no densify, no re-tile —
     and the product handle works as either operand."""
