@@ -1,0 +1,8 @@
+"""The benchmark's tests run on the CPU."""
+import os
+import sys
+
+os.environ.setdefault("JAX_PLATFORMS", "cpu")
+
+_BENCH = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+sys.path[:0] = [_BENCH, os.path.join(os.path.dirname(_BENCH), "src")]
