@@ -167,7 +167,7 @@ def obs_drift_section(smoke: bool = False,
         plans[alg](a_h, b_h).block_until_ready()   # compile before timing
     before = {alg: _time(lambda p=p: p(a_h, b_h).block_until_ready(),
                          repeats=reps) for alg, p in plans.items()}
-    obs.enable(clear=True)
+    obs.enable(clear=True, drift=True)
     obs.reset_drift()
     with obs.span("bench.obs_drift", smoke=smoke):
         # one plan build under tracing so the exported trace carries

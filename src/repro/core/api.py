@@ -2193,11 +2193,12 @@ class MatmulPlan:
             body = algorithm.body
             aux_specs = {k: P(geom.axr, geom.axc, *(None,) * (v.ndim - 2))
                          for k, v in steal.aux.items()}
-            self._aux = {
-                k: jax.device_put(
-                    np.ascontiguousarray(v),
-                    jax.sharding.NamedSharding(mesh, aux_specs[k]))
-                for k, v in steal.aux.items()}
+            with _obs.span("plan_build.commit"):
+                self._aux = {
+                    k: jax.device_put(
+                        np.ascontiguousarray(v),
+                        jax.sharding.NamedSharding(mesh, aux_specs[k]))
+                    for k, v in steal.aux.items()}
 
             def fn(a, b, aux):
                 self.traces += 1          # runs at trace time only
@@ -2221,11 +2222,12 @@ class MatmulPlan:
             packed_body = algorithm.packed_body
             aux_specs = {k: P(geom.axr, geom.axc, *(None,) * (v.ndim - 2))
                          for k, v in wire_aux.items()}
-            self._aux = {
-                k: jax.device_put(
-                    np.ascontiguousarray(v),
-                    jax.sharding.NamedSharding(mesh, aux_specs[k]))
-                for k, v in wire_aux.items()}
+            with _obs.span("plan_build.commit"):
+                self._aux = {
+                    k: jax.device_put(
+                        np.ascontiguousarray(v),
+                        jax.sharding.NamedSharding(mesh, aux_specs[k]))
+                    for k, v in wire_aux.items()}
 
             def fn(a, b, aux):
                 self.traces += 1          # runs at trace time only
@@ -2261,21 +2263,34 @@ class MatmulPlan:
             # ride in packed wire form and the stored->packed slot map is
             # already composed into the (remapped) pair lists.
             sparse_body = algorithm.sparse_body
-            sched = symbolic.scheduled_pairs(
-                algorithm.k_order,
-                pair_a=None if wire_aux is None else wire_aux.get("pa"),
-                pair_b=None if wire_aux is None else wire_aux.get("pb"))
-            # Pair lists are plan-lifetime constants; commit them in their
-            # mesh sharding once so repeated calls don't re-transfer them
-            # to every device (measurably dominates small multiplies).
-            pair_sharding = jax.sharding.NamedSharding(
-                mesh, P(geom.axr, geom.axc, None, None))
-            self._pairs = {k: jax.device_put(np.asarray(v, dtype=np.int32),
-                                             pair_sharding)
-                           for k, v in sched.items()}
-            self._c_rows = jnp.asarray(symbolic.c_rows, dtype=jnp.int32)
-            self._c_cols = jnp.asarray(symbolic.c_cols, dtype=jnp.int32)
-            self._c_counts = jnp.asarray(symbolic.c_counts, dtype=jnp.int32)
+            with _obs.span("plan_build.commit"):
+                sched = symbolic.scheduled_pairs(
+                    algorithm.k_order,
+                    pair_a=None if wire_aux is None else wire_aux.get("pa"),
+                    pair_b=None if wire_aux is None else wire_aux.get("pb"))
+                # Pair lists are plan-lifetime constants; commit them in
+                # their mesh sharding once so repeated calls don't
+                # re-transfer them to every device (measurably dominates
+                # small multiplies).
+                pair_sharding = jax.sharding.NamedSharding(
+                    mesh, P(geom.axr, geom.axc, None, None))
+                self._pairs = {
+                    k: jax.device_put(np.asarray(v, dtype=np.int32),
+                                      pair_sharding)
+                    for k, v in sched.items()}
+                self._c_rows = jnp.asarray(symbolic.c_rows, dtype=jnp.int32)
+                self._c_cols = jnp.asarray(symbolic.c_cols, dtype=jnp.int32)
+                self._c_counts = jnp.asarray(symbolic.c_counts,
+                                             dtype=jnp.int32)
+            # What a product's kernel calls will do: every device runs g
+            # steps of one pair list each (uniform length, so the busiest
+            # device runs as many grid steps as any); real pairs are the
+            # products of two real blocks, over all devices.
+            reg = _obs.registry()
+            reg.gauge("plan.real_pairs", algorithm=algorithm.name).set(
+                symbolic.total_real_pairs())
+            reg.gauge("plan.pair_steps", algorithm=algorithm.name).set(
+                geom.g * symbolic.pair_capacity)
 
             def fn(a, b, pairs):
                 self.traces += 1          # runs at trace time only
@@ -2329,10 +2344,24 @@ class MatmulPlan:
         # reads, no blocking, async dispatch preserved.
         if not _obs.enabled():
             return self._execute(a, b)
+        if _obs.drift_enabled():
+            return self._call_recording_drift(a, b)
+        # Spans on, drift off: the span covers the dispatch only; nothing
+        # blocks and no cost model runs, so a traced product runs as an
+        # untraced one does.
+        with self._multiply_span():
+            return self._execute(a, b)
+
+    def _multiply_span(self):
+        return _obs.span(f"multiply.{self.algorithm.name}", kind=self.kind,
+                         wire=self.wire, output=self.output,
+                         overlap=self.overlap)
+
+    def _call_recording_drift(self, a, b):
+        """One product under ``obs.enable(drift=True)``: block on it and
+        record its measured time beside the cost model's prediction."""
         t0 = time.perf_counter()
-        sp = _obs.span(f"multiply.{self.algorithm.name}", kind=self.kind,
-                       wire=self.wire, output=self.output,
-                       overlap=self.overlap)
+        sp = self._multiply_span()
         with sp:
             out = self._execute(a, b)
             # Per-multiply seconds follow the sync_elapsed discipline:
@@ -3118,6 +3147,8 @@ def _plan_matmul_impl(a, b, *, algorithm: str = "ring_c", mesh=None,
 
 
 def plan_matmul(a, b, **kw) -> MatmulPlan:
+    if not _obs.enabled():
+        return _plan_matmul_impl(a, b, **kw)
     sp = _obs.span("plan_build",
                    algorithm=str(kw.get("algorithm", "ring_c")),
                    output=str(kw.get("output", "dense")),

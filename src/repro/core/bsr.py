@@ -46,6 +46,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from .. import obs as _obs
 from .grid import ProcessGrid, bucket_capacity, ceil_div, pad_to_multiple
 
 __all__ = ["BSR", "TiledBSR", "rmat_edges", "rmat_matrix", "random_sparse"]
@@ -306,6 +307,24 @@ class TiledBSR:
         if balance not in ("none", "rows", "cols", "auto"):
             raise ValueError(f"unknown balance {balance!r}; one of "
                              "('none', 'rows', 'cols', 'auto')")
+        with _obs.span("handle.tile.scan"):
+            host = cls._scan_dense(dense, grid, block_size, capacity, dtype,
+                                   balance)
+        blocks, rows_, cols_, counts, fields = host
+        with _obs.span("handle.tile.upload"):
+            # Block until the data is on the device, traced or not, so the
+            # handle comes back ready and the span ends when the upload
+            # does (jnp.asarray may return while the copy is in flight).
+            stored = jax.block_until_ready(tuple(
+                jnp.asarray(x) for x in (blocks, rows_, cols_, counts)))
+        return cls(*stored, **fields)
+
+    @staticmethod
+    def _scan_dense(dense, grid: ProcessGrid, block_size: int, capacity,
+                    dtype, balance: str) -> tuple:
+        """Host half of :meth:`from_dense`: pad, find the real blocks of
+        every tile, gather them and merge the coverage blocks.  Returns the
+        stored arrays as numpy and the remaining fields."""
         dense = np.asarray(dense)
         m, n = dense.shape
         tm = pad_to_multiple(ceil_div(m, grid.rows), block_size)
@@ -380,19 +399,16 @@ class TiledBSR:
                               np.asarray(t.cols), tile_nbr)
                 for t in (u.with_capacity(cap) for u in row)]
                for row in tiles]
-        blocks = jnp.asarray(np.stack(
-            [np.stack([a[0] for a in row]) for row in aug]))
-        rows_ = jnp.asarray(np.stack(
-            [np.stack([a[1] for a in row]) for row in aug]))
-        cols_ = jnp.asarray(np.stack(
-            [np.stack([a[2] for a in row]) for row in aug]))
-        counts = jnp.asarray(
-            [[t.nnzb for t in row] for row in tiles], dtype=jnp.int32)
-        return cls(blocks=blocks, rows=rows_, cols=cols_, counts=counts,
-                   shape=(mp, np_), block_size=block_size,
-                   grid_shape=(grid.rows, grid.cols), capacity=cap,
-                   logical_shape=(m, n), row_block_perm=perm,
-                   col_block_perm=col_perm)
+        blocks = np.stack([np.stack([a[0] for a in row]) for row in aug])
+        rows_ = np.stack([np.stack([a[1] for a in row]) for row in aug])
+        cols_ = np.stack([np.stack([a[2] for a in row]) for row in aug])
+        counts = np.asarray([[t.nnzb for t in row] for row in tiles],
+                            dtype=np.int32)
+        return blocks, rows_, cols_, counts, dict(
+            shape=(mp, np_), block_size=block_size,
+            grid_shape=(grid.rows, grid.cols), capacity=cap,
+            logical_shape=(m, n), row_block_perm=perm,
+            col_block_perm=col_perm)
 
     def to_dense(self) -> jnp.ndarray:
         gr, gc = self.grid_shape

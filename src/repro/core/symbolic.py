@@ -41,6 +41,7 @@ from typing import Callable, Dict, Optional, Tuple
 
 import numpy as np
 
+from .. import obs as _obs
 from ..kernels.bsr_spmm import list_chunk
 from ..kernels.ops import match_block_pairs
 from .bsr import TiledBSR
@@ -77,6 +78,11 @@ class GridStructure:
 
 def extract_structure(t: TiledBSR) -> GridStructure:
     """Pull a TiledBSR's block structure to the host (one device read)."""
+    with _obs.span("plan_build.structure"):
+        return _extract_structure(t)
+
+
+def _extract_structure(t: TiledBSR) -> GridStructure:
     rows = np.asarray(t.rows)
     cols = np.asarray(t.cols)
     real = np.abs(np.asarray(t.blocks)).sum(axis=(3, 4)) != 0

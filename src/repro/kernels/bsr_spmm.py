@@ -28,6 +28,11 @@ out this way; the SpMM and pair-accumulate kernels run one
 ``pallas_call`` per chunk, all writing into one aliased output buffer.
 Because no output block spans two chunks, no chunk re-zeroes a block
 that another chunk wrote.
+
+Each ``pallas_call`` carries a stable ``name`` (``bsr_spmm``,
+``bsr_pair_matmul``, ``bsr_pair_accumulate``).  It becomes the HLO
+instruction's name, ``<name>.<n>``, for the first chunk and for the chunks
+of the loop alike, so a profiler trace finds every call of a kernel by it.
 """
 from __future__ import annotations
 
@@ -145,6 +150,7 @@ def bsr_spmm_pallas(blocks, rows, cols, dense, *, n_block_rows: int,
             [blocks, dense], out, 3)
         return pl.pallas_call(
             _spmm_kernel,
+            name="bsr_spmm",
             grid_spec=pltpu.PrefetchScalarGridSpec(
                 num_scalar_prefetch=3,    # chunk offset, rows, cols
                 # steps innermost => consecutive row visits
@@ -207,6 +213,7 @@ def bsr_pair_matmul_pallas(a_blocks, b_blocks, pair_a, pair_b, pair_rows,
     )
     out = pl.pallas_call(
         _pair_kernel,
+        name="bsr_pair_matmul",
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct(
             (n_block_rows * bs, n_block_cols * bs), jnp.float32),
@@ -258,6 +265,7 @@ def bsr_pair_accumulate_pallas(a_blocks, b_blocks, pair_a, pair_b, pair_slot,
             [a_blocks, b_blocks], out, 3)
         return pl.pallas_call(
             _pair_acc_kernel,
+            name="bsr_pair_accumulate",
             grid_spec=pltpu.PrefetchScalarGridSpec(
                 num_scalar_prefetch=3,    # pair_a, pair_b, pair_slot
                 grid=(lists[0].shape[0],),

@@ -367,7 +367,7 @@ def main() -> int:
         b = rng.standard_normal((32, 8)).astype(np.float32)
         a_h = DistBSR.from_dense(a_d, g=1, block_size=4)
         b_h = DistDense.for_rhs(jnp.asarray(b), a_h)
-        obs.enable(clear=True)
+        obs.enable(clear=True, drift=True)
         obs.reset_drift()
         plan = api.plan_matmul(a_h, b_h, algorithm="ring_c", impl="ref",
                                cache=False)
@@ -420,7 +420,7 @@ def main() -> int:
                            net_bw=roofline.TPU_V5E.net_bw * 100,
                            hop_latency=1e-9)
         obs.reset_all()
-        obs.enable(clear=True)
+        obs.enable(clear=True, drift=True)   # the replanner reads drift
         api.set_drift_machine(base)
         try:
             p0 = api.plan_matmul(a, b, algorithm="auto", machine=base,

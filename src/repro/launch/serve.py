@@ -70,7 +70,8 @@ def main(argv=None) -> int:
     if cfg.is_encoder:
         raise SystemExit(f"{args.arch} is encoder-only; no serve path")
     if args.trace:
-        obs.enable(clear=True)
+        # drift too: the report below reads what the plan calls record
+        obs.enable(clear=True, drift=True)
     out = serve(cfg, requests=args.requests, prompt_len=args.prompt_len,
                 gen_len=args.gen_len, seed=args.seed, sparse=args.sparse)
     if args.trace:

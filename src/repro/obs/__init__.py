@@ -7,8 +7,12 @@ Quick tour::
 
     obs.enable()                         # tracing on (off by default)
     with obs.span("plan_build", algorithm="ring_c"):
-        ...                              # spans nest, thread-safe
+        ...                              # spans nest, thread-safe, and
+                                         # reach a jax.profiler trace
     obs.export_trace("trace.json")       # Chrome-trace JSON for Perfetto
+
+    obs.enable(drift=True)               # plan calls also block and
+                                         # record predicted-vs-measured
 
     obs.registry().counter("steal3d.plans_built").inc()
     obs.registry().snapshot()            # plain-dict view of every metric
@@ -16,8 +20,8 @@ Quick tour::
     obs.drift_report()                   # cost-model calibration per series
 
 Importing this package never imports jax — benches may import it at module
-scope before platform flags are set; the timing helpers defer their jax
-import to call time.
+scope before platform flags are set; ``enable()`` and the timing helpers
+defer their jax import to call time.
 """
 from .drift import (
     drift_records,
@@ -38,11 +42,11 @@ from .trace import (
     REQUIRED_EVENT_KEYS,
     clear_trace,
     disable,
+    drift_enabled,
     enable,
     enabled,
     events,
     export_trace,
-    instant,
     span,
     sync_elapsed,
     timed,
@@ -57,6 +61,7 @@ __all__ = [
     "REQUIRED_EVENT_KEYS",
     "clear_trace",
     "disable",
+    "drift_enabled",
     "drift_records",
     "drift_report",
     "enable",
@@ -64,7 +69,6 @@ __all__ = [
     "events",
     "export_drift",
     "export_trace",
-    "instant",
     "percentile",
     "record_drift",
     "registry",

@@ -1,8 +1,9 @@
 """Predicted-vs-measured drift series for the plan cost model.
 
-Every traced ``MatmulPlan.__call__`` records the measured (blocking)
-per-multiply seconds next to the plan's ``predicted_perf()`` seconds,
-keyed by ``(algorithm, wire, overlap)``.  ``drift_report()`` condenses
+While drift recording is on (``obs.enable(drift=True)``; spans alone do
+not turn it on), every ``MatmulPlan.__call__`` blocks on its result and
+records the measured per-multiply seconds next to the plan's
+``predicted_perf()`` seconds, keyed by ``(algorithm, wire, overlap)``.  ``drift_report()`` condenses
 each series to a ratio (geometric mean of measured/predicted — the
 cost model's systematic bias) and an RMSE (absolute spread).  Records
 keep the plan's cost-model dict so ``tools/fit_machine.py`` can re-fit

@@ -3,11 +3,27 @@
 Tracing is off by default.  When off, ``span()`` returns one shared no-op
 context manager — no allocation, no clock read — so instrumented hot paths
 (plan calls, serving decode steps) pay a single boolean check.  When on,
-each span records a Chrome-trace "complete" event (``ph: "X"``) with
-microsecond ``ts``/``dur``, the recording thread's id, and any keyword
-attributes under ``args``.  Nesting needs no explicit parent plumbing:
-Perfetto reconstructs the stack per-thread from interval containment, and
-we additionally record the thread-local depth for the textual viewer.
+each span does two things:
+
+* it enters a ``jax.profiler.TraceAnnotation`` of the same name, so a span
+  taken while a profiler session records sits on the host plane of the
+  ``.xplane.pb``, on the same clock as the device's operations;
+* it buffers a Chrome-trace "complete" event (``ph: "X"``) with
+  microsecond ``ts``/``dur``, the recording thread's id, and any keyword
+  attributes under ``args``.  ``ts`` is read from the clock the profiler
+  stamps its own host events with, the wall clock in nanoseconds since the
+  Unix epoch (``time.time_ns``): an ``.xplane.pb`` stores its times from
+  ``profile_start_time`` (a stat of its ``Task Environment`` plane) on, so
+  ``ts * 1e3 - profile_start_time`` is the event's start in the trace.
+
+Nesting needs no explicit parent plumbing: Perfetto reconstructs the stack
+per-thread from interval containment, and we additionally record the
+thread-local depth for the textual viewer.
+
+Drift recording (``MatmulPlan.__call__`` blocking on every product to set
+its measured time beside the cost model's prediction) has a switch of its
+own, ``enable(drift=True)``: spans alone never change what the program
+does.
 
 Timing discipline helpers live here too: ``sync_elapsed`` (block until a
 jax pytree is ready, then read the clock) and ``timed`` (time a thunk with
@@ -30,9 +46,10 @@ class _State:
     def __init__(self):
         self.enabled = False
         self.lock = threading.Lock()
+        self.drift = False
+        self.annotation = None     # jax.profiler.TraceAnnotation, at enable()
         self.events: List[Dict] = []
         self.dropped = 0
-        self.t0 = time.perf_counter()
 
 
 _STATE = _State()
@@ -58,13 +75,14 @@ _NULL_SPAN = _NullSpan()
 
 
 class _Span:
-    __slots__ = ("name", "args", "_start", "_depth")
+    __slots__ = ("name", "args", "_start", "_depth", "_ann")
 
     def __init__(self, name: str, args: Dict):
         self.name = name
         self.args = args
-        self._start = 0.0
+        self._start = 0
         self._depth = 0
+        self._ann = _STATE.annotation(name)
 
     def note(self, **attrs) -> None:
         """Attach attributes discovered mid-span (e.g. cache hit/miss)."""
@@ -74,18 +92,20 @@ class _Span:
         depth = getattr(_TLS, "depth", 0)
         _TLS.depth = depth + 1
         self._depth = depth
-        self._start = time.perf_counter()
+        self._ann.__enter__()
+        self._start = time.time_ns()
         return self
 
     def __exit__(self, *exc):
-        end = time.perf_counter()
+        end = time.time_ns()
+        self._ann.__exit__(*exc)
         _TLS.depth = self._depth
         ev = {
             "ph": "X",
             "name": self.name,
             "cat": "repro",
-            "ts": (self._start - _STATE.t0) * 1e6,
-            "dur": (end - self._start) * 1e6,
+            "ts": self._start / 1e3,
+            "dur": (end - self._start) / 1e3,
             "pid": 0,
             "tid": threading.get_ident() % 2**31,
             "args": dict(self.args, depth=self._depth),
@@ -98,19 +118,35 @@ class _Span:
         return False
 
 
-def enable(clear: bool = False) -> None:
-    """Turn tracing on; ``clear=True`` also drops buffered events."""
+def enable(clear: bool = False, drift: bool = False) -> None:
+    """Turn tracing on; ``clear=True`` also drops buffered events.
+
+    ``drift=True`` also turns on drift recording: every plan call then
+    blocks on its result and records its measured time beside the cost
+    model's prediction (``repro.obs.drift``).  Spans alone never block.
+    """
+    from jax.profiler import TraceAnnotation   # deferred: see the package
+
     if clear:
         clear_trace()
+    _STATE.annotation = TraceAnnotation
+    _STATE.drift = drift
     _STATE.enabled = True
 
 
 def disable() -> None:
+    """Turn tracing and drift recording off."""
     _STATE.enabled = False
+    _STATE.drift = False
 
 
 def enabled() -> bool:
     return _STATE.enabled
+
+
+def drift_enabled() -> bool:
+    """Whether plan calls record drift (``enable(drift=True)``)."""
+    return _STATE.enabled and _STATE.drift
 
 
 def span(name: str, **attrs):
@@ -122,28 +158,6 @@ def span(name: str, **attrs):
     if not _STATE.enabled:
         return _NULL_SPAN
     return _Span(name, attrs)
-
-
-def instant(name: str, **attrs) -> None:
-    """Record a zero-duration marker event (rendered as a span of dur 0)."""
-    if not _STATE.enabled:
-        return
-    now = (time.perf_counter() - _STATE.t0) * 1e6
-    ev = {
-        "ph": "X",
-        "name": name,
-        "cat": "repro",
-        "ts": now,
-        "dur": 0.0,
-        "pid": 0,
-        "tid": threading.get_ident() % 2**31,
-        "args": dict(attrs),
-    }
-    with _STATE.lock:
-        if len(_STATE.events) < _MAX_EVENTS:
-            _STATE.events.append(ev)
-        else:
-            _STATE.dropped += 1
 
 
 def events() -> List[Dict]:

@@ -5,8 +5,10 @@ model (``Machine``), an operand structure, and a healthy g x g mesh.
 This module is the control loop that repairs each of them from *live*
 signals instead of restarting the job:
 
-* **Drift** — every traced multiply leaves a predicted-vs-measured pair
-  in ``obs.drift_records()`` per (algorithm, wire, overlap) series.
+* **Drift** — every multiply made while drift recording is on
+  (``obs.enable(drift=True)``, which the process running the plans turns
+  on; spans alone record none) leaves a predicted-vs-measured pair in
+  ``obs.drift_records()`` per (algorithm, wire, overlap) series.
   :meth:`ElasticReplanner.should_replan` watches the per-series geomean
   ratio (``obs.drift_report()``) and :class:`~repro.runtime.fault.
   StragglerDetector` events; past the configured thresholds,
@@ -125,6 +127,10 @@ class ElasticReplanner:
     """Drift/straggler-triggered re-fit + re-selection, and mesh-shrink
     recovery, over the live plan layer.
 
+    The drift it watches and re-fits from is what plan calls record while
+    ``obs.enable(drift=True)`` is on; with spans alone, or tracing off, the
+    series stay empty and only the detector can trip it.
+
     ``machine`` is the fit base (arith peak / mem bw stay; net_bw and
     hop_latency are re-fitted) — defaults to the current drift baseline.
     ``detector`` optionally wires a :class:`~repro.runtime.fault.
@@ -149,8 +155,9 @@ class ElasticReplanner:
     def should_replan(self) -> Dict[str, str]:
         """Tripped signals, ``{series_or_source: reason}`` (empty = healthy).
 
-        Reads ``obs.drift_report()`` (per-series geomean ratios) and the
-        attached detector's event log.  Respects the cooldown: trips
+        Reads ``obs.drift_report()`` (per-series geomean ratios, recorded
+        only while ``obs.enable(drift=True)`` is on) and the attached
+        detector's event log.  Respects the cooldown: trips
         inside it return empty and count ``replan.suppressed_cooldown``.
         """
         from repro import obs

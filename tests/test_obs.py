@@ -4,9 +4,14 @@ Covers the contracts the obs layer makes:
 
 * **Disabled tracing is a no-op**: ``span()`` returns one shared inert
   object, no events accumulate, and instrumented plan calls take the
-  early-return path.
+  early-return path.  Importing ``repro.obs`` imports no jax.
 * **Spans nest and are thread-safe**: interval containment and recorded
   depth reconstruct the stack; concurrent recorders lose no events.
+* **Spans are on the profiler's clock**: a span taken in a
+  ``jax.profiler`` session lies on the trace's host plane under its name,
+  where the buffered event says it does.
+* **Spans never change what the program does**: with drift off, a traced
+  plan call neither blocks nor runs the cost model.
 * **Chrome-trace schema**: ``export_trace`` round-trips through JSON with
   every event carrying ``ph``/``ts``/``dur``/``name``/``pid``/``tid``,
   and ``validate_trace`` catches violations.
@@ -16,18 +21,24 @@ Covers the contracts the obs layer makes:
   ``fit_from_registry`` recovers known machine constants from synthetic
   drift records.
 * **Instrumented plan path**: traced ``plan_matmul`` + ``MatmulPlan``
-  calls emit plan-build and per-multiply spans, record drift, and the
-  ``jax.named_scope`` wrapper adds zero retraces; the scope label
-  survives into compiled HLO (``scope_op_counts``).
+  calls emit plan-build and per-multiply spans, record drift when drift
+  recording is on, and the ``jax.named_scope`` wrapper adds zero
+  retraces; the scope label survives into compiled HLO
+  (``scope_op_counts``).  Sparse-output plans set the ``plan.real_pairs``
+  and ``plan.pair_steps`` gauges to the symbolic product's counts.
 * **Serving spans**: a ServeEngine run under tracing emits
   admission/prefill/decode-step spans.
 * **check_api timing rule**: raw paired ``perf_counter`` reads without a
   blocking call are flagged outside the allowlisted modules.
 """
+import glob
 import importlib.util
 import json
 import math
+import os
 import pathlib
+import subprocess
+import sys
 import threading
 
 import jax
@@ -133,7 +144,8 @@ def test_tracing_is_thread_safe():
 def test_export_trace_roundtrips_valid_chrome_json(tmp_path):
     obs.enable(clear=True)
     with obs.span("s", tag="v"):
-        obs.instant("marker", n=3)
+        with obs.span("marker", n=3):
+            pass
     obs.disable()
     path = tmp_path / "trace.json"
     obs.export_trace(str(path))
@@ -153,6 +165,57 @@ def test_validate_trace_flags_schema_violations():
     problems = obs.validate_trace(bad)
     assert any("missing key 'tid'" in p for p in problems)
     assert any("ts not numeric" in p for p in problems)
+
+
+def test_obs_imports_without_jax():
+    code = ("import sys\nimport repro.obs\n"
+            "assert 'jax' not in sys.modules, sorted(sys.modules)\n")
+    env = dict(os.environ, PYTHONPATH=str(pathlib.Path(__file__).resolve()
+                                          .parents[1] / "src"))
+    proc = subprocess.run([sys.executable, "-c", code], env=env,
+                          capture_output=True, text=True, timeout=120)
+    assert proc.returncode == 0, proc.stderr
+
+
+def test_span_lies_on_the_profiler_host_plane_at_its_buffered_time(tmp_path):
+    """The buffered event's ``ts``/``dur`` are on the clock of the profiler's
+    own host events: ``ts * 1e3 - profile_start_time`` is where the span's
+    annotation starts in the ``.xplane.pb``."""
+    from jax.profiler import ProfileData
+
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        obs.enable(clear=True)
+        with obs.span("obs.test.outer"):
+            with obs.span("obs.test.inner"):
+                jnp.ones((64, 64)).sum().block_until_ready()
+    finally:
+        obs.disable()
+        jax.profiler.stop_trace()
+    (path,) = glob.glob(str(tmp_path / "**" / "*.xplane.pb"), recursive=True)
+    profile = ProfileData.from_file(path)
+    (start,) = [dict(p.stats)["profile_start_time"] for p in profile.planes
+                if p.name == "Task Environment"]
+    on_host = {ev.name: (ev.start_ns, ev.end_ns)
+               for p in profile.planes if p.name.startswith("/host:")
+               for line in p.lines for ev in line.events
+               if ev.name.startswith("obs.test.")}
+    buffered = {e["name"]: e for e in obs.events()}
+    assert set(on_host) == set(buffered) == {"obs.test.outer",
+                                             "obs.test.inner"}
+    for name, (s, e) in on_host.items():
+        ev = buffered[name]
+        assert abs(ev["ts"] * 1e3 - start - s) < 1e6           # 1 ms, in ns
+        assert abs((ev["ts"] + ev["dur"]) * 1e3 - start - e) < 1e6
+
+
+def test_drift_switch_is_its_own_and_off_by_default():
+    obs.enable()
+    assert obs.enabled() and not obs.drift_enabled()
+    obs.enable(drift=True)
+    assert obs.drift_enabled()
+    obs.disable()
+    assert not obs.enabled() and not obs.drift_enabled()
 
 
 def test_clear_trace_and_enable_clear():
@@ -335,7 +398,7 @@ def test_fit_from_registry_needs_records():
 # ---------------------------------------------------------------------------
 def test_traced_plan_emits_spans_and_drift_with_zero_retraces():
     a_d, b, a_h, b_h = _g1_handles(seed=17)
-    obs.enable(clear=True)
+    obs.enable(clear=True, drift=True)
     obs.reset_drift()
     plan = api.plan_matmul(a_h, b_h, algorithm="ring_c", impl="ref",
                            cache=False)
@@ -356,6 +419,90 @@ def test_traced_plan_emits_spans_and_drift_with_zero_retraces():
     # multiply spans carry the blocking measured time
     mults = [e for e in obs.events() if e["name"] == "multiply.ring_c"]
     assert all(e["args"]["measured_s"] > 0 for e in mults)
+
+
+def test_spans_without_drift_neither_block_nor_run_the_cost_model(
+        monkeypatch):
+    """Spans on, drift off: ``plan(a, b)`` opens its multiply span around
+    the dispatch and returns; a block or a cost model would raise here."""
+    a_d, b, a_h, b_h = _g1_handles(seed=23)
+    plan = api.plan_matmul(a_h, b_h, algorithm="ring_c", impl="ref",
+                           cache=False)
+
+    def refuse(*a, **k):
+        raise AssertionError("a traced plan call blocked or ran the cost "
+                             "model with drift recording off")
+
+    monkeypatch.setattr(jax, "block_until_ready", refuse)
+    monkeypatch.setattr(obs, "sync_elapsed", refuse)
+    monkeypatch.setattr(api.MatmulPlan, "cost_model", refuse)
+    obs.enable(clear=True)
+    outs = [plan(a_h, b_h) for _ in range(3)]
+    obs.disable()
+    monkeypatch.undo()
+    np.testing.assert_allclose(np.asarray(outs[-1]), a_d @ b, rtol=0,
+                               atol=1e-4)
+    mults = [e for e in obs.events() if e["name"] == "multiply.ring_c"]
+    assert len(mults) == 3
+    assert all("measured_s" not in e["args"] for e in mults)
+    assert obs.drift_records() == []
+    assert plan.traces == 1
+
+
+_PLAN_COUNTS = """
+import json, sys
+from repro.runtime.platform import set_host_device_count
+set_host_device_count(4)
+from repro import obs
+from repro.core import api
+from repro.core.bsr import random_sparse
+from repro.kernels.bsr_spmm import list_chunk
+
+out = {}
+for g, m, density in ((1, 256, 0.2), (2, 64, 0.3)):
+    a = api.DistBSR.from_dense(random_sparse(m, m, density, seed=3 + g),
+                               g=g, block_size=4)
+    plan = api.plan_matmul(a, a, algorithm="ring_c", output="sparse",
+                           impl="ref", cache=False)
+    snap = obs.registry().snapshot()
+    sym = plan.symbolic
+    out[g] = {"real_pairs": snap["plan.real_pairs"]["algorithm=ring_c"],
+              "pair_steps": snap["plan.pair_steps"]["algorithm=ring_c"],
+              "sym_real_pairs": sym.total_real_pairs(),
+              "n_real_pairs_sum": int(sym.n_real_pairs.sum()),
+              "pair_capacity": sym.pair_capacity,
+              "list_shape": list(sym.pair_a.shape),
+              "chunk": list_chunk(3)}
+print(json.dumps(out))
+"""
+
+
+@pytest.fixture(scope="module")
+def plan_counts():
+    """Gauges and symbolic counts of a g=1 and a g=2 sparse-output plan,
+    built on four virtual CPU devices in a process of their own."""
+    env = dict(os.environ, PYTHONPATH=str(pathlib.Path(__file__).resolve()
+                                          .parents[1] / "src"))
+    env.pop("XLA_FLAGS", None)
+    proc = subprocess.run([sys.executable, "-c", _PLAN_COUNTS], env=env,
+                          capture_output=True, text=True, timeout=600)
+    assert proc.returncode == 0, proc.stderr
+    return json.loads(proc.stdout.strip().splitlines()[-1])
+
+
+@pytest.mark.parametrize("g,chunked", [(1, True), (2, False)])
+def test_plan_gauges_count_real_pairs_and_grid_steps(plan_counts, g,
+                                                     chunked):
+    c = plan_counts[str(g)]
+    assert c["real_pairs"] == c["sym_real_pairs"] == c["n_real_pairs_sum"]
+    assert c["list_shape"] == [g, g, g, c["pair_capacity"]]
+    # every device runs g kernel calls over lists of one uniform length
+    assert c["pair_steps"] == g * c["pair_capacity"]
+    assert (c["pair_capacity"] > c["chunk"]) == chunked
+    if chunked:
+        assert c["pair_capacity"] % c["chunk"] == 0
+    # real pairs fill at most every grid step of every device
+    assert 0 < c["real_pairs"] <= c["pair_steps"] * g * g
 
 
 def test_untraced_plan_records_nothing():

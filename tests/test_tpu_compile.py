@@ -87,3 +87,47 @@ def test_chunked_pair_accumulate_compiles_past_smem(one_chip):
     # one aliased output buffer: the result is n_slots blocks, not more
     out_bytes = compiled.memory_analysis().output_size_in_bytes
     assert out_bytes == n_slots * BS * BS * np.dtype(np.float32).itemsize
+
+
+def _lower_kernel(kernel, one_chip):
+    """A small call of each kernel; the chunked ones get lists of three
+    chunks, so a first call and a loop of calls."""
+    bs, stored, n_slots = 128, 600, 512
+    blocks = _sds((stored, bs, bs), jnp.float32, one_chip)
+    if kernel == "bsr_pair_accumulate":
+        pairs = 3 * bsr_spmm.list_chunk(3)
+        return jax.jit(
+            lambda a, b, pa, pb, ps: bsr_spmm.bsr_pair_accumulate_pallas(
+                a, b, pa, pb, ps, n_slots=n_slots),
+        ).lower(blocks, blocks,
+                *(_sds((pairs,), jnp.int32, one_chip) for _ in range(3)))
+    if kernel == "bsr_spmm":
+        steps = 3 * bsr_spmm.list_chunk(2)
+        return jax.jit(
+            lambda a, rows, cols, dense: bsr_spmm.bsr_spmm_pallas(
+                a, rows, cols, dense, n_block_rows=n_slots, block_n=256,
+                chunked=True),
+        ).lower(_sds((steps, bs, bs), jnp.float32, one_chip),
+                *(_sds((steps,), jnp.int32, one_chip) for _ in range(2)),
+                _sds((stored * bs, 256), jnp.float32, one_chip))
+    return jax.jit(
+        lambda a, b, pa, pb, pr, pc: bsr_spmm.bsr_pair_matmul_pallas(
+            a, b, pa, pb, pr, pc, n_block_rows=8, n_block_cols=8),
+    ).lower(blocks, blocks,
+            *(_sds((4096,), jnp.int32, one_chip) for _ in range(4)))
+
+
+@pytest.mark.parametrize("kernel,calls", [("bsr_pair_accumulate", 2),
+                                          ("bsr_spmm", 2),
+                                          ("bsr_pair_matmul", 1)])
+def test_kernel_calls_carry_their_stable_name(one_chip, kernel, calls):
+    """Every Pallas call of a kernel, the chunk loop's included, compiles to
+    an instruction named ``<kernel>.<n>``: the name a profiler trace shows
+    for it (the benchmark's kernel-time readers select ops by it)."""
+    import re
+
+    text = _lower_kernel(kernel, one_chip).compile().as_text()
+    names = re.findall(r"^\s*(?:ROOT )?%(\S+) = .*custom_call_target="
+                       r"\"tpu_custom_call\"", text, re.M)
+    assert len(names) == calls, names
+    assert all(re.fullmatch(rf"{kernel}\.\d+", n) for n in names), names

@@ -265,9 +265,10 @@ def fit_from_registry(base=None) -> Tuple[object, Dict]:
     """Re-fit (net_bw, hop_latency) from the live obs drift series.
 
     The observed-step-time loop: any process that executed plans under
-    ``obs.enable()`` has per-multiply measurements (with their cost-model
-    dicts) sitting in ``obs.drift_records()`` — this fits a Machine from
-    them directly, no bench JSON round-trip.  Raises ValueError with
+    ``obs.enable(drift=True)`` (spans alone record no drift) has
+    per-multiply measurements (with their cost-model dicts) sitting in
+    ``obs.drift_records()`` — this fits a Machine from them directly, no
+    bench JSON round-trip.  Raises ValueError with
     fewer than two usable records, like :func:`fit`.
     """
     from repro import obs
