@@ -87,6 +87,7 @@ from jax.sharding import PartitionSpec as P
 from .. import obs as _obs
 from ..kernels import ops as kops
 from ..kernels import ref as kref
+from ..kernels.bsr_spmm import pair_grid_steps
 from . import roofline as _roofline
 from . import schedule as _schedule
 from . import steal3d as _steal3d
@@ -2282,15 +2283,20 @@ class MatmulPlan:
                 self._c_cols = jnp.asarray(symbolic.c_cols, dtype=jnp.int32)
                 self._c_counts = jnp.asarray(symbolic.c_counts,
                                              dtype=jnp.int32)
-            # What a product's kernel calls will do: every device runs g
-            # steps of one pair list each (uniform length, so the busiest
-            # device runs as many grid steps as any); real pairs are the
-            # products of two real blocks, over all devices.
+            # What a product's kernel calls will do: every device walks g
+            # pair lists of one uniform length (so the busiest device walks
+            # as many entries as any), in grid steps of pair_group entries
+            # each; real pairs are the products of two real blocks, over
+            # all devices.
             reg = _obs.registry()
             reg.gauge("plan.real_pairs", algorithm=algorithm.name).set(
                 symbolic.total_real_pairs())
             reg.gauge("plan.pair_steps", algorithm=algorithm.name).set(
                 geom.g * symbolic.pair_capacity)
+            reg.gauge("plan.pair_grid_steps", algorithm=algorithm.name).set(
+                geom.g * pair_grid_steps(
+                    symbolic.pair_capacity, symbolic.block_size,
+                    jnp.promote_types(a_key[-1], b_key[-1])))
 
             def fn(a, b, pairs):
                 self.traces += 1          # runs at trace time only

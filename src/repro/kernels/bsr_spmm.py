@@ -5,8 +5,11 @@ MXU-block granularity (``bs x bs`` dense blocks, bs=128 in production), and
 the CSR structure arrays become *scalar-prefetch* operands that steer the
 BlockSpec index maps.  The grid walks the stored-block list with the reduction
 innermost, so revisits of an output block are consecutive and accumulate in
-VMEM (classic grouped-matmul pattern); double-buffering of the streamed A
-blocks and B column panels is done by the Pallas pipeline automatically.
+VMEM (classic grouped-matmul pattern); in the SpMM and dense-tile kernels the
+Pallas pipeline double-buffers the streamed blocks and column panels.  The
+pair-accumulate kernel instead takes :func:`pair_group` list entries per grid
+step and fetches their operand blocks with its own double-buffered DMAs, so
+the fixed cost of a grid step is paid once per group, not once per pair.
 
 Three kernels:
 
@@ -44,8 +47,9 @@ from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-__all__ = ["SMEM_LIST_BYTES", "list_chunk", "bsr_spmm_pallas",
-           "bsr_pair_matmul_pallas", "bsr_pair_accumulate_pallas"]
+__all__ = ["SMEM_LIST_BYTES", "list_chunk", "pair_group", "pair_grid_steps",
+           "bsr_spmm_pallas", "bsr_pair_matmul_pallas",
+           "bsr_pair_accumulate_pallas"]
 
 # Bytes of int32 scalar-prefetch lists one pallas_call may hold in SMEM
 # (1 MiB on v5e); the rest is left to the kernel's own scalars.
@@ -225,18 +229,138 @@ def bsr_pair_matmul_pallas(a_blocks, b_blocks, pair_a, pair_b, pair_rows,
 # ---------------------------------------------------------------------------
 # Sparse-output SpGEMM inner: C_blocks[ps[s]] += A_blocks[pa[s]] @ B_blocks[pb[s]]
 # ---------------------------------------------------------------------------
-def _pair_acc_kernel(pa_ref, pb_ref, ps_ref, a_ref, b_ref, *refs):
-    c_ref = refs[-1]                  # refs[0] is the aliased input, if any
+# VMEM the pair-accumulate kernel's buffers may take per call (two halves of
+# G A blocks, G B blocks and G float32 running sums), leaving room in
+# Mosaic's default scoped VMEM on a v5e.
+PAIR_VMEM_BYTES = 12 * 1024 * 1024
+MAX_PAIR_GROUP = 64
+
+
+def pair_group(bs: int, dtype) -> int:
+    """List entries one grid step of :func:`bsr_pair_accumulate_pallas`
+    takes for ``bs x bs`` operand blocks of ``dtype``: as many as two
+    halves of operand blocks and float32 sums fit in ``PAIR_VMEM_BYTES``,
+    within ``[1, MAX_PAIR_GROUP]``."""
+    per_entry = 2 * bs * bs * (2 * jnp.dtype(dtype).itemsize + 4)
+    return max(1, min(MAX_PAIR_GROUP, PAIR_VMEM_BYTES // per_entry))
+
+
+def pair_grid_steps(n_entries: int, bs: int, dtype) -> int:
+    """Grid steps :func:`bsr_pair_accumulate_pallas` runs over a list of
+    ``n_entries`` (in the chunked layout when longer than one call's SMEM
+    budget): ``cdiv(L, G)`` for each chunk of ``L`` entries."""
+    chunk, group = list_chunk(3), pair_group(bs, dtype)
+    if n_entries <= chunk:
+        return pl.cdiv(n_entries, group)
+    return n_entries // chunk * pl.cdiv(chunk, group)
+
+
+def _pair_acc_kernel(pa_ref, pb_ref, ps_ref, a_hbm, b_hbm, *refs, group,
+                     length):
+    """Grid step ``s``: the ``group`` list entries from ``s * group`` on.
+
+    Operand blocks stay in HBM.  The kernel DMAs each entry's A and B
+    block into one half of a two-half ring, and fetches group ``s + 1``
+    into the other half while group ``s`` is multiplied.  The products are
+    summed in list order into a running sum that restarts at each new slot
+    and carries over from the previous group's last entry (the first entry
+    of a call always starts a slot).  ``sums[h, i]``
+    keeps the running sum after entry ``i``; a slot's block is written to
+    HBM by DMA from its last entry's place.  ``writes[h]`` counts the
+    writes in flight from half ``h``, waited for before ``sums[h]`` is
+    overwritten.  The group is unrolled, so its products issue back to
+    back with no branch between them.
+    """
+    c_hbm, a_ring, b_ring, sums, writes, in_sem, out_sem = refs[-7:]
     s = pl.program_id(0)
-    prev = ps_ref[jnp.maximum(s - 1, 0)]
-    is_first = jnp.logical_or(s == 0, ps_ref[s] != prev)
+    n_steps = pl.cdiv(length, group)
+    half = s % 2
 
-    @pl.when(is_first)
-    def _zero():
-        c_ref[...] = jnp.zeros_like(c_ref)
+    def operand_copies(h, i, pa=0, pb=0):
+        return (pltpu.make_async_copy(a_hbm.at[pa], a_ring.at[h, i],
+                                      in_sem.at[0, h]),
+                pltpu.make_async_copy(b_hbm.at[pb], b_ring.at[h, i],
+                                      in_sem.at[1, h]))
 
-    c_ref[...] += jnp.dot(a_ref[0], b_ref[0],
-                          preferred_element_type=jnp.float32)
+    def entries(step):
+        return jnp.minimum(group, length - step * group)
+
+    def fetch(step, h):
+        def start(i, _):
+            p = step * group + i
+            for copy in operand_copies(h, i, pa_ref[p], pb_ref[p]):
+                copy.start()
+
+        lax.fori_loop(0, entries(step), start, None)
+
+    def write(h, i=0, slot=0):
+        return pltpu.make_async_copy(sums.at[h, i], c_hbm.at[slot],
+                                     out_sem.at[h])
+
+    def drain(h):
+        def wait(_, __):
+            write(h).wait()
+
+        lax.fori_loop(0, writes[h], wait, None)
+        writes[h] = 0
+
+    @pl.when(s == 0)
+    def _():
+        writes[0], writes[1] = 0, 0
+        fetch(0, 0)
+
+    @pl.when(s + 1 < n_steps)
+    def _():
+        fetch(s + 1, 1 - half)
+
+    def wait_operands(i, _):
+        for copy in operand_copies(half, i):
+            copy.wait()
+
+    lax.fori_loop(0, entries(s), wait_operands, None)
+    drain(half)
+
+    def multiply(n):
+        # Unrolled at trace time, so each entry is kept to a few lax ops.
+        base = s * group
+        run = sums[1 - half, group - 1]
+        zero = jnp.zeros_like(run)
+        slot = ps_ref[jnp.maximum(base - 1, 0)]
+        for i in range(n):
+            prev, slot = slot, ps_ref[lax.add(base, i)]
+            first = lax.ne(prev, slot)
+            if i == 0:
+                first = lax.bitwise_or(first, s == 0)
+            run = lax.add(
+                lax.select(lax.broadcast(first, run.shape), zero, run),
+                jnp.dot(a_ring[half, i], b_ring[half, i],
+                        preferred_element_type=jnp.float32))
+            sums[half, i] = run
+
+    tail = length - (n_steps - 1) * group
+    if tail == group:
+        multiply(group)
+    else:
+        pl.when(s < n_steps - 1)(lambda: multiply(group))
+        pl.when(s == n_steps - 1)(lambda: multiply(tail))
+
+    def write_ends(i, _):
+        p = s * group + i
+        slot = ps_ref[p]
+        last = jnp.logical_or(p == length - 1,
+                              ps_ref[jnp.minimum(p + 1, length - 1)] != slot)
+
+        @pl.when(last)
+        def _():
+            write(half, i, slot).start()
+            writes[half] += 1
+
+    lax.fori_loop(0, entries(s), write_ends, None)
+
+    @pl.when(s == n_steps - 1)
+    def _():
+        drain(0)
+        drain(1)
 
 
 @functools.partial(
@@ -250,30 +374,41 @@ def bsr_pair_accumulate_pallas(a_blocks, b_blocks, pair_a, pair_b, pair_slot,
     pair_slot : i32[P] — output slot per pair, NONDECREASING; every slot in
                 ``[0, n_slots)`` must appear at least once (the symbolic
                 phase emits one coverage pair per slot), because an output
-                block is zeroed on its first visit only.
+                block is written once, when its last pair is summed.
     Padding pairs must reference zero blocks and repeat the final slot.
     Lists longer than ``list_chunk(3)`` must be in the chunked layout
-    (module docstring).
+    (module docstring).  Each grid step takes ``pair_group`` entries.
     Returns f32[n_slots, bs, bs]; the caller casts to the output dtype.
     """
     bs = a_blocks.shape[1]
+    group = pair_group(bs, jnp.promote_types(a_blocks.dtype, b_blocks.dtype))
 
     def call(lists, off, out):
+        length = lists[0].shape[0]
+        hbm = pl.BlockSpec(memory_space=pl.ANY)
         in_specs, operands, aliases = _with_out(
-            [pl.BlockSpec((1, bs, bs), lambda s, pa, pb, ps: (pa[s], 0, 0)),
-             pl.BlockSpec((1, bs, bs), lambda s, pa, pb, ps: (pb[s], 0, 0))],
-            [a_blocks, b_blocks], out, 3)
+            [hbm, hbm], [a_blocks, b_blocks], out, 3)
         return pl.pallas_call(
-            _pair_acc_kernel,
+            functools.partial(_pair_acc_kernel, group=group, length=length),
             name="bsr_pair_accumulate",
             grid_spec=pltpu.PrefetchScalarGridSpec(
                 num_scalar_prefetch=3,    # pair_a, pair_b, pair_slot
-                grid=(lists[0].shape[0],),
+                grid=(pl.cdiv(length, group),),
                 in_specs=in_specs,
-                out_specs=pl.BlockSpec((1, bs, bs),
-                                       lambda s, pa, pb, ps: (ps[s], 0, 0))),
+                out_specs=hbm,
+                scratch_shapes=[
+                    pltpu.VMEM((2, group, bs, bs), a_blocks.dtype),
+                    pltpu.VMEM((2, group, bs, bs), b_blocks.dtype),
+                    pltpu.VMEM((2, group, bs, bs), jnp.float32),
+                    pltpu.SMEM((2,), jnp.int32),
+                    pltpu.SemaphoreType.DMA((2, 2)),
+                    pltpu.SemaphoreType.DMA((2,)),
+                ]),
             out_shape=jax.ShapeDtypeStruct((n_slots, bs, bs), jnp.float32),
             input_output_aliases=aliases,
+            # group s prefetches group s + 1: the steps run in order
+            compiler_params=pltpu.CompilerParams(
+                dimension_semantics=("arbitrary",)),
             interpret=interpret,
         )(*lists, *operands)
 

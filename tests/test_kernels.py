@@ -109,3 +109,107 @@ def test_pair_accumulate_packed_slots(impl):
         want[s] += blocks_a[a_i] @ blocks_b[b_i]
     np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-5)
     assert np.abs(got[1]).max() == 0.0 and np.abs(got[4]).max() == 0.0
+
+
+@pytest.fixture
+def pair_kernel_sizes(monkeypatch):
+    """Set the pair-accumulate kernel's group and chunk for f32 8x8 blocks:
+    ``set(group, chunk)`` sizes its operand ring and SMEM list budget to
+    them.  JAX's caches are cleared around the test, so no other test runs
+    the kernel at these sizes."""
+    import jax
+    from repro.kernels import bsr_spmm
+
+    def set_sizes(group, chunk):
+        monkeypatch.setattr(bsr_spmm, "PAIR_VMEM_BYTES",
+                            group * 2 * 8 * 8 * (2 * 4 + 4))
+        monkeypatch.setattr(bsr_spmm, "SMEM_LIST_BYTES", 3 * 4 * chunk)
+        assert bsr_spmm.pair_group(8, jnp.float32) == group
+        assert bsr_spmm.list_chunk(3) == chunk
+
+    jax.clear_caches()
+    yield set_sizes
+    monkeypatch.undo()
+    jax.clear_caches()
+
+
+def _pair_lists(runs, n_blocks, seed):
+    """Slot-sorted pair lists whose slot ``t`` has ``runs[t]`` entries."""
+    rng = np.random.default_rng(seed)
+    ps = np.repeat(np.arange(len(runs)), runs).astype(np.int32)
+    pa = rng.integers(0, n_blocks, len(ps)).astype(np.int32)
+    pb = rng.integers(0, n_blocks, len(ps)).astype(np.int32)
+    return pa, pb, ps
+
+
+# Slot runs against a 4-entry group; the grid steps start at 0, 4, 8, ...
+@pytest.mark.parametrize("runs", [
+    [3, 2, 4, 1],           # 10 entries: the last group takes 2
+    [1, 2, 1],              # slot changes inside the one group
+    [4, 4, 2],              # slot changes exactly at group boundaries
+    [1, 10, 1],             # slot 1 spans three groups (entries 1-10)
+    [1],                    # a one-entry list
+], ids=["not_multiple", "inside_group", "at_boundary", "three_groups",
+        "one_entry"])
+def test_pair_accumulate_groups_match_ref(pair_kernel_sizes, runs):
+    """Many list entries per grid step, slots changing anywhere in a
+    group or across groups, match the oracle exactly as summed."""
+    pair_kernel_sizes(group=4, chunk=64)
+    rng = np.random.default_rng(len(runs))
+    n_blocks, bs = 9, 8
+    a = jnp.asarray(rng.standard_normal((n_blocks, bs, bs)), jnp.float32)
+    b = jnp.asarray(rng.standard_normal((n_blocks, bs, bs)), jnp.float32)
+    pa, pb, ps = (jnp.asarray(x) for x in _pair_lists(runs, n_blocks, 1))
+    got = ops.bsr_pair_accumulate(a, b, pa, pb, ps, n_slots=len(runs),
+                                  impl="interpret")
+    want = ref.bsr_pair_accumulate_raw_ref(a, b, pa, pb, ps, len(runs))
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               rtol=1e-5, atol=1e-5)
+
+
+def test_pair_accumulate_three_chunks_match_ref(pair_kernel_sizes):
+    """A list in the chunked layout of three chunks (built by the symbolic
+    phase's chunker at a small chunk) runs as three kernel calls into one
+    output; each chunk's last group is partial."""
+    from repro.core import api
+
+    pair_kernel_sizes(group=4, chunk=10)
+    rng = np.random.default_rng(3)
+    n_blocks, bs = 9, 8
+    a_np = rng.standard_normal((n_blocks, bs, bs)).astype(np.float32)
+    b_np = rng.standard_normal((n_blocks, bs, bs)).astype(np.float32)
+    a_np[-1] = b_np[-1] = 0.0                   # the inert padding block
+    runs = [3, 5, 2, 6, 1, 4, 3]
+    pa, pb, ps = _pair_lists(runs, n_blocks - 1, 4)
+    lists = api._symbolic.chunk_pair_lists(
+        [(pa, pb, ps)], [(n_blocks - 1, n_blocks - 1)], 10)
+    pa_c, pb_c, ps_c = (jnp.asarray(x[0]) for x in lists)
+    assert pa_c.shape == (30,)
+    a, b = jnp.asarray(a_np), jnp.asarray(b_np)
+    got = ops.bsr_pair_accumulate(a, b, pa_c, pb_c, ps_c,
+                                  n_slots=len(runs), impl="interpret")
+    want = ref.bsr_pair_accumulate_raw_ref(
+        a, b, jnp.asarray(pa), jnp.asarray(pb), jnp.asarray(ps), len(runs))
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               rtol=1e-5, atol=1e-5)
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+def test_pair_accumulate_natural_group_matches_ref(dtype):
+    """At the group the block shape gives (64 for 8x8 blocks), a list of
+    three groups and a part, slots of 1 to 70 entries."""
+    from repro.kernels.bsr_spmm import pair_group
+
+    assert pair_group(8, dtype) == 64
+    rng = np.random.default_rng(5)
+    n_blocks, bs = 16, 8
+    a = jnp.asarray(rng.standard_normal((n_blocks, bs, bs)), dtype)
+    b = jnp.asarray(rng.standard_normal((n_blocks, bs, bs)), dtype)
+    runs = [1, 70, 5, 63, 64, 2, 9]
+    pa, pb, ps = (jnp.asarray(x) for x in _pair_lists(runs, n_blocks, 6))
+    assert 3 * 64 < ps.shape[0] < 4 * 64
+    got = ops.bsr_pair_accumulate(a, b, pa, pb, ps, n_slots=len(runs),
+                                  out_dtype=jnp.float32, impl="interpret")
+    want = ref.bsr_pair_accumulate_raw_ref(a, b, pa, pb, ps, len(runs))
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               rtol=1e-4, atol=1e-4)

@@ -24,8 +24,9 @@ Covers the contracts the obs layer makes:
   calls emit plan-build and per-multiply spans, record drift when drift
   recording is on, and the ``jax.named_scope`` wrapper adds zero
   retraces; the scope label survives into compiled HLO
-  (``scope_op_counts``).  Sparse-output plans set the ``plan.real_pairs``
-  and ``plan.pair_steps`` gauges to the symbolic product's counts.
+  (``scope_op_counts``).  Sparse-output plans set the ``plan.real_pairs``,
+  ``plan.pair_steps`` and ``plan.pair_grid_steps`` gauges to the symbolic
+  product's counts.
 * **Serving spans**: a ServeEngine run under tracing emits
   admission/prefill/decode-step spans.
 * **check_api timing rule**: raw paired ``perf_counter`` reads without a
@@ -456,7 +457,7 @@ set_host_device_count(4)
 from repro import obs
 from repro.core import api
 from repro.core.bsr import random_sparse
-from repro.kernels.bsr_spmm import list_chunk
+from repro.kernels.bsr_spmm import list_chunk, pair_group
 
 out = {}
 for g, m, density in ((1, 256, 0.2), (2, 64, 0.3)):
@@ -468,6 +469,9 @@ for g, m, density in ((1, 256, 0.2), (2, 64, 0.3)):
     sym = plan.symbolic
     out[g] = {"real_pairs": snap["plan.real_pairs"]["algorithm=ring_c"],
               "pair_steps": snap["plan.pair_steps"]["algorithm=ring_c"],
+              "pair_grid_steps":
+                  snap["plan.pair_grid_steps"]["algorithm=ring_c"],
+              "group": pair_group(4, "float32"),
               "sym_real_pairs": sym.total_real_pairs(),
               "n_real_pairs_sum": int(sym.n_real_pairs.sum()),
               "pair_capacity": sym.pair_capacity,
@@ -503,6 +507,21 @@ def test_plan_gauges_count_real_pairs_and_grid_steps(plan_counts, g,
         assert c["pair_capacity"] % c["chunk"] == 0
     # real pairs fill at most every grid step of every device
     assert 0 < c["real_pairs"] <= c["pair_steps"] * g * g
+
+
+@pytest.mark.parametrize("g,chunked", [(1, True), (2, False)])
+def test_plan_gauge_counts_grouped_kernel_grid_steps(plan_counts, g,
+                                                     chunked):
+    """``plan.pair_grid_steps`` is the kernel's grid steps per product on
+    each device: ``cdiv(L, G)`` for each chunk of ``L`` entries of each of
+    the g lists, ``G`` the kernel's group for the plan's blocks."""
+    c = plan_counts[str(g)]
+    cap, chunk, group = c["pair_capacity"], c["chunk"], c["group"]
+    chunks = [chunk] * (cap // chunk) if chunked else [cap]
+    assert len(chunks) >= (2 if chunked else 1)
+    assert c["pair_grid_steps"] == g * sum(-(-n // group) for n in chunks)
+    assert c["pair_steps"] == g * cap
+    assert c["real_pairs"] == c["sym_real_pairs"]
 
 
 def test_untraced_plan_records_nothing():

@@ -131,3 +131,43 @@ def test_kernel_calls_carry_their_stable_name(one_chip, kernel, calls):
                        r"\"tpu_custom_call\"", text, re.M)
     assert len(names) == calls, names
     assert all(re.fullmatch(rf"{kernel}\.\d+", n) for n in names), names
+
+
+def _pallas_calls(jaxpr):
+    """The ``pallas_call`` equations of a jaxpr, nested ones included."""
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            yield eqn
+        for p in eqn.params.values():
+            inner = getattr(p, "jaxpr", p)
+            if hasattr(inner, "eqns"):
+                yield from _pallas_calls(inner)
+
+
+def test_pair_accumulate_compiles_with_the_group_it_reports(one_chip):
+    """At 128x128 float32 blocks the pair-accumulate kernel takes
+    ``pair_group`` list entries per grid step: each chunk's call has
+    ``cdiv(chunk, G)`` grid steps and buffers of two halves of G blocks,
+    and it compiles at that size for a v5e chip."""
+    chunk = bsr_spmm.list_chunk(3)
+    group = bsr_spmm.pair_group(BS, jnp.float32)
+    assert group == 32
+    pairs, n_slots = 3 * chunk, 4096
+
+    def run(a, b, pa, pb, ps):
+        return bsr_spmm.bsr_pair_accumulate_pallas(a, b, pa, pb, ps,
+                                                   n_slots=n_slots)
+
+    args = (_sds((STORED, BS, BS), jnp.float32, one_chip),
+            _sds((STORED, BS, BS), jnp.float32, one_chip),
+            *(_sds((pairs,), jnp.int32, one_chip) for _ in range(3)))
+    calls = list(_pallas_calls(jax.make_jaxpr(run)(*args).jaxpr))
+    assert calls
+    for eqn in calls:
+        assert eqn.params["grid_mapping"].grid == (-(-chunk // group),)
+        halves = [v.aval.shape for v in eqn.params["jaxpr"].invars
+                  if v.aval.shape == (2, group, BS, BS)]
+        assert len(halves) == 3         # A blocks, B blocks, running sums
+    assert bsr_spmm.pair_grid_steps(pairs, BS, jnp.float32) == \
+        3 * -(-chunk // group)
+    assert "tpu_custom_call" in jax.jit(run).lower(*args).compile().as_text()
