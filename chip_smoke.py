@@ -14,11 +14,14 @@ b=c=d=0.4/3), scale 15 (32,768 vertices), edge factor 16, block size 128
 
 Every plan runs the Pallas kernels (``impl="pallas"``).  Every result is
 checked against an independent ``scipy.sparse`` product on the host: SpMM
-within a stated float64 error bound, SpGEMM exactly.  Lines that start
-with ``info:`` are informational (set-up and steady times, peak device
-memory, the kernel in the compiled HLO, errors); they are not a
-benchmark.  The last line is one JSON object naming the device.  Without
-a TPU the script exits non-zero before any phase and prints no result.
+within a stated float64 error bound, SpGEMM exactly.  On four chips the
+handle's tiles (checked right after ``DistBSR.from_dense``, before any
+plan), every plan's operands and its output must span all four devices.
+Lines that start with ``info:`` are informational (set-up and steady
+times, peak device memory, the kernel in the compiled HLO, errors); they
+are not a benchmark.  The last line is one JSON object naming the
+device.  Without a TPU the script exits non-zero before any phase and
+prints no result.
 """
 from __future__ import annotations
 
@@ -248,6 +251,16 @@ def main(argv=None) -> int:
           f"block={BLOCK} g={g} stored_blocks={int(np.asarray(a_h.counts).sum())}"
           f" capacity={a_h.capacity} generate_s={time.perf_counter() - t0!r}"
           f" tiling_s={t_tile!r}", flush=True)
+    # The handle itself, before any plan: tile (i, j) on device (i, j)
+    # from the start, so staging on one device cannot come back unseen.
+    mesh_ids = sorted(d.id for d in mesh.devices.flat)
+    handle_devices = _devices_of({k: getattr(a_h.tiled, k)
+                                  for k in ("blocks", "rows", "cols")})
+    print("info: " + json.dumps({"phase": "handle",
+                                 "devices": handle_devices}), flush=True)
+    if any(ids != mesh_ids for ids in handle_devices.values()):
+        raise RuntimeError(f"handle: DistBSR.from_dense's tiles not spread "
+                           f"over the mesh {mesh_ids}: {handle_devices}")
 
     runs = []
     spmm_algs = ("ring_c", "summa_ag", "steal3d") if g > 1 else ("ring_c",)
@@ -260,7 +273,6 @@ def main(argv=None) -> int:
                              algorithm="ring_c", machine=machine))
     runs[-1]["peak_bytes_in_use"] = _peak_bytes(dev)
     print("info: " + json.dumps(runs[-1]), flush=True)
-    mesh_ids = sorted(d.id for d in mesh.devices.flat)
     for r in runs:
         if not r["tpu_custom_call"]:
             raise RuntimeError(f"{r['phase']}/{r['algorithm']}: no Pallas "

@@ -87,15 +87,14 @@ from jax.sharding import PartitionSpec as P
 from .. import obs as _obs
 from ..kernels import ops as kops
 from ..kernels import ref as kref
-from ..kernels.bsr_spmm import pair_grid_steps
+from ..kernels.bsr_spmm import pair_grid_steps, spmm_block_n
 from . import roofline as _roofline
 from . import schedule as _schedule
 from . import steal3d as _steal3d
 from . import symbolic as _symbolic
 from . import wire as _wire
 from .bsr import TiledBSR
-from .dist import (make_grid_mesh, place_b_for_stationary_a, skew_bsr,
-                   skew_dense, unskew_c_rows)
+from .dist import make_grid_mesh, tile_mesh, unskew_c_rows
 from .grid import ProcessGrid, bucket_capacity, ceil_div, pad_to_multiple
 from .symbolic import (SymbolicProduct, predicted_density,  # re-export
                        symbolic_spgemm)                     # (public)
@@ -177,6 +176,20 @@ def _local_mm(a: Dict, b: Dict, geom: _Geom) -> jnp.ndarray:
 def _tree_ppermute(tree: Dict, axis: str, g: int, sign: int = 1) -> Dict:
     perm = [((d + sign) % g, d) for d in range(g)]
     return {k: lax.ppermute(v, axis, perm) for k, v in tree.items()}
+
+
+def _ring_steps(g: int, overlap: bool) -> int:
+    """Scanned steps of a ring body: every step but those after the last
+    transfer (one, or two with the two-slot buffer), which run as the
+    epilogue, so no step sends a tile that none consumes."""
+    return max(g - 2, 0) if overlap else g - 1
+
+
+def _split_steps(xs: Dict, n: int, g: int) -> Tuple[Dict, list]:
+    """Per-step maps ``xs`` (leading axis g) split into the scanned steps'
+    ``[:n]`` and one dict per epilogue step ``n .. g-1``."""
+    return ({k: v[:n] for k, v in xs.items()},
+            [{k: v[t] for k, v in xs.items()} for t in range(n, g)])
 
 
 def _tree_bcast(tree: Dict, axis: str, root, my_idx) -> Dict:
@@ -635,39 +648,37 @@ def _sparse_body_ring_c(a, b, pairs, geom: _Geom):
     ring in stored block form (its densified tile never exists), and the
     scanned step consumes the step-scheduled pair lists as scan inputs.
     """
+    def mm(c, a_t, b_t, xs):
+        return c + _sparse_step(a_t, b_t, xs["pa"], xs["pb"], xs["ps"], geom)
+
+    # pair list t pairs with the tile of generation t; the steps after the
+    # last transfer take theirs in the epilogue
+    xs, tail = _split_steps(pairs, _ring_steps(geom.g, geom.overlap), geom.g)
     if geom.overlap:
-        # two-slot double buffer (see _body_ring_c); scan input t pairs
-        # with the tile of generation t, so the xs are sliced to g-1 and
-        # the last pair list feeds the epilogue accumulate.
+        # two-slot double buffer (see _body_ring_c)
         a_f = _tree_ppermute(a, geom.axc, geom.g)
         b_f = _tree_ppermute(b, geom.axr, geom.g)
 
         def step(carry, xs):
             a_t, b_t, a_f, b_f, c = carry
-            pa, pb, ps = xs
             a_n = _tree_ppermute(a_f, geom.axc, geom.g)
             b_n = _tree_ppermute(b_f, geom.axr, geom.g)
-            c = c + _sparse_step(a_t, b_t, pa, pb, ps, geom)
-            return (a_f, b_f, a_n, b_n, c), None
+            return (a_f, b_f, a_n, b_n, mm(c, a_t, b_t, xs)), None
 
-        (a_l, b_l, _, _, c), _ = lax.scan(
-            step, (a, b, a_f, b_f, _sparse_c0(a, geom)),
-            (pairs["pa"][:-1], pairs["pb"][:-1], pairs["ps"][:-1]))
-        c = c + _sparse_step(a_l, b_l, pairs["pa"][-1], pairs["pb"][-1],
-                             pairs["ps"][-1], geom)
+        (a_t, b_t, a_f, b_f, c), _ = lax.scan(
+            step, (a, b, a_f, b_f, _sparse_c0(a, geom)), xs)
+        for (a_t, b_t), x in zip(((a_t, b_t), (a_f, b_f)), tail):
+            c = mm(c, a_t, b_t, x)
         return c.astype(geom.out_dtype)
 
     def step(carry, xs):
         a_t, b_t, c = carry
-        pa, pb, ps = xs
         a_n = _tree_ppermute(a_t, geom.axc, geom.g)   # prefetch (paper SS3.3)
         b_n = _tree_ppermute(b_t, geom.axr, geom.g)
-        c = c + _sparse_step(a_t, b_t, pa, pb, ps, geom)
-        return (a_n, b_n, c), None
+        return (a_n, b_n, mm(c, a_t, b_t, xs)), None
 
-    (_, _, c), _ = lax.scan(step, (a, b, _sparse_c0(a, geom)),
-                            (pairs["pa"], pairs["pb"], pairs["ps"]))
-    return c.astype(geom.out_dtype)
+    (a_t, b_t, c), _ = lax.scan(step, (a, b, _sparse_c0(a, geom)), xs)
+    return mm(c, a_t, b_t, tail[0]).astype(geom.out_dtype)
 
 
 # ---------------------------------------------------------------------------
@@ -705,12 +716,18 @@ def _packed_body_ring_c(a, b, aux, geom: _Geom):
     if b_packed:
         xs["bd"] = aux["b_dmap"]
     c0 = _pvary(jnp.zeros((geom.tm, geom.tn), dtype=geom.out_dtype), geom)
+
+    def mm(c, a_blk, b_buf, xs):
+        b_dense = _packed_b_dense(b_buf, xs["bd"], geom) if b_packed \
+            else b_buf
+        return c + _packed_a_mm(a_blk, xs["ag"], xs["ar"], xs["ac"],
+                                b_dense, geom)
+
+    # consume maps for step t pair with tile generation t; the steps after
+    # the last transfer take theirs in the epilogue
+    xs, tail = _split_steps(xs, _ring_steps(geom.g, geom.overlap), geom.g)
     if geom.overlap:
-        # two-slot double buffer (see _body_ring_c); consume maps for
-        # step t pair with tile generation t, so xs slice to g-1 and the
-        # final maps feed the epilogue accumulate.
-        last = {k: v[-1] for k, v in xs.items()}
-        xs = {k: v[:-1] for k, v in xs.items()}
+        # two-slot double buffer (see _body_ring_c)
         a_f = lax.ppermute(a["blocks"], geom.axc, _ring_perm(geom.g))
         b_f = lax.ppermute(b0, geom.axr, _ring_perm(geom.g))
 
@@ -718,30 +735,22 @@ def _packed_body_ring_c(a, b, aux, geom: _Geom):
             a_blk, b_buf, a_f, b_f, c = carry
             a_n = lax.ppermute(a_f, geom.axc, _ring_perm(geom.g))
             b_n = lax.ppermute(b_f, geom.axr, _ring_perm(geom.g))
-            b_dense = _packed_b_dense(b_buf, xs["bd"], geom) if b_packed \
-                else b_buf
-            c = c + _packed_a_mm(a_blk, xs["ag"], xs["ar"], xs["ac"],
-                                 b_dense, geom)
-            return (a_f, b_f, a_n, b_n, c), None
+            return (a_f, b_f, a_n, b_n, mm(c, a_blk, b_buf, xs)), None
 
-        (a_l, b_l, _, _, c), _ = lax.scan(
+        (a_l, b_l, a_f, b_f, c), _ = lax.scan(
             step, (a["blocks"], b0, a_f, b_f, c0), xs)
-        b_dense = _packed_b_dense(b_l, last["bd"], geom) if b_packed else b_l
-        return c + _packed_a_mm(a_l, last["ag"], last["ar"], last["ac"],
-                                b_dense, geom)
+        for (a_t, b_t), x in zip(((a_l, b_l), (a_f, b_f)), tail):
+            c = mm(c, a_t, b_t, x)
+        return c
 
     def step(carry, xs):
         a_blk, b_buf, c = carry
         a_n = lax.ppermute(a_blk, geom.axc, _ring_perm(geom.g))  # prefetch
         b_n = lax.ppermute(b_buf, geom.axr, _ring_perm(geom.g))
-        b_dense = _packed_b_dense(b_buf, xs["bd"], geom) if b_packed \
-            else b_buf
-        c = c + _packed_a_mm(a_blk, xs["ag"], xs["ar"], xs["ac"], b_dense,
-                             geom)
-        return (a_n, b_n, c), None
+        return (a_n, b_n, mm(c, a_blk, b_buf, xs)), None
 
-    (_, _, c), _ = lax.scan(step, (a["blocks"], b0, c0), xs)
-    return c
+    (a_l, b_l, c), _ = lax.scan(step, (a["blocks"], b0, c0), xs)
+    return mm(c, a_l, b_l, tail[0])
 
 
 def _packed_body_ring_c_bidir(a, b, aux, geom: _Geom):
@@ -760,11 +769,17 @@ def _packed_body_ring_c_bidir(a, b, aux, geom: _Geom):
     c_l0 = _pvary(jnp.zeros((geom.tm, half), dtype=geom.out_dtype), geom)
     c_r0 = _pvary(jnp.zeros((geom.tm, geom.tn - half),
                             dtype=geom.out_dtype), geom)
+
+    def mm(c_l, c_r, a_f, a_b, b_f, b_b, xs):
+        return (c_l + _packed_a_mm(a_f, xs["fg"], xs["fr"], xs["fc"], b_f,
+                                   geom),
+                c_r + _packed_a_mm(a_b, xs["bg"], xs["br"], xs["bc"], b_b,
+                                   geom))
+
+    # consume maps sliced so step t's maps meet tile generation t
+    xs, tail = _split_steps(xs, _ring_steps(geom.g, geom.overlap), geom.g)
     if geom.overlap:
-        # four streams x two slots (see _body_ring_c_bidir), consume maps
-        # sliced so step t's maps meet tile generation t
-        last = {k: v[-1] for k, v in xs.items()}
-        xs = {k: v[:-1] for k, v in xs.items()}
+        # four streams x two slots (see _body_ring_c_bidir)
         a_ff = lax.ppermute(a["blocks"], geom.axc, _ring_perm(geom.g, +1))
         a_bf = lax.ppermute(a["blocks"], geom.axc, _ring_perm(geom.g, -1))
         b_ff = lax.ppermute(b_fwd, geom.axr, _ring_perm(geom.g, +1))
@@ -776,20 +791,16 @@ def _packed_body_ring_c_bidir(a, b, aux, geom: _Geom):
             a_bn = lax.ppermute(a_bf, geom.axc, _ring_perm(geom.g, -1))
             b_fn = lax.ppermute(b_ff, geom.axr, _ring_perm(geom.g, +1))
             b_bn = lax.ppermute(b_bf, geom.axr, _ring_perm(geom.g, -1))
-            c_l = c_l + _packed_a_mm(a_f, xs["fg"], xs["fr"], xs["fc"],
-                                     b_f, geom)
-            c_r = c_r + _packed_a_mm(a_b, xs["bg"], xs["br"], xs["bc"],
-                                     b_b, geom)
+            c_l, c_r = mm(c_l, c_r, a_f, a_b, b_f, b_b, xs)
             return (a_ff, a_bf, b_ff, b_bf, a_fn, a_bn, b_fn, b_bn,
                     c_l, c_r), None
 
-        (a_fl, a_bl, b_fl, b_bl, _, _, _, _, c_l, c_r), _ = lax.scan(
+        (a_f, a_b, b_f, b_b, a_ff, a_bf, b_ff, b_bf, c_l, c_r), _ = lax.scan(
             step, (a["blocks"], a["blocks"], b_fwd, b_bwd,
                    a_ff, a_bf, b_ff, b_bf, c_l0, c_r0), xs)
-        c_l = c_l + _packed_a_mm(a_fl, last["fg"], last["fr"], last["fc"],
-                                 b_fl, geom)
-        c_r = c_r + _packed_a_mm(a_bl, last["bg"], last["br"], last["bc"],
-                                 b_bl, geom)
+        for tiles, x in zip(((a_f, a_b, b_f, b_b), (a_ff, a_bf, b_ff, b_bf)),
+                            tail):
+            c_l, c_r = mm(c_l, c_r, *tiles, x)
         return jnp.concatenate([c_l, c_r], axis=1)
 
     def step(carry, xs):
@@ -798,14 +809,12 @@ def _packed_body_ring_c_bidir(a, b, aux, geom: _Geom):
         a_bn = lax.ppermute(a_b, geom.axc, _ring_perm(geom.g, -1))
         b_fn = lax.ppermute(b_f, geom.axr, _ring_perm(geom.g, +1))
         b_bn = lax.ppermute(b_b, geom.axr, _ring_perm(geom.g, -1))
-        c_l = c_l + _packed_a_mm(a_f, xs["fg"], xs["fr"], xs["fc"], b_f,
-                                 geom)
-        c_r = c_r + _packed_a_mm(a_b, xs["bg"], xs["br"], xs["bc"], b_b,
-                                 geom)
+        c_l, c_r = mm(c_l, c_r, a_f, a_b, b_f, b_b, xs)
         return (a_fn, a_bn, b_fn, b_bn, c_l, c_r), None
 
-    (_, _, _, _, c_l, c_r), _ = lax.scan(
+    (a_f, a_b, b_f, b_b, c_l, c_r), _ = lax.scan(
         step, (a["blocks"], a["blocks"], b_fwd, b_bwd, c_l0, c_r0), xs)
+    c_l, c_r = mm(c_l, c_r, a_f, a_b, b_f, b_b, tail[0])
     return jnp.concatenate([c_l, c_r], axis=1)
 
 
@@ -818,6 +827,16 @@ def _packed_body_ring_a(a, b, aux, geom: _Geom):
     ROADMAP's sparse-output ring_a item).
     """
     acc0 = _pvary(jnp.zeros((geom.tm, geom.tn), dtype=geom.out_dtype), geom)
+
+    def mm_hop(acc, b_blk, bd):
+        # accumulate, then route the partial C tile one hop toward its
+        # owner: g hops in all, the last one home
+        acc = acc + _local_mm(a, {"dense": _packed_b_dense(b_blk, bd, geom)},
+                              geom)
+        return lax.ppermute(acc, geom.axc, _ring_perm(geom.g))
+
+    bds, tail = _split_steps({"bd": aux["b_dmap"]},
+                             _ring_steps(geom.g, geom.overlap), geom.g)
     if geom.overlap:
         # B stream two-slot only — the accumulator ring is a serial
         # dependence chain and cannot be double-buffered (see _body_ring_a)
@@ -826,28 +845,21 @@ def _packed_body_ring_a(a, b, aux, geom: _Geom):
         def step(carry, bd):
             b_blk, b_f, acc = carry
             b_n = lax.ppermute(b_f, geom.axr, _ring_perm(geom.g))
-            acc = acc + _local_mm(
-                a, {"dense": _packed_b_dense(b_blk, bd, geom)}, geom)
-            acc = lax.ppermute(acc, geom.axc, _ring_perm(geom.g))
-            return (b_f, b_n, acc), None
+            return (b_f, b_n, mm_hop(acc, b_blk, bd)), None
 
-        (b_l, _, acc), _ = lax.scan(step, (b["blocks"], b_f, acc0),
-                                    aux["b_dmap"][:-1])
-        acc = acc + _local_mm(
-            a, {"dense": _packed_b_dense(b_l, aux["b_dmap"][-1], geom)},
-            geom)
-        return lax.ppermute(acc, geom.axc, _ring_perm(geom.g))
+        (b_l, b_f, acc), _ = lax.scan(step, (b["blocks"], b_f, acc0),
+                                      bds["bd"])
+        for b_t, x in zip((b_l, b_f), tail):
+            acc = mm_hop(acc, b_t, x["bd"])
+        return acc
 
     def step(carry, bd):
         b_blk, acc = carry
         b_n = lax.ppermute(b_blk, geom.axr, _ring_perm(geom.g))  # prefetch
-        acc = acc + _local_mm(a, {"dense": _packed_b_dense(b_blk, bd, geom)},
-                              geom)
-        acc = lax.ppermute(acc, geom.axc, _ring_perm(geom.g))
-        return (b_n, acc), None
+        return (b_n, mm_hop(acc, b_blk, bd)), None
 
-    (_, acc), _ = lax.scan(step, (b["blocks"], acc0), aux["b_dmap"])
-    return acc
+    (b_l, acc), _ = lax.scan(step, (b["blocks"], acc0), bds["bd"])
+    return mm_hop(acc, b_l, tail[0]["bd"])
 
 
 def _packed_body_summa_ag(a, b, aux, geom: _Geom):
@@ -1067,14 +1079,15 @@ def _body_ring_c(a, b, geom: _Geom):
     """Paper Alg 2 (stationary-C): skewed placement + neighbour ppermute."""
     b = _densify_b(b, geom)
     c0 = _pvary(jnp.zeros((geom.tm, geom.tn), dtype=geom.out_dtype), geom)
+    n = _ring_steps(geom.g, geom.overlap)
     if geom.overlap:
         # Split-step double buffer: the carry holds the tile being
         # consumed AND the tile in flight, so the transfer consumed at
         # step t+1 was issued at step t-1 — a full local matmul of slack
         # for the collective-permute DMA.  The prologue issues step 1's
-        # transfer, the scan runs g-1 steps, and the epilogue accumulates
-        # the last tile with nothing left to prefetch: g permutes per
-        # stream total, exactly the bulk body's wire traffic.
+        # transfer, the scan runs the steps that still have a tile to
+        # issue, and the epilogue accumulates the last two tiles: g-1
+        # permutes per stream, exactly the bulk body's wire traffic.
         a_f = _tree_ppermute(a, geom.axc, geom.g)
         b_f = _tree_ppermute(b, geom.axr, geom.g)
 
@@ -1085,9 +1098,10 @@ def _body_ring_c(a, b, geom: _Geom):
             c = c + _local_mm(a_t, b_t, geom)
             return (a_f, b_f, a_n, b_n, c), None
 
-        (a_l, b_l, _, _, c), _ = lax.scan(step, (a, b, a_f, b_f, c0), None,
-                                          length=geom.g - 1)
-        return c + _local_mm(a_l, b_l, geom)
+        (a_t, b_t, a_f, b_f, c), _ = lax.scan(step, (a, b, a_f, b_f, c0),
+                                              None, length=n)
+        c = c + _local_mm(a_t, b_t, geom)
+        return c + _local_mm(a_f, b_f, geom) if geom.g > 1 else c
 
     def step(carry, _):
         a_t, b_t, c = carry
@@ -1098,8 +1112,9 @@ def _body_ring_c(a, b, geom: _Geom):
         c = c + _local_mm(a_t, b_t, geom)
         return (a_n, b_n, c), None
 
-    (_, _, c), _ = lax.scan(step, (a, b, c0), None, length=geom.g)
-    return c
+    # the last step has no tile left to fetch: it runs after the scan
+    (a_t, b_t, c), _ = lax.scan(step, (a, b, c0), None, length=n)
+    return c + _local_mm(a_t, b_t, geom)
 
 
 @register_algorithm("ring_a", b_placement=STATIONARY_A, unskew_out="rows",
@@ -1110,6 +1125,15 @@ def _body_ring_a(a, b, geom: _Geom):
     """Paper Alg 1 (stationary-A): B rides the ring, partial C rides back."""
     b = _densify_b(b, geom)
     acc0 = _pvary(jnp.zeros((geom.tm, geom.tn), dtype=geom.out_dtype), geom)
+    n = _ring_steps(geom.g, geom.overlap)
+
+    def mm_hop(acc, b_t):
+        # route the partial C tile one hop toward its owner (the TPU
+        # replacement for the paper's remote accumulation queue push):
+        # g hops in all, the last one home
+        acc = acc + _local_mm(a, b_t, geom)
+        return lax.ppermute(acc, geom.axc, _ring_perm(geom.g))
+
     if geom.overlap:
         # Only the B stream double-buffers: the partial-C permute depends
         # on the accumulate it follows (the ride-home chain is inherently
@@ -1120,27 +1144,19 @@ def _body_ring_a(a, b, geom: _Geom):
         def step(carry, _):
             b_t, b_f, acc = carry
             b_n = _tree_ppermute(b_f, geom.axr, geom.g)
-            acc = acc + _local_mm(a, b_t, geom)
-            acc = lax.ppermute(acc, geom.axc, _ring_perm(geom.g))
-            return (b_f, b_n, acc), None
+            return (b_f, b_n, mm_hop(acc, b_t)), None
 
-        (b_l, _, acc), _ = lax.scan(step, (b, b_f, acc0), None,
-                                    length=geom.g - 1)
-        acc = acc + _local_mm(a, b_l, geom)
-        return lax.ppermute(acc, geom.axc, _ring_perm(geom.g))
+        (b_t, b_f, acc), _ = lax.scan(step, (b, b_f, acc0), None, length=n)
+        acc = mm_hop(acc, b_t)
+        return mm_hop(acc, b_f) if geom.g > 1 else acc
 
     def step(carry, _):
         b_t, acc = carry
         b_n = _tree_ppermute(b_t, geom.axr, geom.g)   # prefetch next B tile
-        acc = acc + _local_mm(a, b_t, geom)
-        # route the partial C tile one hop toward its owner (the TPU
-        # replacement for the paper's remote accumulation queue push)
-        acc = lax.ppermute(acc, geom.axc,
-                           [((d + 1) % geom.g, d) for d in range(geom.g)])
-        return (b_n, acc), None
+        return (b_n, mm_hop(acc, b_t)), None
 
-    (_, acc), _ = lax.scan(step, (b, acc0), None, length=geom.g)
-    return acc
+    (b_t, acc), _ = lax.scan(step, (b, acc0), None, length=n)
+    return mm_hop(acc, b_t)
 
 
 @register_algorithm("ring_c_bidir", a_placement=SKEW_ROWS,
@@ -1168,6 +1184,11 @@ def _body_ring_c_bidir(a, b, geom: _Geom):
     c_l0 = _pvary(jnp.zeros((geom.tm, half), dtype=geom.out_dtype), geom)
     c_r0 = _pvary(jnp.zeros((geom.tm, geom.tn - half), dtype=geom.out_dtype),
                   geom)
+    n = _ring_steps(geom.g, geom.overlap)
+
+    def mm(c_l, c_r, a_f, a_b, b_f, b_b):
+        return c_l + _local_mm(a_f, b_f, geom), c_r + _local_mm(a_b, b_b, geom)
+
     if geom.overlap:
         # four streams, each with a two-slot buffer (see _body_ring_c)
         a_ff = _tree_ppermute(a, geom.axc, geom.g, +1)
@@ -1181,16 +1202,16 @@ def _body_ring_c_bidir(a, b, geom: _Geom):
             a_bn = _tree_ppermute(a_bf, geom.axc, geom.g, -1)
             b_fn = _tree_ppermute(b_ff, geom.axr, geom.g, +1)
             b_bn = _tree_ppermute(b_bf, geom.axr, geom.g, -1)
-            c_l = c_l + _local_mm(a_f, b_f, geom)
-            c_r = c_r + _local_mm(a_b, b_b, geom)
+            c_l, c_r = mm(c_l, c_r, a_f, a_b, b_f, b_b)
             return (a_ff, a_bf, b_ff, b_bf, a_fn, a_bn, b_fn, b_bn,
                     c_l, c_r), None
 
-        (a_fl, a_bl, b_fl, b_bl, _, _, _, _, c_l, c_r), _ = lax.scan(
+        (a_f, a_b, b_f, b_b, a_ff, a_bf, b_ff, b_bf, c_l, c_r), _ = lax.scan(
             step, (a, a, b_fwd, b_bwd, a_ff, a_bf, b_ff, b_bf, c_l0, c_r0),
-            None, length=geom.g - 1)
-        c_l = c_l + _local_mm(a_fl, b_fl, geom)
-        c_r = c_r + _local_mm(a_bl, b_bl, geom)
+            None, length=n)
+        c_l, c_r = mm(c_l, c_r, a_f, a_b, b_f, b_b)
+        if geom.g > 1:
+            c_l, c_r = mm(c_l, c_r, a_ff, a_bf, b_ff, b_bf)
         return jnp.concatenate([c_l, c_r], axis=1)
 
     def step(carry, _):
@@ -1200,12 +1221,12 @@ def _body_ring_c_bidir(a, b, geom: _Geom):
         a_bn = _tree_ppermute(a_b, geom.axc, geom.g, -1)
         b_fn = _tree_ppermute(b_f, geom.axr, geom.g, +1)
         b_bn = _tree_ppermute(b_b, geom.axr, geom.g, -1)
-        c_l = c_l + _local_mm(a_f, b_f, geom)
-        c_r = c_r + _local_mm(a_b, b_b, geom)
+        c_l, c_r = mm(c_l, c_r, a_f, a_b, b_f, b_b)
         return (a_fn, a_bn, b_fn, b_bn, c_l, c_r), None
 
-    (_, _, _, _, c_l, c_r), _ = lax.scan(
-        step, (a, a, b_fwd, b_bwd, c_l0, c_r0), None, length=geom.g)
+    (a_f, a_b, b_f, b_b, c_l, c_r), _ = lax.scan(
+        step, (a, a, b_fwd, b_bwd, c_l0, c_r0), None, length=n)
+    c_l, c_r = mm(c_l, c_r, a_f, a_b, b_f, b_b)
     return jnp.concatenate([c_l, c_r], axis=1)
 
 
@@ -1388,36 +1409,72 @@ def _canonical_placement(placement: str, g: int) -> str:
     return NATURAL if g == 1 else placement
 
 
-def _place_bsr(t: TiledBSR, placement: str) -> TiledBSR:
-    if placement == NATURAL:
-        return t
-    if placement in (SKEW_ROWS, SKEW_COLS):
-        return skew_bsr(t, placement[len("skew_"):])
-    if placement == STATIONARY_A:
-        g = t.grid_shape[0]
-        i = np.arange(g)[:, None]
-        j = np.arange(g)[None, :]
-        si, sj = j + 0 * i, (i + j) % g   # position (i,j) <- tile (j,(i+j)%g)
-        take = lambda arr: arr[si, sj]
-        return TiledBSR(
-            blocks=take(t.blocks), rows=take(t.rows), cols=take(t.cols),
-            counts=take(t.counts), shape=t.shape, block_size=t.block_size,
-            grid_shape=t.grid_shape, capacity=t.capacity,
-            logical_shape=t.logical_shape, row_block_perm=t.row_block_perm,
-            col_block_perm=t.col_block_perm)
-    raise ValueError(f"unknown placement {placement!r}; one of {PLACEMENTS}")
+def _tile_grid(x):
+    """The mesh that holds ``x`` one tile per device: ``x`` is split over
+    both axes of a two-axis mesh in its two leading dimensions and over
+    nothing else.  ``None`` for an array laid out otherwise."""
+    s = getattr(x, "sharding", None)
+    if not isinstance(s, jax.sharding.NamedSharding) \
+            or len(s.mesh.axis_names) != 2:
+        return None
+    spec = tuple(s.spec) + (None,) * (x.ndim - len(s.spec))
+    if spec[:2] != tuple(s.mesh.axis_names) \
+            or any(p is not None for p in spec[2:]):
+        return None
+    return s.mesh
 
 
-def _place_dense(x: jnp.ndarray, g: int, placement: str) -> jnp.ndarray:
+def _map_tiles(x, fn):
+    """An array laid out as ``x`` (one tile per device of its mesh) whose
+    tile at mesh position (i, j) is ``fn((i, j), tiles)``, where ``tiles``
+    maps each position to the single-device array of ``x``'s tile there.
+    No array is gathered on any device."""
+    mesh = x.sharding.mesh
+    at = {d: ij for ij, d in np.ndenumerate(mesh.devices)}
+    tiles = {at[sh.device]: sh.data for sh in x.addressable_shards}
+    out = [fn(ij, tiles) for ij, _ in np.ndenumerate(mesh.devices)]
+    g_r, g_c = mesh.devices.shape
+    shape = (g_r * out[0].shape[0], g_c * out[0].shape[1]) + out[0].shape[2:]
+    return jax.make_array_from_single_device_arrays(shape, x.sharding, out)
+
+
+def _place_tree(tree: Dict[str, jnp.ndarray], placement: str,
+                g: int) -> Dict[str, jnp.ndarray]:
+    """``tree`` in ``placement``: mesh position (i, j) holds the natural
+    tile :func:`repro.core.wire.placement_tiles` names.  Each tile moves
+    straight from the device that holds it to the one that needs it (under
+    the span ``handle.place``), so no device holds more than its own tile
+    and the one it receives.  A leaf not laid out one tile per device (a
+    handle staged on one device) is first committed to
+    :func:`repro.core.dist.tile_mesh`; a placement needs its g * g
+    devices."""
     if placement == NATURAL:
-        return x
-    if placement == SKEW_ROWS:
-        return skew_dense(x, g, "rows")
-    if placement == SKEW_COLS:
-        return skew_dense(x, g, "cols")
-    if placement == STATIONARY_A:
-        return place_b_for_stationary_a(x, g)
-    raise ValueError(f"unknown placement {placement!r}; one of {PLACEMENTS}")
+        return tree
+    if not all(_tile_grid(v) is not None for v in tree.values()):
+        mesh = tile_mesh(g)
+        if mesh is None:
+            raise ValueError(f"placement {placement!r} of a {g}x{g} grid "
+                             f"needs {g * g} devices, JAX has "
+                             f"{len(jax.devices())}")
+        tiles = jax.sharding.NamedSharding(mesh, P(*mesh.axis_names))
+        tree = {k: v if _tile_grid(v) is not None
+                else jax.device_put(v, tiles) for k, v in tree.items()}
+    src = _wire.placement_tiles(placement, g)
+    mesh_devices = next(iter(tree.values())).sharding.mesh.devices
+    moved = 0
+
+    def move(ij, tiles):
+        nonlocal moved
+        tile = tiles[tuple(src[ij])]
+        if tuple(src[ij]) == ij:
+            return tile
+        moved += tile.nbytes
+        return jax.device_put(tile, mesh_devices[ij])
+
+    with _obs.span("handle.place", placement=placement) as sp:
+        out = {k: _map_tiles(v, move) for k, v in tree.items()}
+        sp.note(bytes_moved=moved)
+    return out
 
 
 class DistMatrix:
@@ -1642,14 +1699,20 @@ class DistBSR(DistMatrix):
         placement = _canonical_placement(placement, self.g)
         tree = cache.get(placement)
         if tree is None:
-            po = self.packed_operand()
-            placed = self.placed(placement)["blocks"]
-            tiles = _wire.placement_tiles(placement, self.g)
-            pidx = po.pack_idx[tiles[..., 0], tiles[..., 1]]  # [g, g, wc]
-            g = self.g
-            ii = jnp.arange(g)[:, None, None]
-            jj = jnp.arange(g)[None, :, None]
-            tree = {"blocks": placed[ii, jj, jnp.asarray(pidx)]}
+            # pack each natural tile where it lives, then place the packed
+            # tiles: only real blocks move between devices
+            pidx = self.packed_operand().pack_idx            # [g, g, wc]
+            blocks = self.tiled.blocks
+            if _tile_grid(blocks) is not None:
+                devices = blocks.sharding.mesh.devices
+                packed = _map_tiles(blocks, lambda ij, tiles: tiles[ij][
+                    :, :, jax.device_put(pidx[ij], devices[ij])])
+            else:
+                g = self.g
+                packed = blocks[jnp.arange(g)[:, None, None],
+                                jnp.arange(g)[None, :, None],
+                                jnp.asarray(pidx)]
+            tree = _place_tree({"blocks": packed}, placement, self.g)
             cache[placement] = tree
         if commit is not None:
             tree = cache[placement] = commit(tree)
@@ -1683,8 +1746,9 @@ class DistBSR(DistMatrix):
         placement = _canonical_placement(placement, self.g)
         tree = self._placed.get(placement)
         if tree is None:
-            t = _place_bsr(self.tiled, placement)
-            tree = {"blocks": t.blocks, "rows": t.rows, "cols": t.cols}
+            t = self.tiled
+            tree = _place_tree({"blocks": t.blocks, "rows": t.rows,
+                                "cols": t.cols}, placement, self.g)
             self._placed[placement] = tree
         if commit is not None:
             tree = self._placed[placement] = commit(tree)
@@ -1718,8 +1782,13 @@ class DistDense(DistMatrix):
     @classmethod
     def from_global(cls, x, g: int, *, rows_pad: Optional[int] = None,
                     cols_pad: Optional[int] = None) -> "DistDense":
-        """Wrap a global array, zero-padding each dim to a multiple of g."""
-        x = jnp.asarray(x)
+        """Wrap a global array, zero-padding each dim to a multiple of g.
+
+        Where :func:`repro.core.dist.tile_mesh` gives a mesh (``g > 1``
+        and ``g * g`` devices), tile (i, j) goes straight to device (i, j)
+        of it, the layout plans run in; a host array is padded on the host.
+        """
+        x = x if isinstance(x, jax.Array) else np.asarray(x)
         m, n = x.shape
         rp = pad_to_multiple(m, g) if rows_pad is None else rows_pad
         cp = pad_to_multiple(n, g) if cols_pad is None else cols_pad
@@ -1727,7 +1796,14 @@ class DistDense(DistMatrix):
             raise ValueError(f"bad padded shape ({rp}, {cp}) for array "
                              f"{x.shape} on a {g}x{g} grid")
         if (rp, cp) != (m, n):
-            x = jnp.zeros((rp, cp), x.dtype).at[:m, :n].set(x)
+            if isinstance(x, jax.Array):
+                x = jnp.zeros((rp, cp), x.dtype).at[:m, :n].set(x)
+            else:
+                x = np.pad(x, ((0, rp - m), (0, cp - n)))
+        mesh = tile_mesh(g)
+        if mesh is not None:
+            x = jax.device_put(x, jax.sharding.NamedSharding(
+                mesh, P(*mesh.axis_names)))
         return cls(x, g, logical_shape=(m, n))
 
     @classmethod
@@ -1739,7 +1815,7 @@ class DistDense(DistMatrix):
         anything smaller is only zero-padded with an explicit
         ``allow_pad=True`` (silent padding hides shape bugs).
         """
-        x = jnp.asarray(x)
+        x = x if isinstance(x, jax.Array) else np.asarray(x)
         k = x.shape[0]
         k_pad, k_log = a.shape[1], a.logical_shape[1]
         if k > k_pad:
@@ -1774,7 +1850,7 @@ class DistDense(DistMatrix):
         placement = _canonical_placement(placement, self._g)
         tree = self._placed.get(placement)
         if tree is None:
-            tree = {"dense": _place_dense(self.data, self._g, placement)}
+            tree = _place_tree({"dense": self.data}, placement, self._g)
             self._placed[placement] = tree
         if commit is not None:
             tree = self._placed[placement] = commit(tree)
@@ -1840,7 +1916,7 @@ def _reshard_bsr(h: DistBSR, g: int, capacity) -> DistBSR:
             src = np.array([e[2] for e in ent], dtype=np.int64)
             # pad to uniform capacity the way BSR.with_capacity does
             # (repeat the last coordinate, zero block), then merge the
-            # coverage blocks in sorted order like _augment_tile
+            # coverage blocks in sorted order like TiledBSR._scan_dense
             pad = cap - len(ent)
             last_r = r[-1] if len(ent) else np.int32(0)
             last_c = c[-1] if len(ent) else np.int32(0)
@@ -2017,6 +2093,18 @@ def _cost_model(alg: Algorithm, geom: _Geom, a_key: tuple, b_key: tuple,
         tiles = {"a": a_bytes, "b": b_bytes, "c": c_bytes}
         return _assemble_cost(alg, g, a_bytes, b_bytes, c_bytes, flops_step,
                               tiles)
+    tiles, flops_step = _dense_output_tiles(geom, a_key, b_key, wire_caps)
+    return _assemble_cost(alg, g, tiles["a"], tiles["b"], tiles["c"],
+                          flops_step, tiles)
+
+
+def _dense_output_tiles(geom: _Geom, a_key: tuple, b_key: tuple,
+                        wire_caps: Dict[str, int]
+                        ) -> Tuple[Dict[str, float], float]:
+    """The bytes of one A, B and C tile as a dense-output body ships it
+    (``{"a", "b", "c"}``) and the flops of one step (see
+    :func:`_cost_model`)."""
+    g = geom.g
     if a_key[0] == "bsr":
         bs, cap = a_key[3], a_key[4]
         wa = np.dtype(_key_dtype(a_key)).itemsize
@@ -2043,9 +2131,7 @@ def _cost_model(alg: Algorithm, geom: _Geom, a_key: tuple, b_key: tuple,
         tk_b = b_key[1][0] // g
         b_bytes = tk_b * geom.tn * wb
     c_bytes = geom.tm * geom.tn * np.dtype(geom.out_dtype).itemsize
-    tiles = {"a": a_bytes, "b": b_bytes, "c": c_bytes}
-    return _assemble_cost(alg, g, a_bytes, b_bytes, c_bytes, flops_step,
-                          tiles)
+    return {"a": a_bytes, "b": b_bytes, "c": c_bytes}, flops_step
 
 
 def _assemble_cost(alg: Algorithm, g: int, a_bytes, b_bytes, c_bytes,
@@ -2671,12 +2757,11 @@ def _coerce_pair(a, b, *, g: Optional[int] = None, allow_pad: bool = False
     elif isinstance(a, TiledBSR):
         a_h = DistBSR.from_tiled(a)
     else:
-        arr = jnp.asarray(a)
         if g is None:
             raise ValueError(
                 "a dense left operand needs g=<grid size> or a DistDense "
                 "handle (DistDense.from_global)")
-        a_h = DistDense.from_global(arr, g)
+        a_h = DistDense.from_global(a, g)
     if g is not None and a_h.g != g:
         raise ValueError(f"left operand lives on a {a_h.g}x{a_h.g} grid, "
                          f"but g={g} was requested")
@@ -2686,7 +2771,7 @@ def _coerce_pair(a, b, *, g: Optional[int] = None, allow_pad: bool = False
     elif isinstance(b, TiledBSR):
         b_h = DistBSR.from_tiled(b)
     else:
-        b_h = DistDense.for_rhs(jnp.asarray(b), a_h, allow_pad=allow_pad)
+        b_h = DistDense.for_rhs(b, a_h, allow_pad=allow_pad)
 
     if getattr(b_h, "row_block_perm", None):
         raise ValueError(
@@ -3146,10 +3231,64 @@ def _plan_matmul_impl(a, b, *, algorithm: str = "ring_c", mesh=None,
                           steal=steal, wire=wire, packs=packs,
                           wire_aux=wire_aux, wire_caps=wire_caps,
                           wire_fps=wire_fps, overlap=overlap)
+    if sym is None:
+        _set_dense_output_gauges(plan, a_h, wire_aux)
     plan.validate(validate, a_h, b_h)
     if cache:
         _PLAN_CACHE[key] = plan
     return plan
+
+
+def _set_dense_output_gauges(plan: MatmulPlan, a_h: DistMatrix,
+                             wire_aux: Optional[Dict[str, np.ndarray]]
+                             ) -> None:
+    """What one product of a dense-output plan does on its busiest device,
+    set as gauges labelled ``algorithm=`` and ``wire=``:
+
+    ``plan.spmm_block_steps``, the SpMM kernel's grid steps: g steps, each
+    walking the A list once for every column panel of B (one kernel call
+    a step per A stream, each on its own share of B's columns);
+    ``plan.spmm_real_blocks``, the real A blocks among them: an A that
+    rides (``"a"`` in the schedule's wire) brings each tile of the
+    device's grid row once, an A that stays (``ring_a``) is the device's
+    own tile at every step; 0 and 0 where no SpMM kernel runs (a dense A,
+    or steal3d's pair kernel);
+
+    ``plan.wire_bytes``, the bytes its collectives send out of one device:
+    each stream's tile at the wire's capacity times the transfers the body
+    makes of it, g - 1 for a ring shift or an all-gather, g hops for
+    ``ring_a``'s partial C, 2 (g - 1) for ``summa_bcast``'s psum
+    broadcasts (what a ring all-reduce sends); a steal3d plan's own count
+    of its gathers, moves and reductions."""
+    alg, geom, g = plan.algorithm, plan.geom, plan.geom.g
+    steps = real = 0
+    if alg.static_planner is None and isinstance(a_h, DistBSR):
+        length = wire_aux["a_rows"].shape[-1] if "a" in plan._packs \
+            else a_h.tiled.store_capacity
+        n_a = max(alg.wire.count("a"), 1)
+        share = geom.tn // n_a
+        widths = [share] * (n_a - 1) + [geom.tn - share * (n_a - 1)]
+        panels = sum(w // spmm_block_n(w) for w in widths if w)
+        counts = np.asarray(a_h.counts, dtype=np.int64)
+        walked = counts.sum(axis=1).max() if "a" in alg.wire \
+            else g * counts.max()
+        steps, real = g * length * panels, int(walked) * panels
+    if plan.steal is not None:
+        sent = plan.steal.cost["total_net_bytes"]
+    else:
+        tiles, _ = _dense_output_tiles(geom, plan._a_key, plan._b_key,
+                                       plan._wire_caps or {})
+        per = 2 * (g - 1) if alg.style == "bsp" \
+            and not alg.wire_amortized else g - 1
+        # a tile named twice rides both ways round the ring, which at
+        # g = 2 is one permutation: the compiler sends it once
+        streams = alg.wire if g > 2 else set(alg.wire)
+        sent = sum(tiles[t] * (g if t == "c" else per) for t in streams)
+    reg = _obs.registry()
+    labels = dict(algorithm=alg.name, wire=plan.wire)
+    reg.gauge("plan.spmm_block_steps", **labels).set(steps)
+    reg.gauge("plan.spmm_real_blocks", **labels).set(real)
+    reg.gauge("plan.wire_bytes", **labels).set(float(sent))
 
 
 def plan_matmul(a, b, **kw) -> MatmulPlan:

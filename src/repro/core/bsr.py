@@ -47,6 +47,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from .. import obs as _obs
+from .dist import tile_mesh
 from .grid import ProcessGrid, bucket_capacity, ceil_div, pad_to_multiple
 
 __all__ = ["BSR", "TiledBSR", "rmat_edges", "rmat_matrix", "random_sparse"]
@@ -125,40 +126,16 @@ class BSR:
         capacity: Optional[int] = None,
         dtype=None,
     ) -> "BSR":
-        dense = np.asarray(dense)
-        m, n = dense.shape
-        mp, np_ = pad_to_multiple(m, block_size), pad_to_multiple(n, block_size)
-        padded = np.zeros((mp, np_), dtype=dense.dtype)
-        padded[:m, :n] = dense
-        nbr, nbc = mp // block_size, np_ // block_size
-        view = padded.reshape(nbr, block_size, nbc, block_size).transpose(0, 2, 1, 3)
-        mask = np.abs(view).sum(axis=(2, 3)) != 0
-        rr, cc = np.nonzero(mask)  # np.nonzero returns row-major (sorted by row)
-        nnzb = len(rr)
-        # an all-zero matrix legitimately has capacity 0 (coverage blocks
-        # added by the TiledBSR augmenter keep kernels well-defined)
-        cap = capacity if capacity is not None else nnzb
-        if nnzb > cap:
-            raise ValueError(f"capacity {cap} < nnzb {nnzb}")
-        bs = block_size
-        blocks = np.zeros((cap, bs, bs), dtype=dense.dtype)
-        rows = np.zeros((cap,), dtype=np.int32)
-        cols = np.zeros((cap,), dtype=np.int32)
-        blocks[:nnzb] = view[rr, cc]
-        rows[:nnzb] = rr
-        cols[:nnzb] = cc
-        if nnzb > 0:  # keep padding sorted: repeat the last (row, col)
-            rows[nnzb:] = rr[-1]
-            cols[nnzb:] = cc[-1]
-        out_dtype = dtype or dense.dtype
+        blocks, rows, cols, nnzb, shape, logical = _host_bsr(
+            dense, block_size, capacity, dtype)
         return cls(
-            blocks=jnp.asarray(blocks, dtype=out_dtype),
+            blocks=jnp.asarray(blocks),
             rows=jnp.asarray(rows),
             cols=jnp.asarray(cols),
-            shape=(mp, np_),
-            block_size=bs,
+            shape=shape,
+            block_size=block_size,
             nnzb=nnzb,
-            logical_shape=(m, n),
+            logical_shape=logical,
         )
 
     @classmethod
@@ -205,24 +182,77 @@ class BSR:
                    self.logical_shape)
 
 
-def _augment_tile(blocks: np.ndarray, rows: np.ndarray, cols: np.ndarray,
-                  n_block_rows: int):
-    """Merge one zero block per block-row into a tile's block list (sorted).
+# Blocks gathered per numpy call: bounds the temporary of one gather.
+_GATHER_CHUNK = 1024
 
-    This is the SpMM kernel's coverage requirement — every output block-row
-    must be visited so the first-visit zeroing initializes the whole C tile —
-    precomputed at tiling time instead of per ring step.  The stable sort
-    keeps real blocks in row order and the appended zero blocks inert.
-    """
-    cov = np.arange(n_block_rows, dtype=rows.dtype)
-    rows_aug = np.concatenate([rows, cov])
-    order = np.argsort(rows_aug, kind="stable")
-    bs = blocks.shape[1]
-    blocks_aug = np.concatenate(
-        [blocks, np.zeros((n_block_rows, bs, bs), blocks.dtype)])[order]
-    cols_aug = np.concatenate(
-        [cols, np.zeros((n_block_rows,), cols.dtype)])[order]
-    return blocks_aug, rows_aug[order], cols_aug
+
+def _out_dtype(src_dtype, dtype) -> np.dtype:
+    """The stored blocks' dtype: ``dtype`` or the input's, as JAX keeps it
+    (float64 is stored as float32 unless x64 is on)."""
+    return np.dtype(jax.dtypes.canonicalize_dtype(dtype or src_dtype))
+
+
+def _padded(dense: np.ndarray, shape: Tuple[int, int]) -> np.ndarray:
+    """``dense`` zero-padded to ``shape``; the input itself when it already
+    has that shape (no copy)."""
+    if dense.shape == tuple(shape):
+        return dense
+    out = np.zeros(shape, dtype=dense.dtype)
+    out[:dense.shape[0], :dense.shape[1]] = dense
+    return out
+
+
+def _block_mask(x: np.ndarray, bs: int) -> np.ndarray:
+    """``bool[rows/bs, cols/bs]``: which ``bs x bs`` blocks of ``x`` hold a
+    nonzero.  One block row at a time, so the temporaries are one block
+    row's size, never the matrix's."""
+    nbr, nbc = x.shape[0] // bs, x.shape[1] // bs
+    mask = np.empty((nbr, nbc), dtype=bool)
+    for r in range(nbr):
+        slab = x[r * bs:(r + 1) * bs].reshape(bs, nbc, bs)
+        mask[r] = (slab != 0).any(axis=(0, 2))
+    return mask
+
+
+def _gather_blocks(x: np.ndarray, bs: int, br: np.ndarray, bc: np.ndarray,
+                   out: np.ndarray, at: np.ndarray) -> None:
+    """``out[at[k]] = x``'s block ``(br[k], bc[k])``, converted to
+    ``out``'s dtype, a chunk of blocks at a time."""
+    x4 = x.reshape(x.shape[0] // bs, bs, x.shape[1] // bs, bs)
+    for k in range(0, len(br), _GATHER_CHUNK):
+        sl = slice(k, k + _GATHER_CHUNK)
+        out[at[sl]] = x4[br[sl], :, bc[sl], :]
+
+
+def _host_bsr(dense, block_size: int, capacity: Optional[int], dtype):
+    """Host half of :meth:`BSR.from_dense`: the padded matrix's nonzero
+    blocks in row-major order, padded to ``capacity`` with zero blocks
+    that repeat the last (row, col).  Returns numpy ``(blocks, rows, cols,
+    nnzb, shape, logical_shape)``."""
+    dense = np.asarray(dense)
+    m, n = dense.shape
+    bs = block_size
+    shape = (pad_to_multiple(m, bs), pad_to_multiple(n, bs))
+    x = _padded(dense, shape)
+    rr, cc = np.nonzero(_block_mask(x, bs))  # row-major: sorted by row
+    nnzb = len(rr)
+    # an all-zero matrix legitimately has capacity 0 (coverage blocks
+    # added by the TiledBSR augmenter keep kernels well-defined)
+    cap = capacity if capacity is not None else nnzb
+    if nnzb > cap:
+        raise ValueError(f"capacity {cap} < nnzb {nnzb}")
+    blocks = np.zeros((cap, bs, bs), dtype=_out_dtype(dense.dtype, dtype))
+    _gather_blocks(x, bs, rr, cc, blocks, np.arange(nnzb))
+    rows, cols = _pad_last(rr, cap), _pad_last(cc, cap)
+    return blocks, rows, cols, nnzb, shape, (m, n)
+
+
+def _pad_last(idx: np.ndarray, cap: int) -> np.ndarray:
+    """``int32[cap]``: ``idx`` then its last entry repeated (0 when empty),
+    so a padded list stays sorted."""
+    out = np.full((cap,), idx[-1] if len(idx) else 0, dtype=np.int32)
+    out[:len(idx)] = idx
+    return out
 
 
 @functools.partial(
@@ -242,7 +272,7 @@ class TiledBSR:
     counts : i32[gr, gc]    *real* blocks per tile (the load-imbalance map)
 
     Stored arrays are pre-augmented for kernel coverage (zero block per
-    block-row, merged in sorted order — see :func:`_augment_tile`), so the
+    block-row, merged in sorted order — see :meth:`_scan_dense`), so the
     distributed hot loop consumes them as-is.  ``capacity`` counts real
     block slots only; zero padding/coverage blocks are inert under the
     scatter-add consumers (``to_dense``, ``densify_raw``, the ref SpMM).
@@ -303,6 +333,12 @@ class TiledBSR:
         (``"auto"``) before tiling; the permutation is carried as
         ``row_block_perm`` / ``col_block_perm`` and undone by the planner.
         An axis is only kept when it *strictly* shrinks the capacity.
+
+        Where the grid is ``g x g`` with ``g > 1`` and JAX has ``g * g``
+        devices, tile (i, j) is uploaded straight to device (i, j) of
+        :func:`repro.core.dist.make_grid_mesh`, the layout plans run in,
+        so no device ever holds the whole matrix; otherwise the tiles go
+        to the default device.
         """
         if balance not in ("none", "rows", "cols", "auto"):
             raise ValueError(f"unknown balance {balance!r}; one of "
@@ -311,80 +347,81 @@ class TiledBSR:
             host = cls._scan_dense(dense, grid, block_size, capacity, dtype,
                                    balance)
         blocks, rows_, cols_, counts, fields = host
+        mesh = tile_mesh(grid.rows) if grid.rows == grid.cols else None
         with _obs.span("handle.tile.upload"):
-            # Block until the data is on the device, traced or not, so the
+            if mesh is None:
+                put = jnp.asarray
+            else:
+                tiles = jax.sharding.NamedSharding(
+                    mesh, jax.sharding.PartitionSpec(*mesh.axis_names))
+
+                def put(x):
+                    return jax.device_put(x, tiles)
+            # Block until the data is on the devices, traced or not, so the
             # handle comes back ready and the span ends when the upload
-            # does (jnp.asarray may return while the copy is in flight).
-            stored = jax.block_until_ready(tuple(
-                jnp.asarray(x) for x in (blocks, rows_, cols_, counts)))
+            # does (the puts may return while the copies are in flight).
+            stored = jax.block_until_ready(
+                (put(blocks), put(rows_), put(cols_), jnp.asarray(counts)))
         return cls(*stored, **fields)
 
     @staticmethod
     def _scan_dense(dense, grid: ProcessGrid, block_size: int, capacity,
                     dtype, balance: str) -> tuple:
-        """Host half of :meth:`from_dense`: pad, find the real blocks of
-        every tile, gather them and merge the coverage blocks.  Returns the
-        stored arrays as numpy and the remaining fields."""
+        """Host half of :meth:`from_dense`, in numpy only: find the real
+        blocks of every tile, gather them and merge the coverage blocks.
+        The input is read in place (copied only where it needs padding);
+        what it allocates is the stored arrays.  Returns those as numpy and
+        the remaining fields."""
         dense = np.asarray(dense)
         m, n = dense.shape
-        tm = pad_to_multiple(ceil_div(m, grid.rows), block_size)
-        tn = pad_to_multiple(ceil_div(n, grid.cols), block_size)
+        bs = block_size
+        tm = pad_to_multiple(ceil_div(m, grid.rows), bs)
+        tn = pad_to_multiple(ceil_div(n, grid.cols), bs)
         mp, np_ = tm * grid.rows, tn * grid.cols
-        padded = np.zeros((mp, np_), dtype=dense.dtype)
-        padded[:m, :n] = dense
+        x = _padded(dense, (mp, np_))
+        mask = _block_mask(x, bs)
+        tile_nbr, tile_nbc = tm // bs, tn // bs
+
+        def tile_counts(mk):
+            return mk.reshape(grid.rows, tile_nbr, grid.cols,
+                              tile_nbc).sum(axis=(1, 3))
+
+        # Tiling reads block (row_src[r], col_src[c]) of the input where a
+        # balance permutation puts block (r, c).
+        row_src, col_src = np.arange(mask.shape[0]), np.arange(mask.shape[1])
         perm = col_perm = None
         if balance != "none":
             from .schedule import balance_row_perm
-            nbr_global = mp // block_size
-            nbc_global = np_ // block_size
-            mask = np.abs(
-                padded.reshape(nbr_global, block_size, nbc_global,
-                               block_size)).sum(axis=(1, 3)) != 0
-
-            def tile_cap(m):
-                per_tile = m.reshape(grid.rows, nbr_global // grid.rows,
-                                     grid.cols, nbc_global // grid.cols)
-                return int(per_tile.sum(axis=(1, 3)).max())
 
             # balance_row_perm equalizes grid-ROW (or grid-COL) totals; the
             # uniform capacity is the per-TILE max, which a permutation can
             # occasionally worsen (mass re-concentrating in one tile).
             # Keep an axis only when it strictly shrinks the capacity;
             # "auto" takes the axis with the larger shrink (rows on ties).
-            best_cap = tile_cap(mask)
+            best_cap = int(tile_counts(mask).max())
             best_axis = None
             if balance in ("rows", "auto"):
-                p = balance_row_perm(mask.sum(axis=1), grid.rows)
-                c = tile_cap(mask[np.asarray(p)])
+                p = np.asarray(balance_row_perm(mask.sum(axis=1), grid.rows))
+                c = int(tile_counts(mask[p]).max())
                 if c < best_cap:
-                    best_axis, best_cap, perm = "rows", c, p
+                    best_axis, best_cap, row_src = "rows", c, p
             if balance in ("cols", "auto"):
-                p = balance_row_perm(mask.sum(axis=0), grid.cols)
-                c = tile_cap(mask[:, np.asarray(p)])
+                p = np.asarray(balance_row_perm(mask.sum(axis=0), grid.cols))
+                c = int(tile_counts(mask[:, p]).max())
                 if c < best_cap:
-                    best_axis, best_cap, col_perm = "cols", c, p
+                    best_axis, best_cap, col_src = "cols", c, p
             if best_axis == "rows":
-                col_perm = None
-                padded = padded.reshape(nbr_global, block_size, np_)[perm]
-                padded = padded.reshape(mp, np_)
-                perm = tuple(int(p) for p in perm)
+                col_src = np.arange(mask.shape[1])
+                perm = tuple(int(p) for p in row_src)
             elif best_axis == "cols":
-                perm = None
-                padded = padded.reshape(mp, nbc_global,
-                                        block_size)[:, col_perm]
-                padded = padded.reshape(mp, np_)
-                col_perm = tuple(int(p) for p in col_perm)
+                row_src = np.arange(mask.shape[0])
+                col_perm = tuple(int(p) for p in col_src)
             else:
-                perm = col_perm = None
-        tiles = []
-        for i in range(grid.rows):
-            row = []
-            for j in range(grid.cols):
-                row.append(BSR.from_dense(
-                    padded[i * tm:(i + 1) * tm, j * tn:(j + 1) * tn],
-                    block_size, dtype=dtype))
-            tiles.append(row)
-        max_nnzb = max(max(t.nnzb for t in row) for row in tiles)
+                row_src = np.arange(mask.shape[0])
+                col_src = np.arange(mask.shape[1])
+        mask = mask[row_src][:, col_src]
+        counts = tile_counts(mask).astype(np.int32)
+        max_nnzb = int(counts.max())
         if capacity == "bucket":
             cap = bucket_capacity(max_nnzb)
         else:
@@ -394,19 +431,34 @@ class TiledBSR:
             # an all-zero matrix keeps capacity 0: store_capacity is then
             # just the coverage blocks — the cheap empty fast path
             cap = capacity if capacity is not None else max_nnzb
-        tile_nbr = tm // block_size
-        aug = [[_augment_tile(np.asarray(t.blocks), np.asarray(t.rows),
-                              np.asarray(t.cols), tile_nbr)
-                for t in (u.with_capacity(cap) for u in row)]
-               for row in tiles]
-        blocks = np.stack([np.stack([a[0] for a in row]) for row in aug])
-        rows_ = np.stack([np.stack([a[1] for a in row]) for row in aug])
-        cols_ = np.stack([np.stack([a[2] for a in row]) for row in aug])
-        counts = np.asarray([[t.nnzb for t in row] for row in tiles],
-                            dtype=np.int32)
+        store = cap + tile_nbr
+        g_shape = (grid.rows, grid.cols)
+        blocks = np.zeros(g_shape + (store, bs, bs),
+                          dtype=_out_dtype(dense.dtype, dtype))
+        rows_ = np.empty(g_shape + (store,), dtype=np.int32)
+        cols_ = np.empty(g_shape + (store,), dtype=np.int32)
+        cov = np.arange(tile_nbr, dtype=np.int32)
+        for i in range(grid.rows):
+            for j in range(grid.cols):
+                rr, cc = np.nonzero(mask[i * tile_nbr:(i + 1) * tile_nbr,
+                                         j * tile_nbc:(j + 1) * tile_nbc])
+                # The tile's list padded to the capacity (zero blocks that
+                # repeat its last (row, col)), with one zero coverage block
+                # per block row merged in by a stable sort: every block row
+                # is visited, real blocks first and in row order.
+                rows_aug = np.concatenate([_pad_last(rr, cap), cov])
+                order = np.argsort(rows_aug, kind="stable")
+                rows_[i, j] = rows_aug[order]
+                cols_[i, j] = np.concatenate(
+                    [_pad_last(cc, cap), np.zeros_like(cov)])[order]
+                at = np.empty_like(order)
+                at[order] = np.arange(store)
+                _gather_blocks(x, bs, row_src[i * tile_nbr + rr],
+                               col_src[j * tile_nbc + cc], blocks[i, j],
+                               at[:len(rr)])
         return blocks, rows_, cols_, counts, dict(
             shape=(mp, np_), block_size=block_size,
-            grid_shape=(grid.rows, grid.cols), capacity=cap,
+            grid_shape=g_shape, capacity=cap,
             logical_shape=(m, n), row_block_perm=perm,
             col_block_perm=col_perm)
 

@@ -17,11 +17,9 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from .bsr import TiledBSR
-
 __all__ = [
-    "make_grid_mesh", "tileize", "untileize",
-    "skew_dense", "skew_bsr", "place_b_for_stationary_a", "unskew_c_rows",
+    "make_grid_mesh", "tile_mesh", "tileize", "untileize",
+    "skew_dense", "unskew_c_rows",
 ]
 
 
@@ -29,6 +27,16 @@ def make_grid_mesh(g: int, axis_row: str = "row", axis_col: str = "col"):
     """A g x g device mesh with Auto axis types."""
     return jax.make_mesh((g, g), (axis_row, axis_col),
                          axis_types=(jax.sharding.AxisType.Auto,) * 2)
+
+
+def tile_mesh(g: int):
+    """The mesh a ``g x g`` grid of tiles is put on when it is made: tile
+    (i, j) on device (i, j) of :func:`make_grid_mesh`, the layout plans
+    run in.  ``None`` (the default device) for ``g == 1`` or fewer than
+    ``g * g`` devices."""
+    if g > 1 and len(jax.devices()) >= g * g:
+        return make_grid_mesh(g)
+    return None
 
 
 def tileize(x: jnp.ndarray, g: int) -> jnp.ndarray:
@@ -74,41 +82,6 @@ def skew_dense(x: jnp.ndarray, g: int, kind: str) -> jnp.ndarray:
     else:
         raise ValueError(kind)
     return untileize(tiles)
-
-
-def skew_bsr(a: TiledBSR, kind: str) -> TiledBSR:
-    """Skew a TiledBSR's tile grid (same placement semantics as skew_dense)."""
-    g = a.grid_shape[0]
-    if a.grid_shape[0] != a.grid_shape[1]:
-        raise ValueError("skew needs a square grid")
-    i = np.arange(g)[:, None]
-    j = np.arange(g)[None, :]
-    if kind == "rows":
-        si, sj = i + 0 * j, (j + i) % g
-    elif kind == "cols":
-        si, sj = (i + j) % g, j + 0 * i
-    else:
-        raise ValueError(kind)
-    take = lambda arr: arr[si, sj]
-    return TiledBSR(
-        blocks=take(a.blocks), rows=take(a.rows), cols=take(a.cols),
-        counts=take(a.counts), shape=a.shape, block_size=a.block_size,
-        grid_shape=a.grid_shape, capacity=a.capacity,
-        logical_shape=a.logical_shape, row_block_perm=a.row_block_perm,
-        col_block_perm=a.col_block_perm)
-
-
-def place_b_for_stationary_a(b: jnp.ndarray, g: int) -> jnp.ndarray:
-    """Initial B placement for the stationary-A ring.
-
-    Mesh position (i, k) holds B tile (k, (i+k) % g): the owner of A[i, k]
-    starts with the B tile for its first output column j0 = (i+k) % g — the
-    paper's ``k_offset = i + k`` for stationary A.
-    """
-    tiles = tileize(b, g)
-    i = np.arange(g)[:, None]
-    k = np.arange(g)[None, :]
-    return untileize(tiles[k + 0 * i, (i + k) % g])
 
 
 def unskew_c_rows(c: jnp.ndarray, g: int) -> jnp.ndarray:

@@ -295,7 +295,7 @@ def symbolic_spgemm(a: TiledBSR, b: TiledBSR,
     store = capacity + nbr
 
     # Pass 2: packed C layout (mirrors BSR.from_dense padding +
-    # bsr._augment_tile coverage merge, so the result satisfies the
+    # TiledBSR._scan_dense coverage merge, so the result satisfies the
     # TiledBSR storage contract) and slot-mapped pair lists.
     c_rows = np.zeros((g, g, store), dtype=np.int32)
     c_cols = np.zeros((g, g, store), dtype=np.int32)

@@ -145,7 +145,7 @@ def pack_operand(struct: GridStructure) -> PackedOperand:
             slot_map[i, j, sl] = np.arange(nr)
             # consume side: merge the real blocks with one coverage zero
             # per block-row (the bsr_spmm_raw(augment=False) contract),
-            # exactly like bsr._augment_tile but as packed-slot gathers
+            # exactly like TiledBSR._scan_dense but as packed-slot gathers
             r = struct.rows[i, j][sl].astype(np.int64)
             c = struct.cols[i, j][sl].astype(np.int64)
             cov = np.arange(nbr, dtype=np.int64)
@@ -176,8 +176,9 @@ def pack_operand(struct: GridStructure) -> PackedOperand:
 def placement_tiles(placement: str, g: int) -> np.ndarray:
     """Natural tile coordinates held at mesh position (i, j): i64[g, g, 2].
 
-    Mirrors ``api._place_bsr`` / ``core.dist`` exactly (asserted by the
-    packed-vs-padded allclose tests).
+    ``api._place_tree`` moves each tile to where this puts it, for padded
+    and packed wire alike (asserted by the packed-vs-padded allclose
+    tests).
     """
     i = np.arange(g)[:, None]
     j = np.arange(g)[None, :]

@@ -48,7 +48,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 __all__ = ["SMEM_LIST_BYTES", "list_chunk", "pair_group", "pair_grid_steps",
-           "bsr_spmm_pallas", "bsr_pair_matmul_pallas",
+           "spmm_block_n", "bsr_spmm_pallas", "bsr_pair_matmul_pallas",
            "bsr_pair_accumulate_pallas"]
 
 # Bytes of int32 scalar-prefetch lists one pallas_call may hold in SMEM
@@ -112,6 +112,16 @@ def _spmm_kernel(off_ref, rows_ref, cols_ref, a_ref, b_ref, *refs):
     a = a_ref[0]                      # [bs, bs]
     b = b_ref[...]                    # [bs, bn]
     c_ref[...] += jnp.dot(a, b, preferred_element_type=jnp.float32)
+
+
+def spmm_block_n(n: int, block_n: int = 256) -> int:
+    """Columns of B one grid step of :func:`bsr_spmm_pallas` takes for a B
+    ``n`` wide (``n > 0``): the largest ``block_n / 2**k`` that divides
+    ``n``, so a call runs ``n // spmm_block_n(n)`` column panels."""
+    bn = min(block_n, n)
+    while n % bn:
+        bn //= 2
+    return max(bn, 1)
 
 
 @functools.partial(

@@ -17,7 +17,7 @@ import numpy as np
 
 from . import ref as _ref
 from .bsr_spmm import (bsr_pair_accumulate_pallas, bsr_pair_matmul_pallas,
-                       bsr_spmm_pallas)
+                       bsr_spmm_pallas, spmm_block_n)
 
 __all__ = [
     "default_impl", "bsr_spmm", "bsr_spmm_raw", "match_block_pairs",
@@ -60,9 +60,6 @@ def bsr_spmm_raw(blocks, rows, cols, dense, *, n_block_rows: int,
                          jnp.promote_types(blocks.dtype, dense.dtype))
     if impl == "ref":
         return _ref.bsr_spmm_raw_ref(blocks, rows, cols, dense, n_block_rows)
-    bn = min(block_n, n)
-    while n % bn:
-        bn //= 2
     if augment:
         # Coverage augmentation: append one zero block per block-row so that
         # every output block is visited (and therefore zero-initialized) by
@@ -77,7 +74,8 @@ def bsr_spmm_raw(blocks, rows, cols, dense, *, n_block_rows: int,
             [cols, jnp.zeros((n_block_rows,), cols.dtype)])[order]
         rows = rows_aug[order]
     return bsr_spmm_pallas(blocks, rows, cols, dense,
-                           n_block_rows=n_block_rows, block_n=max(bn, 1),
+                           n_block_rows=n_block_rows,
+                           block_n=spmm_block_n(n, block_n),
                            chunked=chunked, interpret=(impl == "interpret"))
 
 
