@@ -88,6 +88,7 @@ from .. import obs as _obs
 from ..kernels import ops as kops
 from ..kernels import ref as kref
 from ..kernels.bsr_spmm import pair_grid_steps, spmm_block_n
+from . import entries as _entries
 from . import roofline as _roofline
 from . import schedule as _schedule
 from . import steal3d as _steal3d
@@ -1069,6 +1070,35 @@ def _body_summa_ag(a, b, geom: _Geom):
     return c
 
 
+def _entry_ring_c(a, b, geom: _Geom):
+    """Paper Alg 2 with A's tile shipped as its nonzero entries.
+
+    ``a`` holds the device's own tile (``blocks``, ``rows``, ``cols``) and
+    its encoding (``vals``, ``idx``; :mod:`repro.core.entries`).  Each step
+    issues the shift of the encoded tile (with its rows and cols) and of B
+    before the local matmul, then rebuilds the received tile, bit for bit
+    (``kref.entry_decode_ref``, one XLA scatter), for the next step; the
+    encoded form travels on round the ring and is never encoded again.  The g - 1 steps are
+    written out one by one, not scanned: each ends in the decode of the
+    tile just received.  One form serves both ``overlap`` modes: every
+    shift is issued a full local matmul before its tile is used, and at
+    g = 2 the split-step and bulk bodies are the same program.
+    """
+    b = _densify_b(b, geom)
+    wire = {k: a[k] for k in ("vals", "idx", "rows", "cols")}  # float first:
+    tile = {k: a[k] for k in ("blocks", "rows", "cols")}       # one message
+    c = _pvary(jnp.zeros((geom.tm, geom.tn), dtype=geom.out_dtype), geom)
+    for _ in range(geom.g - 1):
+        wire = _tree_ppermute(wire, geom.axc, geom.g)
+        b_n = _tree_ppermute(b, geom.axr, geom.g)
+        c = c + _local_mm(tile, b, geom)
+        tile = {"blocks": kref.entry_decode_ref(wire["vals"], wire["idx"],
+                                                a["blocks"].shape),
+                "rows": wire["rows"], "cols": wire["cols"]}
+        b = b_n
+    return c + _local_mm(tile, b, geom)
+
+
 @register_algorithm("ring_c", a_placement=SKEW_ROWS, b_placement=SKEW_COLS,
                     sparse_body=_sparse_body_ring_c,
                     packed_body=_packed_body_ring_c,
@@ -1076,7 +1106,11 @@ def _body_summa_ag(a, b, geom: _Geom):
                     wire_planner=_wire_planner_ring_c,
                     k_order=lambda i, j, t, g: (i + j + t) % g)
 def _body_ring_c(a, b, geom: _Geom):
-    """Paper Alg 2 (stationary-C): skewed placement + neighbour ppermute."""
+    """Paper Alg 2 (stationary-C): skewed placement + neighbour ppermute.
+    An A tree holding its tile's entries (``"vals"``, ``wire="entries"``)
+    ships them in place of the blocks (:func:`_entry_ring_c`)."""
+    if "vals" in a:
+        return _entry_ring_c(a, b, geom)
     b = _densify_b(b, geom)
     c0 = _pvary(jnp.zeros((geom.tm, geom.tn), dtype=geom.out_dtype), geom)
     n = _ring_steps(geom.g, geom.overlap)
@@ -1535,6 +1569,8 @@ class DistBSR(DistMatrix):
                              f"{tiled.grid_shape}")
         self.tiled = tiled
         self._placed: Dict[str, Dict[str, jnp.ndarray]] = {}
+        self._entry_counts: Optional[np.ndarray] = None
+        self._entry_placed: Dict[tuple, Dict[str, jnp.ndarray]] = {}
 
     @classmethod
     def from_tiled(cls, tiled: TiledBSR, *, balance: str = "none",
@@ -1716,6 +1752,44 @@ class DistBSR(DistMatrix):
             cache[placement] = tree
         if commit is not None:
             tree = cache[placement] = commit(tree)
+        return tree
+
+    def entry_counts(self) -> np.ndarray:
+        """Nonzero entries of each tile, host ``int64[g, g]``: entries whose
+        bit pattern is not zero (``-0.0`` and NaN count).  Counted once per
+        handle, each tile on the device that holds it."""
+        if self._entry_counts is None:
+            blocks = self.tiled.blocks
+            self._entry_counts = _entries.count(blocks, _tile_grid(blocks))
+        return self._entry_counts
+
+    def entry_wire(self, placement: str, cap: int,
+                   commit: Optional[Callable] = None
+                   ) -> Dict[str, jnp.ndarray]:
+        """The tiles as their nonzero entries for a placement: ``{"vals":
+        [g, g, cap], "idx": int32[g, g, cap]}`` (:mod:`repro.core.entries`).
+        Each natural tile is encoded on its own device, then the encodings
+        are placed, so only entries move between devices.  Cached per
+        placement and ``cap``; ``commit`` as in :meth:`placed`."""
+        most = int(self.entry_counts().max())
+        if most > cap:
+            raise ValueError(
+                f"a tile of the left operand holds {most} nonzero entries, "
+                f"more than this plan's {cap}; build a new plan with "
+                "plan_matmul")
+        placement = _canonical_placement(placement, self.g)
+        key = (placement, cap)
+        tree = self._entry_placed.get(key)
+        if tree is None:
+            blocks = self.tiled.blocks
+            # wait for the encoder before any tile moves: its work space
+            # is gone before a device receives a second tile of blocks
+            tree = jax.block_until_ready(
+                _entries.encode(blocks, _tile_grid(blocks), cap))
+            tree = self._entry_placed[key] = _place_tree(tree, placement,
+                                                         self.g)
+        if commit is not None:
+            tree = self._entry_placed[key] = commit(tree)
         return tree
 
     def densify(self) -> jnp.ndarray:
@@ -2236,7 +2310,7 @@ class MatmulPlan:
                  wire_aux: Optional[Dict[str, np.ndarray]] = None,
                  wire_caps: Optional[Dict[str, int]] = None,
                  wire_fps: Optional[Dict[str, str]] = None,
-                 overlap: str = "auto"):
+                 overlap: str = "auto", entry_cap: int = 0):
         self.algorithm = algorithm
         self.geom = geom
         # the overlap mode this plan was built under ("auto"|"on"|"off");
@@ -2264,11 +2338,14 @@ class MatmulPlan:
         self._packs = packs
         self._wire_caps = wire_caps
         self._wire_fps = wire_fps or {}
+        # wire="entries": A's stream ships as this many entries a tile
+        self._entry_cap = entry_cap
         self.traces = 0
         # static-verification memo: modes this plan already passed
         # ("fast"/"full") — revalidating a cached plan is a set lookup
         self._validated: set = set()
-        specs = (_specs_for_keys(_tree_keys(a_key), geom.axr, geom.axc),
+        a_keys = _tree_keys(a_key) + (("vals", "idx") if entry_cap else ())
+        specs = (_specs_for_keys(a_keys, geom.axr, geom.axc),
                  _specs_for_keys(_tree_keys(b_key), geom.axr, geom.axc))
 
         if steal is not None:
@@ -2518,6 +2595,12 @@ class MatmulPlan:
             b_tree = b_h.packed_wire(pl_b, on_mesh) if packed \
                 else {"blocks": b_h.placed(pl_b, on_mesh)["blocks"]}
             return (a_tree, b_tree, self._pairs)
+        if self._entry_cap:
+            # encode before the blocks are placed: the encoder's work
+            # space then sits beside one tile of the device's, not two
+            a_tree = a_h.entry_wire(pl_a, self._entry_cap, on_mesh)
+            return ({**a_tree, **a_h.placed(pl_a, on_mesh)},
+                    b_h.placed(pl_b, on_mesh))
         if packed:
             for who, h in (("a", a_h), ("b", b_h)):
                 if who in self._packs \
@@ -2890,7 +2973,8 @@ def _resolve_wire(wire: str, output: str) -> str:
     wire, so structurally different operands with equal abstract shapes
     keep sharing one cached plan) and resolves to ``"packed"`` for
     sparse-output plans, which are specialized to the operands' structure
-    anyway — there packing is a strict win.
+    anyway — there packing is a strict win.  (A dense-output ``"auto"``
+    plan may still ship A as entries: :func:`_entry_cap_for`.)
     """
     if wire not in ("auto", "padded", "packed"):
         raise ValueError(f"unknown wire {wire!r}; one of "
@@ -2917,6 +3001,23 @@ def _wire_caps_for(a_h: DistMatrix, b_h: DistMatrix,
                 int(counts.max()) if counts.size else 0,
                 h.tiled.store_capacity)
     return caps
+
+
+def _entry_cap_for(alg: Algorithm, a_h: DistMatrix) -> int:
+    """The entry capacity A's stream ships at under ``wire="auto"``, or 0
+    where it ships blocks: the schedule is ``ring_c``, A is block-sparse on
+    a grid of more than one tile, and shipping and decoding its fullest
+    tile's nonzero entries (counted on the devices,
+    :meth:`DistBSR.entry_counts`) beats the exposed shift of its blocks
+    (:func:`repro.core.entries.entries_win`)."""
+    if alg.body is not _body_ring_c or not isinstance(a_h, DistBSR) \
+            or a_h.g < 2:
+        return 0
+    cap = _entries.entry_capacity(a_h.entry_counts().max())
+    t = a_h.tiled
+    win = _entries.entries_win(cap, t.store_capacity, t.block_size,
+                               jnp.dtype(t.dtype).itemsize)
+    return cap if win else 0
 
 
 def _b_pack_wins(b_h: DistMatrix) -> bool:
@@ -3065,7 +3166,14 @@ def _plan_matmul_impl(a, b, *, algorithm: str = "ring_c", mesh=None,
     operands with equal abstract shapes keep sharing one cached plan.
     Packed plans join the cache keyed on the packed operands' structure
     fingerprints; a schedule with no packable traffic for these operands
-    (e.g. ``ring_a`` with a dense B) degrades to its padded plan.
+    (e.g. ``ring_a`` with a dense B) degrades to its padded plan.  Under
+    ``"auto"``, a dense-output ``ring_c`` plan on g > 1 ships A's tile as
+    its nonzero entries (``plan.wire == "entries"``,
+    :mod:`repro.core.entries`) when shipping and decoding A's fullest
+    tile's entries beats the exposed shift of its blocks (below about
+    0.6% of a float32 tile's positions); such a plan joins the cache
+    keyed on the entry capacity and refuses a left operand with more
+    entries in a tile.
 
     ``overlap`` selects the schedule bodies' dependence structure:
     ``"auto"`` (default) and ``"on"`` build the split-step
@@ -3128,6 +3236,7 @@ def _plan_matmul_impl(a, b, *, algorithm: str = "ring_c", mesh=None,
             output = "dense"
     requested = algorithm
     auto_scores = None
+    wire_request = wire
     wire = _resolve_wire(wire, output)
     if wire == "packed" and not (isinstance(a_h, DistBSR)
                                  or isinstance(b_h, DistBSR)):
@@ -3167,6 +3276,10 @@ def _plan_matmul_impl(a, b, *, algorithm: str = "ring_c", mesh=None,
                 packs = tuple(t for t in packs if t != "b")
         if not packs:
             wire = "padded"
+    entry_cap = _entry_cap_for(alg, a_h) \
+        if wire_request == "auto" and sym is None else 0
+    if entry_cap:
+        wire = "entries"
     mesh = _prep_mesh(mesh, a_h.g, axis_row, axis_col)
     key = (alg.name, impl, axis_row, axis_col, allow_pad, overlap,
            _mesh_key(mesh), a_h.abstract_key(), b_h.abstract_key())
@@ -3183,6 +3296,9 @@ def _plan_matmul_impl(a, b, *, algorithm: str = "ring_c", mesh=None,
         # consume maps / remapped pair lists are baked per structure
         key += ("wire-packed",) + tuple(
             (a_h if t == "a" else b_h).structure_key() for t in packs)
+    if entry_cap:
+        # the executable's shapes hold the entry capacity, not a structure
+        key += ("wire-entries", entry_cap)
     if cache:
         plan = _PLAN_CACHE.get(key)
         if plan is not None:
@@ -3230,7 +3346,8 @@ def _plan_matmul_impl(a, b, *, algorithm: str = "ring_c", mesh=None,
                           auto_scores=auto_scores, symbolic=sym,
                           steal=steal, wire=wire, packs=packs,
                           wire_aux=wire_aux, wire_caps=wire_caps,
-                          wire_fps=wire_fps, overlap=overlap)
+                          wire_fps=wire_fps, overlap=overlap,
+                          entry_cap=entry_cap)
     if sym is None:
         _set_dense_output_gauges(plan, a_h, wire_aux)
     plan.validate(validate, a_h, b_h)
@@ -3259,7 +3376,13 @@ def _set_dense_output_gauges(plan: MatmulPlan, a_h: DistMatrix,
     makes of it, g - 1 for a ring shift or an all-gather, g hops for
     ``ring_a``'s partial C, 2 (g - 1) for ``summa_bcast``'s psum
     broadcasts (what a ring all-reduce sends); a steal3d plan's own count
-    of its gathers, moves and reductions."""
+    of its gathers, moves and reductions.  Under ``wire="entries"`` A's
+    tile is its entries (a value and an int32 position each) with its rows
+    and cols;
+
+    ``plan.wire_entries`` (labelled ``stream="a"`` too), the entries A's
+    stream ships out of one device a product: g - 1 shifts of the entry
+    capacity under ``wire="entries"``, 0 where it ships blocks."""
     alg, geom, g = plan.algorithm, plan.geom, plan.geom.g
     steps = real = 0
     if alg.static_planner is None and isinstance(a_h, DistBSR):
@@ -3278,6 +3401,11 @@ def _set_dense_output_gauges(plan: MatmulPlan, a_h: DistMatrix,
     else:
         tiles, _ = _dense_output_tiles(geom, plan._a_key, plan._b_key,
                                        plan._wire_caps or {})
+        if plan._entry_cap:
+            store = plan._a_key[4] + geom.a_nbr
+            tiles["a"] = _entries.entry_bytes(
+                plan._entry_cap, np.dtype(_key_dtype(plan._a_key)).itemsize) \
+                + store * 2 * 4
         per = 2 * (g - 1) if alg.style == "bsp" \
             and not alg.wire_amortized else g - 1
         # a tile named twice rides both ways round the ring, which at
@@ -3289,6 +3417,8 @@ def _set_dense_output_gauges(plan: MatmulPlan, a_h: DistMatrix,
     reg.gauge("plan.spmm_block_steps", **labels).set(steps)
     reg.gauge("plan.spmm_real_blocks", **labels).set(real)
     reg.gauge("plan.wire_bytes", **labels).set(float(sent))
+    reg.gauge("plan.wire_entries", stream="a", **labels).set(
+        (g - 1) * plan._entry_cap)
 
 
 def plan_matmul(a, b, **kw) -> MatmulPlan:

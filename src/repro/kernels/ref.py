@@ -14,6 +14,7 @@ __all__ = [
     "bsr_pair_matmul_raw_ref",
     "bsr_pair_accumulate_raw_ref",
     "densify_raw",
+    "entry_decode_ref",
 ]
 
 
@@ -93,3 +94,14 @@ def densify_raw(blocks, rows, cols, n_block_rows: int, n_block_cols: int):
     out = jnp.zeros((n_block_rows, n_block_cols, bs, bs), blocks.dtype)
     out = out.at[rows, cols].add(blocks)
     return out.transpose(0, 2, 1, 3).reshape(n_block_rows * bs, n_block_cols * bs)
+
+
+def entry_decode_ref(vals, idx, shape):
+    """The ``shape`` (``[slots, bs, bs]``) tile whose entries are ``vals``
+    at flat positions ``idx`` (ascending, distinct): zeros with each entry
+    written at its position, one scatter; entries positioned past the tile
+    are dropped."""
+    size = shape[0] * shape[1] * shape[2]
+    flat = jnp.zeros(size, vals.dtype).at[idx].set(
+        vals, indices_are_sorted=True, unique_indices=True, mode="drop")
+    return flat.reshape(shape)

@@ -386,17 +386,35 @@ b_h = api.DistDense.for_rhs(b, a)
 per_byte = {"collective-permute": 1.0, "all-gather": g - 1.0,
             "all-reduce": 2.0 * (g - 1) / g}
 out = {}
+
+
+def sent_and_gauge(plan, a, b_h):
+    stats = analyze_hlo(plan.lower(a, b_h).compile().as_text())
+    sent = sum(v * per_byte[k]
+               for k, v in stats.collective_bytes.items() if v)
+    label = f"algorithm={plan.algorithm.name},wire={plan.wire}"
+    return [sent, obs.registry().snapshot()["plan.wire_bytes"][label]]
+
+
 for alg in api.algorithms():
     for wire in ("padded", "packed"):
         for overlap in ("off", "on"):
             plan = api.plan_matmul(a, b_h, algorithm=alg, impl="ref",
                                    wire=wire, overlap=overlap, cache=False)
-            stats = analyze_hlo(plan.lower(a, b_h).compile().as_text())
-            sent = sum(v * per_byte[k]
-                       for k, v in stats.collective_bytes.items() if v)
-            label = f"algorithm={alg},wire={plan.wire}"
-            gauge = obs.registry().snapshot()["plan.wire_bytes"][label]
-            out[f"{alg}.{wire}.{overlap}"] = [sent, gauge]
+            out[f"{alg}.{wire}.{overlap}"] = sent_and_gauge(plan, a, b_h)
+# ring_c's A stream as its nonzero entries, on the default wire: an A of
+# 32 x 32 blocks, one or two entries in each stored block
+n_e = 256 * g
+d_e = np.zeros((n_e, n_e), np.float32)
+d_e[np.arange(0, n_e, 7), (np.arange(0, n_e, 7) * 13) % n_e] = 1.0
+a_e = api.DistBSR.from_dense(d_e, g=g, block_size=32)
+b_e = api.DistDense.for_rhs(
+    np.random.default_rng(0).standard_normal((n_e, 64)).astype(np.float32),
+    a_e)
+for overlap in ("off", "on"):
+    plan = api.plan_matmul(a_e, b_e, algorithm="ring_c", impl="ref",
+                           overlap=overlap, cache=False)
+    out[f"ring_c.{plan.wire}.{overlap}"] = sent_and_gauge(plan, a_e, b_e)
 print(json.dumps(out))
 """
 
@@ -406,7 +424,8 @@ def test_wire_gauge_is_what_the_compiled_program_sends(g):
     """``plan.wire_bytes`` equals the bytes of the compiled program's
     collectives, loops counted by their trips, for every dense-output
     schedule, wire and overlap: the ring bodies send g - 1 shifts a
-    stream, none that no step consumes."""
+    stream, none that no step consumes; ``ring_c``'s A stream shipped as
+    its entries sends their values and positions with rows and cols."""
     env = dict(os.environ, PYTHONPATH=str(pathlib.Path(__file__).resolve()
                                           .parents[1] / "src"))
     env.pop("XLA_FLAGS", None)
@@ -415,6 +434,7 @@ def test_wire_gauge_is_what_the_compiled_program_sends(g):
                           timeout=600)
     assert proc.returncode == 0, proc.stderr[-4000:]
     out = json.loads(proc.stdout.strip().splitlines()[-1])
-    assert len(out) == 24
+    assert len(out) == 26
+    assert {"ring_c.entries.off", "ring_c.entries.on"} <= set(out)
     for key, (sent, gauge) in out.items():
         assert sent > 0 and gauge == pytest.approx(sent, rel=1e-12), key

@@ -3,7 +3,8 @@
 Interpret mode never checks TPU tiling or SMEM limits; the TPU compiler
 (installed here, no chip needed) does.  Each test compiles one kernel at
 the shapes ``chip_smoke.py`` runs (R-MAT scale 15, 128x128 blocks) for a
-v5e chip and checks that the Pallas kernel reached the compiled program.
+v5e chip and checks that the Pallas kernel reached the compiled program;
+one compiles the 2x2 SpMM cell's whole product for the described 2x2.
 The topology is described inside a fixture, so only the worker that runs
 this file loads the TPU compiler.
 """
@@ -25,9 +26,9 @@ STORED = 38_500             # 38,244 stored blocks + one coverage block per row
 
 
 @pytest.fixture(scope="module")
-def one_chip():
-    """One chip of a described v5e:2x2, with the persistent compilation
-    cache off (a compile for a described chip cannot be read back)."""
+def topo():
+    """A described v5e:2x2, with the persistent compilation cache off (a
+    compile for a described chip cannot be read back)."""
     from jax.experimental import topologies
     from jax.experimental.compilation_cache import compilation_cache
     old_log = os.environ.get("TPU_LOG_DIR")
@@ -41,12 +42,18 @@ def one_chip():
                                                 topology_name="v5e:2x2")
         except Exception as e:      # noqa: BLE001 (no TPU compiler here)
             pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
-        yield SingleDeviceSharding(topo.devices[0])
+        yield topo
     finally:
         jax.config.update("jax_enable_compilation_cache", old_cache)
         compilation_cache.reset_cache()
         if old_log is None:
             os.environ.pop("TPU_LOG_DIR", None)
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    """One chip of the described v5e:2x2."""
+    return SingleDeviceSharding(topo.devices[0])
 
 
 def _sds(shape, dtype, sharding):
@@ -171,3 +178,79 @@ def test_pair_accumulate_compiles_with_the_group_it_reports(one_chip):
     assert bsr_spmm.pair_grid_steps(pairs, BS, jnp.float32) == \
         3 * -(-chunk // group)
     assert "tpu_custom_call" in jax.jit(run).lower(*args).compile().as_text()
+
+
+def test_entry_ring_sends_what_its_gauge_counts(topo):
+    """The 2x2 SpMM cell's product (scale-16 R-MAT, 39,740 stored slots a
+    tile, B 512 wide) with ``ring_c``'s A stream shipped as entries, at the
+    capacity of its fullest tile (287,175 nonzeros), compiled for a
+    described v5e 2x2: its collective-permutes send ``plan.wire_bytes``,
+    under a fiftieth of what the block stream sends; the received tile is
+    rebuilt by one XLA scatter in the block stream's receive buffer, and
+    the temp grows by B's received tile, which leaves VMEM."""
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    from repro import obs
+    from repro.core import api, entries
+    from repro.launch.hlo_analysis import analyze_hlo
+
+    mesh = Mesh(np.array(topo.devices).reshape(2, 2), ("row", "col"))
+    n, w, cap = 1 << 16, 512, 39_484
+    store = cap + n // 2 // BS
+    a_key = ("bsr", (n, n), (2, 2), BS, cap, "float32")
+    b_key = ("dense", (n, w), 2, "float32")
+    geom = api._Geom(g=2, tm=n // 2, tn=w // 2, a_nbr=n // 2 // BS,
+                     b_nbr=0, b_nbc=0, impl="pallas", axr="row", axc="col",
+                     out_dtype=jnp.float32, overlap=True)
+
+    def sds(shape, dtype, spec):
+        return jax.ShapeDtypeStruct(shape, dtype,
+                                    sharding=NamedSharding(mesh, spec))
+
+    ecap = entries.entry_capacity(287_175)
+    assert entries.entries_win(ecap, store, BS, 4)
+    sent, temp = {}, {}
+    for wire, entry_cap in (("padded", 0), ("entries", ecap)):
+        plan = api.MatmulPlan(api.REGISTRY.get("ring_c"), geom, mesh, a_key,
+                              b_key, wire=wire, entry_cap=entry_cap)
+        a = {"blocks": sds((2, 2, store, BS, BS), jnp.float32,
+                           P("row", "col", None, None, None)),
+             "rows": sds((2, 2, store), jnp.int32, P("row", "col", None)),
+             "cols": sds((2, 2, store), jnp.int32, P("row", "col", None))}
+        if entry_cap:
+            a["vals"] = sds((2, 2, entry_cap), jnp.float32,
+                            P("row", "col", None))
+            a["idx"] = sds((2, 2, entry_cap), jnp.int32,
+                           P("row", "col", None))
+        b = {"dense": sds((n, w), jnp.float32, P("row", "col"))}
+        compiled = plan._exec.lower(a, b).compile()
+        text = compiled.as_text()
+        temp[wire] = compiled.memory_analysis().temp_size_in_bytes
+        sent[wire] = analyze_hlo(text).collective_bytes["collective-permute"]
+        api._set_dense_output_gauges(plan, None, None)
+        gauge = obs.registry().snapshot()["plan.wire_bytes"][
+            f"algorithm=ring_c,wire={wire}"]
+        assert gauge == sent[wire], wire
+    assert sent["padded"] == 2_638_272_992
+    assert sent["entries"] == 36_225_848 < sent["padded"] / 50
+    # the decoded tile takes the received blocks' place; the scatter moves
+    # B's received tile (32 MiB) from VMEM into HBM temp, and no more
+    b_tile = n // 2 * (w // 2) * 4
+    assert temp["entries"] - temp["padded"] < b_tile + (1 << 20)
+
+
+@pytest.mark.parametrize("cap", [294_187, 3_700_000])
+def test_entry_encoder_work_space_stays_small(one_chip, cap):
+    """The encoder of one tile of the 2x2 cell's shape (39,740 stored
+    128 x 128 float32 slots), compiled for a v5e, at the cell's entry
+    capacity and at one near the fullest tile the rule still ships as
+    entries: it places its entries a chunk at a time, so its temp stays
+    a few chunks' worth (under 128 MiB) beside a 2.6 GB tile, however many
+    entries there are."""
+    from repro.core import entries
+
+    slots = 39_740
+    assert entries.entries_win(cap, slots, BS, 4)
+    blocks = _sds((1, 1, slots, BS, BS), jnp.float32, one_chip)
+    compiled = entries._encode.lower(blocks, None, cap).compile()
+    assert compiled.memory_analysis().temp_size_in_bytes < 128 << 20
