@@ -73,9 +73,10 @@ def _perm_problems(perm, g: int) -> List[str]:
 _RING_SIGNS = {"ring_c": (1,), "ring_a": (1,), "ring_c_bidir": (1, -1)}
 
 
-def check_perms(plan) -> List[Finding]:
-    """schedule.ppermute-bijection over every perm the plan's body uses."""
-    from repro.core import api as _api
+def plan_perms(plan) -> List[Tuple[str, tuple]]:
+    """Every ppermute permutation the plan's body uses, labelled: the
+    ring hops of the ring schedules and steal3d's rounds."""
+    from repro.core import schedules as _schedules
     g = plan.geom.g
     perms: List[Tuple[str, tuple]] = []
     if plan.steal is not None:
@@ -85,13 +86,18 @@ def check_perms(plan) -> List[Finding]:
                              ("col_reduce", sp.col_deltas)):
             for delta in deltas:
                 perms.append((f"steal3d {what} delta={delta}",
-                              _api._steal3d_perm(g, delta)))
+                              _schedules._steal3d_perm(g, delta)))
     for sign in _RING_SIGNS.get(plan.algorithm.name, ()):
         perms.append((f"{plan.algorithm.name} ring sign={sign:+d}",
-                      _api._ring_perm(g, sign)))
+                      _schedules._ring_perm(g, sign)))
+    return perms
+
+
+def check_perms(plan) -> List[Finding]:
+    """schedule.ppermute-bijection over every perm the plan's body uses."""
     findings = []
-    for label, perm in perms:
-        for prob in _perm_problems(perm, g):
+    for label, perm in plan_perms(plan):
+        for prob in _perm_problems(perm, plan.geom.g):
             findings.append(Finding(
                 "schedule.ppermute-bijection",
                 f"{label} permutation {tuple(perm)} {prob}",

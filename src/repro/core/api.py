@@ -13,9 +13,9 @@ TPU analogue of that design:
   skew-cols / stationary-A), so the paper's ``k_offset`` skew is
   materialized at most once per operand and reused across calls.
 * :func:`plan_matmul` -> :class:`MatmulPlan` — precomputes the static
-  :class:`_Geom`, operand pack specs and placement requirements, and holds
-  one jit-compiled ``shard_map`` executable: calling the plan again with the
-  same abstract shapes never re-traces.  ``plan.cost_model()`` exposes the
+  geometry (``schedules._Geom``), operand pack specs and placement
+  requirements, and holds one jit-compiled ``shard_map`` executable:
+  calling the plan again with the same abstract shapes never re-traces.  ``plan.cost_model()`` exposes the
   per-step network volume / flops that feed ``core/roofline.py`` and
   ``core/schedule.py``.
 * :func:`matmul` — one polymorphic entry point dispatching
@@ -25,20 +25,13 @@ TPU analogue of that design:
   output unskew and per-step wire traffic, so new schedules (work-stealing
   layouts, stationary-B, ...) plug in without touching the engine.
 
-The algorithm family (see the body docstrings): ``summa_bcast`` /
-``summa_ag`` are the bulk-synchronous baselines, ``ring_c`` / ``ring_a``
-the RDMA-style stationary-C / stationary-A rings with placement-time
-``k_offset`` skew and prefetch via early ``ppermute``, ``ring_c_bidir`` a
-bidirectional stationary-C ring that splits the output into column
-half-panels circulating in opposite directions (full-duplex links), and
-``steal3d`` the static realization of the paper's SS3.4 locality-aware
-work stealing: a plan-time LPT assignment of the 3D (i, k, j) work grid
-(:mod:`repro.core.steal3d`) executed as per-device pair lists with static
-moved-tile and owner-reduction ppermute rounds.  ``plan_matmul(...,
-algorithm="auto")`` scores every registered schedule with the
-alpha-beta-gamma cost model (:func:`auto_select`) and builds the cheapest
-— the static analogue of Bharadwaj et al.'s observation that the best
-distributed sparse schedule flips with sparsity and aspect ratio.
+The algorithm family (``summa_bcast``, ``summa_ag``, ``ring_c``,
+``ring_a``, ``ring_c_bidir``, ``steal3d``) and its shard_map bodies live
+in :mod:`repro.core.schedules`.  ``plan_matmul(..., algorithm="auto")``
+scores every registered schedule with the alpha-beta-gamma cost model
+(:func:`auto_select`) and builds the cheapest — the static analogue of
+Bharadwaj et al.'s observation that the best distributed sparse schedule
+flips with sparsity and aspect ratio.
 
 SpGEMM additionally supports **sparse outputs** (``output="sparse"`` /
 ``"auto"``): a host-side symbolic phase (:mod:`repro.core.symbolic`,
@@ -61,13 +54,6 @@ cache key); ``wire="auto"`` packs the already-structure-keyed
 sparse-output plans and keeps dense-output plans padded so bucketed
 handles keep sharing cached executables.
 
-Two hot-loop invariants the bodies maintain (asserted by the jaxpr test in
-``tests/test_api.py``): sparse A tiles arrive *pre-augmented* from
-:class:`~repro.core.bsr.TiledBSR` (no coverage concat+sort inside the
-scanned step), and sparse B tiles never scatter inside the scan — padded
-plans densify once per ring pass before the scan (``_densify_b``), packed
-plans densify per step by a static *gather* (``ops.densify_packed``).
-
 The legacy free functions in ``core/spmm.py`` remain as deprecated shims
 delegating to the shared plan cache here.
 """
@@ -81,22 +67,21 @@ from typing import Callable, Dict, List, Optional, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax import lax
 from jax.sharding import PartitionSpec as P
 
 from .. import obs as _obs
-from ..kernels import ops as kops
-from ..kernels import ref as kref
 from ..kernels.bsr_spmm import pair_grid_steps, spmm_block_n
 from . import entries as _entries
 from . import roofline as _roofline
 from . import schedule as _schedule
+from . import schedules as _schedules
 from . import steal3d as _steal3d
 from . import symbolic as _symbolic
 from . import wire as _wire
 from .bsr import TiledBSR
 from .dist import make_grid_mesh, tile_mesh, unskew_c_rows
 from .grid import ProcessGrid, bucket_capacity, ceil_div, pad_to_multiple
+from .schedules import _Geom
 from .symbolic import (SymbolicProduct, predicted_density,  # re-export
                        symbolic_spgemm)                     # (public)
 from .wire import PackedOperand, wire_capacity              # re-export
@@ -121,86 +106,6 @@ SKEW_ROWS = "skew_rows"        # position (i, j) holds tile (i, (i+j)%g)
 SKEW_COLS = "skew_cols"        # position (i, j) holds tile ((i+j)%g, j)
 STATIONARY_A = "stationary_a"  # position (i, j) holds tile (j, (i+j)%g)
 PLACEMENTS = (NATURAL, SKEW_ROWS, SKEW_COLS, STATIONARY_A)
-
-
-@dataclasses.dataclass(frozen=True)
-class _Geom:
-    """Static geometry threaded to the shard_map bodies via closure."""
-    g: int
-    tm: int           # local C tile rows
-    tn: int           # local C tile cols
-    a_nbr: int        # block-rows per A tile (0 => dense A)
-    b_nbr: int        # block-rows per B tile (0 => dense B)
-    b_nbc: int        # block-cols per B tile (0 => dense B)
-    impl: Optional[str]
-    axr: str
-    axc: str
-    out_dtype: object
-    c_store: int = 0  # packed C slots per tile (sparse-output plans only)
-    overlap: bool = False
-    # split-step double-buffered bodies (plan_matmul(overlap=...)): each
-    # scanned step issues step t+1's collective BEFORE step t's
-    # accumulate, carrying a two-slot buffer per stream, so XLA's async
-    # collectives can hide the transfer under the local matmul
-
-
-# ---------------------------------------------------------------------------
-# Local tile math (operand trees hold ONLY arrays)
-# ---------------------------------------------------------------------------
-def _densify_b(b: Dict, geom: _Geom) -> Dict:
-    """Densify a sparse B tile ONCE, before the scanned ring steps.
-
-    Every schedule consumes B as a dense tile; doing the scatter here means
-    each B tile is densified at most once per ring pass, and the scanned
-    step body stays free of scatter/sort work (asserted by the jaxpr test).
-    The densified tile is also what rides the wire — see ``_cost_model``.
-    """
-    if "dense" in b:
-        return b
-    return {"dense": kref.densify_raw(b["blocks"], b["rows"], b["cols"],
-                                      geom.b_nbr, geom.b_nbc)}
-
-
-def _local_mm(a: Dict, b: Dict, geom: _Geom) -> jnp.ndarray:
-    b_dense = b["dense"]    # bodies pre-densify sparse B via _densify_b
-    if "dense" in a:
-        out = jnp.dot(a["dense"], b_dense, preferred_element_type=jnp.float32)
-    else:
-        # TiledBSR tiles are pre-augmented/pre-sorted at tiling time, so the
-        # kernel wrapper must not redo coverage inside the compiled loop.
-        out = kops.bsr_spmm_raw(a["blocks"], a["rows"], a["cols"], b_dense,
-                                n_block_rows=geom.a_nbr, impl=geom.impl,
-                                augment=False)
-    return out.astype(geom.out_dtype)
-
-
-def _tree_ppermute(tree: Dict, axis: str, g: int, sign: int = 1) -> Dict:
-    perm = [((d + sign) % g, d) for d in range(g)]
-    return {k: lax.ppermute(v, axis, perm) for k, v in tree.items()}
-
-
-def _ring_steps(g: int, overlap: bool) -> int:
-    """Scanned steps of a ring body: every step but those after the last
-    transfer (one, or two with the two-slot buffer), which run as the
-    epilogue, so no step sends a tile that none consumes."""
-    return max(g - 2, 0) if overlap else g - 1
-
-
-def _split_steps(xs: Dict, n: int, g: int) -> Tuple[Dict, list]:
-    """Per-step maps ``xs`` (leading axis g) split into the scanned steps'
-    ``[:n]`` and one dict per epilogue step ``n .. g-1``."""
-    return ({k: v[:n] for k, v in xs.items()},
-            [{k: v[t] for k, v in xs.items()} for t in range(n, g)])
-
-
-def _tree_bcast(tree: Dict, axis: str, root, my_idx) -> Dict:
-    sel = my_idx == root
-    return {k: lax.psum(jnp.where(sel, v, jnp.zeros_like(v)), axis)
-            for k, v in tree.items()}
-
-
-def _pvary(x, geom: _Geom):
-    return lax.pcast(x, (geom.axr, geom.axc), to="varying")
 
 
 # ---------------------------------------------------------------------------
@@ -405,10 +310,6 @@ def invalidate_plans(*, algorithm: Optional[str] = None,
     return evicted
 
 
-def _evict_plans_for_algorithm(name: str) -> None:
-    invalidate_plans(algorithm=name)
-
-
 @dataclasses.dataclass(frozen=True)
 class Algorithm:
     """A registered schedule: shard_map body + declarative placement needs.
@@ -483,13 +384,13 @@ class AlgorithmRegistry:
         if alg.name in self._algorithms:
             if not overwrite:
                 raise ValueError(f"algorithm {alg.name!r} already registered")
-            _evict_plans_for_algorithm(alg.name)
+            invalidate_plans(algorithm=alg.name)
         self._algorithms[alg.name] = alg
         return alg
 
     def unregister(self, name: str) -> None:
         if self._algorithms.pop(name, None) is not None:
-            _evict_plans_for_algorithm(name)
+            invalidate_plans(algorithm=name)
 
     def get(self, name: str) -> Algorithm:
         try:
@@ -514,32 +415,13 @@ class AlgorithmRegistry:
 REGISTRY = AlgorithmRegistry()
 
 
-def register_algorithm(name: str, *, a_placement: str = NATURAL,
-                       b_placement: str = NATURAL,
-                       unskew_out: Optional[str] = None,
-                       wire: Tuple[str, ...] = ("a", "b"),
-                       wire_amortized: bool = False, style: str = "rdma",
-                       duplex: int = 1, msgs_per_step: Optional[int] = None,
-                       sparse_body: Optional[Callable] = None,
-                       k_order: Optional[Callable] = None,
-                       balance_axis: str = "rows",
-                       static_planner: Optional[Callable] = None,
-                       cost_fn: Optional[Callable] = None,
-                       packed_body: Optional[Callable] = None,
-                       packable: Tuple[str, ...] = (),
-                       wire_planner: Optional[Callable] = None,
-                       registry: AlgorithmRegistry = REGISTRY):
-    """Decorator registering a shard_map body as a named algorithm."""
+def register_algorithm(name: str, *,
+                       registry: AlgorithmRegistry = REGISTRY, **fields):
+    """Decorator registering a shard_map body as a named algorithm;
+    ``fields`` are :class:`Algorithm`'s (its defaults hold for the rest,
+    and a field it lacks raises ``TypeError``)."""
     def deco(body):
-        registry.register(Algorithm(
-            name=name, body=body, a_placement=a_placement,
-            b_placement=b_placement, unskew_out=unskew_out, wire=wire,
-            wire_amortized=wire_amortized, style=style, duplex=duplex,
-            msgs_per_step=msgs_per_step, sparse_body=sparse_body,
-            k_order=k_order, balance_axis=balance_axis,
-            static_planner=static_planner, cost_fn=cost_fn,
-            packed_body=packed_body, packable=packable,
-            wire_planner=wire_planner))
+        registry.register(Algorithm(name=name, body=body, **fields))
         return body
     return deco
 
@@ -567,701 +449,80 @@ def recommended_balance(algorithm: str) -> str:
     return REGISTRY.get(algorithm).balance_axis
 
 
-# ---------------------------------------------------------------------------
-# Sparse-output bodies (packed SpGEMM; see plan_matmul(output="sparse"))
-# ---------------------------------------------------------------------------
-# The numeric phase of symbolic/numeric SpGEMM: both operands stay in their
-# stored block form (only ``blocks`` rides the wire — the pair lists encode
-# all structure, so rows/cols never leave the host), and each step
-# scatter-accumulates matched block products into the packed output slots
-# allocated by the symbolic phase.  No dense C tile, and no B densification,
-# ever materializes on a device.
-def _sparse_step(a_t: Dict, b_t: Dict, pa, pb, ps, geom: _Geom):
-    return kops.bsr_pair_accumulate(
-        a_t["blocks"], b_t["blocks"], pa, pb, ps, n_slots=geom.c_store,
-        out_dtype=jnp.float32, impl=geom.impl)
-
-
-def _sparse_c0(a: Dict, geom: _Geom):
-    bs = a["blocks"].shape[-1]
-    return _pvary(jnp.zeros((geom.c_store, bs, bs), jnp.float32), geom)
-
-
-def _sparse_body_summa_bcast(a, b, pairs, geom: _Geom):
-    """Bulk-synchronous SUMMA with packed sparse output."""
-    my_row = lax.axis_index(geom.axr)
-    my_col = lax.axis_index(geom.axc)
-    if geom.overlap:
-        # split-step (see _body_summa_bcast): step t carries inner step
-        # t's panels and pairs while broadcasting step t+1's panels.
-        a_c = _tree_bcast(a, geom.axc, jnp.int32(0), my_col)
-        b_c = _tree_bcast(b, geom.axr, jnp.int32(0), my_row)
-
-        def step(carry, xs):
-            a_k, b_k, c = carry
-            k, pa, pb, ps = xs
-            a_n = _tree_bcast(a, geom.axc, k, my_col)
-            b_n = _tree_bcast(b, geom.axr, k, my_row)
-            c = c + _sparse_step(a_k, b_k, pa, pb, ps, geom)
-            return (a_n, b_n, c), None
-
-        (a_l, b_l, c), _ = lax.scan(
-            step, (a_c, b_c, _sparse_c0(a, geom)),
-            (jnp.arange(1, geom.g), pairs["pa"][:-1], pairs["pb"][:-1],
-             pairs["ps"][:-1]))
-        c = c + _sparse_step(a_l, b_l, pairs["pa"][-1], pairs["pb"][-1],
-                             pairs["ps"][-1], geom)
-        return c.astype(geom.out_dtype)
-
-    def step(c, xs):
-        k, pa, pb, ps = xs
-        a_k = _tree_bcast(a, geom.axc, k, my_col)
-        b_k = _tree_bcast(b, geom.axr, k, my_row)
-        return c + _sparse_step(a_k, b_k, pa, pb, ps, geom), None
-
-    c, _ = lax.scan(step, _sparse_c0(a, geom),
-                    (jnp.arange(geom.g), pairs["pa"], pairs["pb"],
-                     pairs["ps"]))
-    return c.astype(geom.out_dtype)
-
-
-def _sparse_body_summa_ag(a, b, pairs, geom: _Geom):
-    """All-gather SUMMA with packed sparse output."""
-    a_g = {k: lax.all_gather(v, geom.axc) for k, v in a.items()}
-    b_g = {k: lax.all_gather(v, geom.axr) for k, v in b.items()}
-
-    def step(c, xs):
-        k, pa, pb, ps = xs
-        a_k = {kk: v[k] for kk, v in a_g.items()}
-        b_k = {kk: v[k] for kk, v in b_g.items()}
-        return c + _sparse_step(a_k, b_k, pa, pb, ps, geom), None
-
-    c, _ = lax.scan(step, _sparse_c0(a, geom),
-                    (jnp.arange(geom.g), pairs["pa"], pairs["pb"],
-                     pairs["ps"]))
-    return c.astype(geom.out_dtype)
-
-
-def _sparse_body_ring_c(a, b, pairs, geom: _Geom):
-    """Stationary-C ring with packed sparse output.
-
-    Same skewed placement and prefetch structure as ``ring_c``; B rides the
-    ring in stored block form (its densified tile never exists), and the
-    scanned step consumes the step-scheduled pair lists as scan inputs.
-    """
-    def mm(c, a_t, b_t, xs):
-        return c + _sparse_step(a_t, b_t, xs["pa"], xs["pb"], xs["ps"], geom)
-
-    # pair list t pairs with the tile of generation t; the steps after the
-    # last transfer take theirs in the epilogue
-    xs, tail = _split_steps(pairs, _ring_steps(geom.g, geom.overlap), geom.g)
-    if geom.overlap:
-        # two-slot double buffer (see _body_ring_c)
-        a_f = _tree_ppermute(a, geom.axc, geom.g)
-        b_f = _tree_ppermute(b, geom.axr, geom.g)
-
-        def step(carry, xs):
-            a_t, b_t, a_f, b_f, c = carry
-            a_n = _tree_ppermute(a_f, geom.axc, geom.g)
-            b_n = _tree_ppermute(b_f, geom.axr, geom.g)
-            return (a_f, b_f, a_n, b_n, mm(c, a_t, b_t, xs)), None
-
-        (a_t, b_t, a_f, b_f, c), _ = lax.scan(
-            step, (a, b, a_f, b_f, _sparse_c0(a, geom)), xs)
-        for (a_t, b_t), x in zip(((a_t, b_t), (a_f, b_f)), tail):
-            c = mm(c, a_t, b_t, x)
-        return c.astype(geom.out_dtype)
-
-    def step(carry, xs):
-        a_t, b_t, c = carry
-        a_n = _tree_ppermute(a_t, geom.axc, geom.g)   # prefetch (paper SS3.3)
-        b_n = _tree_ppermute(b_t, geom.axr, geom.g)
-        return (a_n, b_n, mm(c, a_t, b_t, xs)), None
-
-    (a_t, b_t, c), _ = lax.scan(step, (a, b, _sparse_c0(a, geom)), xs)
-    return mm(c, a_t, b_t, tail[0]).astype(geom.out_dtype)
-
-
-# ---------------------------------------------------------------------------
-# Packed-wire dense-output bodies (plan_matmul(wire="packed"))
-# ---------------------------------------------------------------------------
-# The packed variants ship ONLY real blocks: a sparse A tile rides as a
-# packed [wire_capacity, bs, bs] buffer (no coverage blocks, no rows/cols
-# index traffic) and a sparse B tile likewise, densified on the consumer
-# by a static *gather* (ops.densify_packed) instead of the pre-scan
-# scatter.  All structure lives in plan-time consume maps (repro.core.wire)
-# riding as scan inputs — per-device local data, never on the network —
-# so the scanned steps stay sort/scatter-free (the jaxpr invariant).
-def _ring_perm(g: int, sign: int = 1):
-    return [((d + sign) % g, d) for d in range(g)]
-
-
-def _packed_a_mm(a_blocks, gidx, rows, cols, b_dense, geom: _Geom):
-    """One packed local SpMM step: gather the coverage-augmented block
-    list out of the packed buffer, then the standard augment-free kernel."""
-    return kops.bsr_spmm_raw(a_blocks[gidx], rows, cols, b_dense,
-                             n_block_rows=geom.a_nbr, impl=geom.impl,
-                             augment=False).astype(geom.out_dtype)
-
-
-def _packed_b_dense(b_buf, dmap, geom: _Geom):
-    return kops.densify_packed(b_buf, dmap, n_block_rows=geom.b_nbr,
-                               n_block_cols=geom.b_nbc)
-
-
-def _packed_body_ring_c(a, b, aux, geom: _Geom):
-    """Stationary-C ring over packed wire buffers (paper Alg 2)."""
-    b_packed = "b_dmap" in aux
-    b0 = b["blocks"] if b_packed else _densify_b(b, geom)["dense"]
-    xs = {"ag": aux["a_gidx"], "ar": aux["a_rows"], "ac": aux["a_cols"]}
-    if b_packed:
-        xs["bd"] = aux["b_dmap"]
-    c0 = _pvary(jnp.zeros((geom.tm, geom.tn), dtype=geom.out_dtype), geom)
-
-    def mm(c, a_blk, b_buf, xs):
-        b_dense = _packed_b_dense(b_buf, xs["bd"], geom) if b_packed \
-            else b_buf
-        return c + _packed_a_mm(a_blk, xs["ag"], xs["ar"], xs["ac"],
-                                b_dense, geom)
-
-    # consume maps for step t pair with tile generation t; the steps after
-    # the last transfer take theirs in the epilogue
-    xs, tail = _split_steps(xs, _ring_steps(geom.g, geom.overlap), geom.g)
-    if geom.overlap:
-        # two-slot double buffer (see _body_ring_c)
-        a_f = lax.ppermute(a["blocks"], geom.axc, _ring_perm(geom.g))
-        b_f = lax.ppermute(b0, geom.axr, _ring_perm(geom.g))
-
-        def step(carry, xs):
-            a_blk, b_buf, a_f, b_f, c = carry
-            a_n = lax.ppermute(a_f, geom.axc, _ring_perm(geom.g))
-            b_n = lax.ppermute(b_f, geom.axr, _ring_perm(geom.g))
-            return (a_f, b_f, a_n, b_n, mm(c, a_blk, b_buf, xs)), None
-
-        (a_l, b_l, a_f, b_f, c), _ = lax.scan(
-            step, (a["blocks"], b0, a_f, b_f, c0), xs)
-        for (a_t, b_t), x in zip(((a_l, b_l), (a_f, b_f)), tail):
-            c = mm(c, a_t, b_t, x)
-        return c
-
-    def step(carry, xs):
-        a_blk, b_buf, c = carry
-        a_n = lax.ppermute(a_blk, geom.axc, _ring_perm(geom.g))  # prefetch
-        b_n = lax.ppermute(b_buf, geom.axr, _ring_perm(geom.g))
-        return (a_n, b_n, mm(c, a_blk, b_buf, xs)), None
-
-    (a_l, b_l, c), _ = lax.scan(step, (a["blocks"], b0, c0), xs)
-    return mm(c, a_l, b_l, tail[0])
-
-
-def _packed_body_ring_c_bidir(a, b, aux, geom: _Geom):
-    """Bidirectional stationary-C ring, A packed in both directions.
-
-    B's column half-panels are not block-aligned (tn // 2 need not be a
-    block multiple), so B rides densified as in the padded body; only the
-    A streams — the bidir schedule's doubled wire term — pack.
-    """
-    b = _densify_b(b, geom)
-    half = geom.tn // 2
-    b_fwd, b_bwd = b["dense"][:, :half], b["dense"][:, half:]
-    xs = {"fg": aux["a_gidx"], "fr": aux["a_rows"], "fc": aux["a_cols"],
-          "bg": aux["a_gidx_bwd"], "br": aux["a_rows_bwd"],
-          "bc": aux["a_cols_bwd"]}
-    c_l0 = _pvary(jnp.zeros((geom.tm, half), dtype=geom.out_dtype), geom)
-    c_r0 = _pvary(jnp.zeros((geom.tm, geom.tn - half),
-                            dtype=geom.out_dtype), geom)
-
-    def mm(c_l, c_r, a_f, a_b, b_f, b_b, xs):
-        return (c_l + _packed_a_mm(a_f, xs["fg"], xs["fr"], xs["fc"], b_f,
-                                   geom),
-                c_r + _packed_a_mm(a_b, xs["bg"], xs["br"], xs["bc"], b_b,
-                                   geom))
-
-    # consume maps sliced so step t's maps meet tile generation t
-    xs, tail = _split_steps(xs, _ring_steps(geom.g, geom.overlap), geom.g)
-    if geom.overlap:
-        # four streams x two slots (see _body_ring_c_bidir)
-        a_ff = lax.ppermute(a["blocks"], geom.axc, _ring_perm(geom.g, +1))
-        a_bf = lax.ppermute(a["blocks"], geom.axc, _ring_perm(geom.g, -1))
-        b_ff = lax.ppermute(b_fwd, geom.axr, _ring_perm(geom.g, +1))
-        b_bf = lax.ppermute(b_bwd, geom.axr, _ring_perm(geom.g, -1))
-
-        def step(carry, xs):
-            a_f, a_b, b_f, b_b, a_ff, a_bf, b_ff, b_bf, c_l, c_r = carry
-            a_fn = lax.ppermute(a_ff, geom.axc, _ring_perm(geom.g, +1))
-            a_bn = lax.ppermute(a_bf, geom.axc, _ring_perm(geom.g, -1))
-            b_fn = lax.ppermute(b_ff, geom.axr, _ring_perm(geom.g, +1))
-            b_bn = lax.ppermute(b_bf, geom.axr, _ring_perm(geom.g, -1))
-            c_l, c_r = mm(c_l, c_r, a_f, a_b, b_f, b_b, xs)
-            return (a_ff, a_bf, b_ff, b_bf, a_fn, a_bn, b_fn, b_bn,
-                    c_l, c_r), None
-
-        (a_f, a_b, b_f, b_b, a_ff, a_bf, b_ff, b_bf, c_l, c_r), _ = lax.scan(
-            step, (a["blocks"], a["blocks"], b_fwd, b_bwd,
-                   a_ff, a_bf, b_ff, b_bf, c_l0, c_r0), xs)
-        for tiles, x in zip(((a_f, a_b, b_f, b_b), (a_ff, a_bf, b_ff, b_bf)),
-                            tail):
-            c_l, c_r = mm(c_l, c_r, *tiles, x)
-        return jnp.concatenate([c_l, c_r], axis=1)
-
-    def step(carry, xs):
-        a_f, a_b, b_f, b_b, c_l, c_r = carry
-        a_fn = lax.ppermute(a_f, geom.axc, _ring_perm(geom.g, +1))
-        a_bn = lax.ppermute(a_b, geom.axc, _ring_perm(geom.g, -1))
-        b_fn = lax.ppermute(b_f, geom.axr, _ring_perm(geom.g, +1))
-        b_bn = lax.ppermute(b_b, geom.axr, _ring_perm(geom.g, -1))
-        c_l, c_r = mm(c_l, c_r, a_f, a_b, b_f, b_b, xs)
-        return (a_fn, a_bn, b_fn, b_bn, c_l, c_r), None
-
-    (a_f, a_b, b_f, b_b, c_l, c_r), _ = lax.scan(
-        step, (a["blocks"], a["blocks"], b_fwd, b_bwd, c_l0, c_r0), xs)
-    c_l, c_r = mm(c_l, c_r, a_f, a_b, b_f, b_b, tail[0])
-    return jnp.concatenate([c_l, c_r], axis=1)
-
-
-def _packed_body_ring_a(a, b, aux, geom: _Geom):
-    """Stationary-A ring with the sparse B operand packed on the wire.
-
-    A never moves (nothing to pack); the win is B riding as real blocks
-    instead of a densified tile, gather-densified each step.  Partial C
-    tiles still ride back dense — their structure differs per hop (the
-    ROADMAP's sparse-output ring_a item).
-    """
-    acc0 = _pvary(jnp.zeros((geom.tm, geom.tn), dtype=geom.out_dtype), geom)
-
-    def mm_hop(acc, b_blk, bd):
-        # accumulate, then route the partial C tile one hop toward its
-        # owner: g hops in all, the last one home
-        acc = acc + _local_mm(a, {"dense": _packed_b_dense(b_blk, bd, geom)},
-                              geom)
-        return lax.ppermute(acc, geom.axc, _ring_perm(geom.g))
-
-    bds, tail = _split_steps({"bd": aux["b_dmap"]},
-                             _ring_steps(geom.g, geom.overlap), geom.g)
-    if geom.overlap:
-        # B stream two-slot only — the accumulator ring is a serial
-        # dependence chain and cannot be double-buffered (see _body_ring_a)
-        b_f = lax.ppermute(b["blocks"], geom.axr, _ring_perm(geom.g))
-
-        def step(carry, bd):
-            b_blk, b_f, acc = carry
-            b_n = lax.ppermute(b_f, geom.axr, _ring_perm(geom.g))
-            return (b_f, b_n, mm_hop(acc, b_blk, bd)), None
-
-        (b_l, b_f, acc), _ = lax.scan(step, (b["blocks"], b_f, acc0),
-                                      bds["bd"])
-        for b_t, x in zip((b_l, b_f), tail):
-            acc = mm_hop(acc, b_t, x["bd"])
-        return acc
-
-    def step(carry, bd):
-        b_blk, acc = carry
-        b_n = lax.ppermute(b_blk, geom.axr, _ring_perm(geom.g))  # prefetch
-        return (b_n, mm_hop(acc, b_blk, bd)), None
-
-    (b_l, acc), _ = lax.scan(step, (b["blocks"], acc0), bds["bd"])
-    return mm_hop(acc, b_l, tail[0]["bd"])
-
-
-def _packed_body_summa_ag(a, b, aux, geom: _Geom):
-    """All-gather SUMMA over packed panels (per-source packed segments)."""
-    b_packed = "b_dmap" in aux
-    a_pool = lax.all_gather(a["blocks"], geom.axc)   # [g, wc_a, bs, bs]
-    a_flat = a_pool.reshape((-1,) + a_pool.shape[-2:])
-    xs = {"ag": aux["a_gidx"], "ar": aux["a_rows"], "ac": aux["a_cols"]}
-    if b_packed:
-        b_pool = lax.all_gather(b["blocks"], geom.axr)
-        b_flat = b_pool.reshape((-1,) + b_pool.shape[-2:])
-        xs["bd"] = aux["b_dmap"]
-    else:
-        b_g = lax.all_gather(_densify_b(b, geom)["dense"], geom.axr)
-        xs["k"] = jnp.arange(geom.g)
-
-    def step(c, xs):
-        b_dense = _packed_b_dense(b_flat, xs["bd"], geom) if b_packed \
-            else b_g[xs["k"]]
-        c = c + _packed_a_mm(a_flat, xs["ag"], xs["ar"], xs["ac"], b_dense,
-                             geom)
-        return c, None
-
-    c0 = _pvary(jnp.zeros((geom.tm, geom.tn), dtype=geom.out_dtype), geom)
-    c, _ = lax.scan(step, c0, xs)
-    return c
-
-
-def _packed_body_summa_bcast(a, b, aux, geom: _Geom):
-    """Bulk-synchronous SUMMA broadcasting packed buffers per inner step."""
-    b_packed = "b_dmap" in aux
-    my_row = lax.axis_index(geom.axr)
-    my_col = lax.axis_index(geom.axc)
-    b0 = b["blocks"] if b_packed else _densify_b(b, geom)["dense"]
-    xs = {"ag": aux["a_gidx"], "ar": aux["a_rows"], "ac": aux["a_cols"],
-          "k": jnp.arange(geom.g)}
-    if b_packed:
-        xs["bd"] = aux["b_dmap"]
-
-    def bcast(k):
-        a_k = lax.psum(jnp.where(my_col == k, a["blocks"],
-                                 jnp.zeros_like(a["blocks"])), geom.axc)
-        b_k = lax.psum(jnp.where(my_row == k, b0, jnp.zeros_like(b0)),
-                       geom.axr)
-        return a_k, b_k
-
-    c0 = _pvary(jnp.zeros((geom.tm, geom.tn), dtype=geom.out_dtype), geom)
-    if geom.overlap:
-        # split-step (see _body_summa_bcast): broadcast inner step k while
-        # accumulating the carried panels of step k-1
-        last = {k: v[-1] for k, v in xs.items()}
-        xs = {k: v[1:] if k == "k" else v[:-1] for k, v in xs.items()}
-        a_c, b_c = bcast(jnp.int32(0))
-
-        def step(carry, xs):
-            a_k, b_k, c = carry
-            a_n, b_n = bcast(xs["k"])
-            b_dense = _packed_b_dense(b_k, xs["bd"], geom) if b_packed \
-                else b_k
-            c = c + _packed_a_mm(a_k, xs["ag"], xs["ar"], xs["ac"],
-                                 b_dense, geom)
-            return (a_n, b_n, c), None
-
-        (a_l, b_l, c), _ = lax.scan(step, (a_c, b_c, c0), xs)
-        b_dense = _packed_b_dense(b_l, last["bd"], geom) if b_packed else b_l
-        return c + _packed_a_mm(a_l, last["ag"], last["ar"], last["ac"],
-                                b_dense, geom)
-
-    def step(c, xs):
-        a_k, b_k = bcast(xs["k"])
-        b_dense = _packed_b_dense(b_k, xs["bd"], geom) if b_packed else b_k
-        c = c + _packed_a_mm(a_k, xs["ag"], xs["ar"], xs["ac"], b_dense,
-                             geom)
-        return c, None
-
-    c, _ = lax.scan(step, c0, xs)
-    return c
-
-
 # ---- per-schedule wire planners (consume-map construction) ----------------
-def _wire_consume(aux, prefix, po, tiles, bases=None):
-    cons = _wire.schedule_consume(po, tiles, bases)
-    aux[f"{prefix}_gidx"] = cons["gidx"]
-    aux[f"{prefix}_rows"] = cons["rows"]
-    aux[f"{prefix}_cols"] = cons["cols"]
-
-
-def _wire_planner_ring_c(a_po, b_po, geom: _Geom):
-    aux: Dict[str, np.ndarray] = {}
-    if a_po is not None:
-        _wire_consume(aux, "a", a_po, _wire.tiles_ring_c(geom.g))
-    if b_po is not None:
-        aux["b_dmap"] = _wire.schedule_dense_map(
-            b_po, _wire.tiles_ring_c_b(geom.g))
-    return aux
-
-
-def _wire_planner_ring_c_bidir(a_po, b_po, geom: _Geom):
-    aux: Dict[str, np.ndarray] = {}
-    _wire_consume(aux, "a", a_po, _wire.tiles_ring_c(geom.g))
-    cons = _wire.schedule_consume(a_po, _wire.tiles_ring_c_bwd(geom.g))
-    aux["a_gidx_bwd"] = cons["gidx"]
-    aux["a_rows_bwd"] = cons["rows"]
-    aux["a_cols_bwd"] = cons["cols"]
-    return aux
-
-
-def _wire_planner_ring_a(a_po, b_po, geom: _Geom):
-    return {"b_dmap": _wire.schedule_dense_map(
-        b_po, _wire.tiles_ring_a_b(geom.g))}
-
-
 def _summa_bases(g: int, wc: int) -> np.ndarray:
     """Flat base offset of inner step k's tile in an all-gathered pool."""
     return np.broadcast_to(np.arange(g, dtype=np.int64) * wc, (g, g, g))
 
 
-def _wire_planner_summa_ag(a_po, b_po, geom: _Geom):
-    g = geom.g
-    aux: Dict[str, np.ndarray] = {}
-    if a_po is not None:
-        _wire_consume(aux, "a", a_po, _wire.tiles_summa_a(g),
-                      _summa_bases(g, a_po.wire_capacity))
-    if b_po is not None:
-        aux["b_dmap"] = _wire.schedule_dense_map(
-            b_po, _wire.tiles_summa_b(g),
-            _summa_bases(g, b_po.wire_capacity))
-    return aux
+def _wire_planner(tiles_a, tiles_b, pooled: bool = False,
+                  tiles_a_bwd=None) -> Callable:
+    """A schedule's wire planner: the consume maps of a packed A whose
+    tile at each step is ``tiles_a(g)`` (and ``tiles_a_bwd(g)`` on the -1
+    ring of a bidirectional schedule) and the densify maps of a packed B
+    (``tiles_b(g)``); ``pooled`` steps index one all-gathered pool."""
+    def plan(a_po, b_po, geom: _Geom) -> Dict[str, np.ndarray]:
+        g = geom.g
 
+        def bases(po):
+            return _summa_bases(g, po.wire_capacity) if pooled else None
 
-def _wire_planner_summa_bcast(a_po, b_po, geom: _Geom):
-    g = geom.g
-    aux: Dict[str, np.ndarray] = {}
-    if a_po is not None:
-        _wire_consume(aux, "a", a_po, _wire.tiles_summa_a(g))
-    if b_po is not None:
-        aux["b_dmap"] = _wire.schedule_dense_map(b_po,
-                                                 _wire.tiles_summa_b(g))
-    return aux
+        aux: Dict[str, np.ndarray] = {}
+        for tiles, sfx in ((tiles_a, ""), (tiles_a_bwd, "_bwd")):
+            if a_po is not None and tiles is not None:
+                cons = _wire.schedule_consume(a_po, tiles(g), bases(a_po))
+                aux.update({f"a_{k}{sfx}": cons[k]
+                            for k in ("gidx", "rows", "cols")})
+        if b_po is not None:
+            aux["b_dmap"] = _wire.schedule_dense_map(b_po, tiles_b(g),
+                                                     bases(b_po))
+        return aux
+    return plan
 
 
 # ---------------------------------------------------------------------------
-# Algorithm bodies (run inside shard_map on local tile views)
+# The registered schedules (bodies: repro.core.schedules)
 # ---------------------------------------------------------------------------
-@register_algorithm("summa_bcast", style="bsp",
-                    sparse_body=_sparse_body_summa_bcast,
-                    packed_body=_packed_body_summa_bcast,
-                    packable=("a", "b"),
-                    wire_planner=_wire_planner_summa_bcast,
-                    k_order=lambda i, j, t, g: t + 0 * (i + j))
-def _body_summa_bcast(a, b, geom: _Geom):
-    """Bulk-synchronous SUMMA (paper SS2.2): a broadcast per inner step."""
-    b = _densify_b(b, geom)
-    my_row = lax.axis_index(geom.axr)
-    my_col = lax.axis_index(geom.axc)
-    c0 = _pvary(jnp.zeros((geom.tm, geom.tn), dtype=geom.out_dtype), geom)
-    if geom.overlap:
-        # split-step: broadcast inner step k+1 before accumulating step k's
-        # carried panels, so the collective overlaps the local matmul
-        a_c = _tree_bcast(a, geom.axc, jnp.int32(0), my_col)
-        b_c = _tree_bcast(b, geom.axr, jnp.int32(0), my_row)
-
-        def step(carry, k):
-            a_k, b_k, c = carry
-            a_n = _tree_bcast(a, geom.axc, k, my_col)
-            b_n = _tree_bcast(b, geom.axr, k, my_row)
-            c = c + _local_mm(a_k, b_k, geom)
-            return (a_n, b_n, c), None
-
-        (a_l, b_l, c), _ = lax.scan(step, (a_c, b_c, c0),
-                                    jnp.arange(1, geom.g))
-        return c + _local_mm(a_l, b_l, geom)
-
-    def step(c, k):
-        a_k = _tree_bcast(a, geom.axc, k, my_col)  # bcast A[:, k] along rows
-        b_k = _tree_bcast(b, geom.axr, k, my_row)  # bcast B[k, :] along cols
-        return c + _local_mm(a_k, b_k, geom), None
-
-    c, _ = lax.scan(step, c0, jnp.arange(geom.g))
-    return c
-
-
-@register_algorithm("summa_ag", style="bsp", wire_amortized=True,
-                    sparse_body=_sparse_body_summa_ag,
-                    packed_body=_packed_body_summa_ag,
-                    packable=("a", "b"),
-                    wire_planner=_wire_planner_summa_ag,
-                    k_order=lambda i, j, t, g: t + 0 * (i + j))
-def _body_summa_ag(a, b, geom: _Geom):
-    """All-gather SUMMA: one big up-front collective, g x tile footprint.
-
-    No overlap variant: the schedule is wire-amortized — every inner step
-    depends on the single up-front all-gather, so there is no per-step
-    transfer to double-buffer (the gather gates all compute).
-    """
-    b = _densify_b(b, geom)
-    a_g = {k: lax.all_gather(v, geom.axc) for k, v in a.items()}
-    b_g = {k: lax.all_gather(v, geom.axr) for k, v in b.items()}
-
-    def step(c, k):
-        a_k = {kk: v[k] for kk, v in a_g.items()}
-        b_k = {kk: v[k] for kk, v in b_g.items()}
-        return c + _local_mm(a_k, b_k, geom), None
-
-    c0 = _pvary(jnp.zeros((geom.tm, geom.tn), dtype=geom.out_dtype), geom)
-    c, _ = lax.scan(step, c0, jnp.arange(geom.g))
-    return c
-
-
-def _entry_ring_c(a, b, geom: _Geom):
-    """Paper Alg 2 with A's tile shipped as its nonzero entries.
-
-    ``a`` holds the device's own tile (``blocks``, ``rows``, ``cols``) and
-    its encoding (``vals``, ``idx``; :mod:`repro.core.entries`).  Each step
-    issues the shift of the encoded tile (with its rows and cols) and of B
-    before the local matmul, then rebuilds the received tile, bit for bit
-    (``kref.entry_decode_ref``, one XLA scatter), for the next step; the
-    encoded form travels on round the ring and is never encoded again.  The g - 1 steps are
-    written out one by one, not scanned: each ends in the decode of the
-    tile just received.  One form serves both ``overlap`` modes: every
-    shift is issued a full local matmul before its tile is used, and at
-    g = 2 the split-step and bulk bodies are the same program.
-    """
-    b = _densify_b(b, geom)
-    wire = {k: a[k] for k in ("vals", "idx", "rows", "cols")}  # float first:
-    tile = {k: a[k] for k in ("blocks", "rows", "cols")}       # one message
-    c = _pvary(jnp.zeros((geom.tm, geom.tn), dtype=geom.out_dtype), geom)
-    for _ in range(geom.g - 1):
-        wire = _tree_ppermute(wire, geom.axc, geom.g)
-        b_n = _tree_ppermute(b, geom.axr, geom.g)
-        c = c + _local_mm(tile, b, geom)
-        tile = {"blocks": kref.entry_decode_ref(wire["vals"], wire["idx"],
-                                                a["blocks"].shape),
-                "rows": wire["rows"], "cols": wire["cols"]}
-        b = b_n
-    return c + _local_mm(tile, b, geom)
-
-
-@register_algorithm("ring_c", a_placement=SKEW_ROWS, b_placement=SKEW_COLS,
-                    sparse_body=_sparse_body_ring_c,
-                    packed_body=_packed_body_ring_c,
-                    packable=("a", "b"),
-                    wire_planner=_wire_planner_ring_c,
-                    k_order=lambda i, j, t, g: (i + j + t) % g)
-def _body_ring_c(a, b, geom: _Geom):
-    """Paper Alg 2 (stationary-C): skewed placement + neighbour ppermute.
-    An A tree holding its tile's entries (``"vals"``, ``wire="entries"``)
-    ships them in place of the blocks (:func:`_entry_ring_c`)."""
-    if "vals" in a:
-        return _entry_ring_c(a, b, geom)
-    b = _densify_b(b, geom)
-    c0 = _pvary(jnp.zeros((geom.tm, geom.tn), dtype=geom.out_dtype), geom)
-    n = _ring_steps(geom.g, geom.overlap)
-    if geom.overlap:
-        # Split-step double buffer: the carry holds the tile being
-        # consumed AND the tile in flight, so the transfer consumed at
-        # step t+1 was issued at step t-1 — a full local matmul of slack
-        # for the collective-permute DMA.  The prologue issues step 1's
-        # transfer, the scan runs the steps that still have a tile to
-        # issue, and the epilogue accumulates the last two tiles: g-1
-        # permutes per stream, exactly the bulk body's wire traffic.
-        a_f = _tree_ppermute(a, geom.axc, geom.g)
-        b_f = _tree_ppermute(b, geom.axr, geom.g)
-
-        def step(carry, _):
-            a_t, b_t, a_f, b_f, c = carry
-            a_n = _tree_ppermute(a_f, geom.axc, geom.g)   # step t+2's tile
-            b_n = _tree_ppermute(b_f, geom.axr, geom.g)
-            c = c + _local_mm(a_t, b_t, geom)
-            return (a_f, b_f, a_n, b_n, c), None
-
-        (a_t, b_t, a_f, b_f, c), _ = lax.scan(step, (a, b, a_f, b_f, c0),
-                                              None, length=n)
-        c = c + _local_mm(a_t, b_t, geom)
-        return c + _local_mm(a_f, b_f, geom) if geom.g > 1 else c
-
-    def step(carry, _):
-        a_t, b_t, c = carry
-        # "async_get_tile" for step k+1, issued before the local matmul so
-        # the collective-permute DMA overlaps MXU work (paper SS3.3 prefetch).
-        a_n = _tree_ppermute(a_t, geom.axc, geom.g)
-        b_n = _tree_ppermute(b_t, geom.axr, geom.g)
-        c = c + _local_mm(a_t, b_t, geom)
-        return (a_n, b_n, c), None
-
-    # the last step has no tile left to fetch: it runs after the scan
-    (a_t, b_t, c), _ = lax.scan(step, (a, b, c0), None, length=n)
-    return c + _local_mm(a_t, b_t, geom)
-
-
-@register_algorithm("ring_a", b_placement=STATIONARY_A, unskew_out="rows",
-                    wire=("b", "c"), balance_axis="cols",
-                    packed_body=_packed_body_ring_a, packable=("b",),
-                    wire_planner=_wire_planner_ring_a)
-def _body_ring_a(a, b, geom: _Geom):
-    """Paper Alg 1 (stationary-A): B rides the ring, partial C rides back."""
-    b = _densify_b(b, geom)
-    acc0 = _pvary(jnp.zeros((geom.tm, geom.tn), dtype=geom.out_dtype), geom)
-    n = _ring_steps(geom.g, geom.overlap)
-
-    def mm_hop(acc, b_t):
-        # route the partial C tile one hop toward its owner (the TPU
-        # replacement for the paper's remote accumulation queue push):
-        # g hops in all, the last one home
-        acc = acc + _local_mm(a, b_t, geom)
-        return lax.ppermute(acc, geom.axc, _ring_perm(geom.g))
-
-    if geom.overlap:
-        # Only the B stream double-buffers: the partial-C permute depends
-        # on the accumulate it follows (the ride-home chain is inherently
-        # serial), so C's hop count stays g while B's transfers gain a
-        # full matmul of slack.
-        b_f = _tree_ppermute(b, geom.axr, geom.g)
-
-        def step(carry, _):
-            b_t, b_f, acc = carry
-            b_n = _tree_ppermute(b_f, geom.axr, geom.g)
-            return (b_f, b_n, mm_hop(acc, b_t)), None
-
-        (b_t, b_f, acc), _ = lax.scan(step, (b, b_f, acc0), None, length=n)
-        acc = mm_hop(acc, b_t)
-        return mm_hop(acc, b_f) if geom.g > 1 else acc
-
-    def step(carry, _):
-        b_t, acc = carry
-        b_n = _tree_ppermute(b_t, geom.axr, geom.g)   # prefetch next B tile
-        return (b_n, mm_hop(acc, b_t)), None
-
-    (b_t, acc), _ = lax.scan(step, (b, acc0), None, length=n)
-    return mm_hop(acc, b_t)
-
-
-@register_algorithm("ring_c_bidir", a_placement=SKEW_ROWS,
-                    b_placement=SKEW_COLS, wire=("a", "a", "b"), duplex=2,
-                    packed_body=_packed_body_ring_c_bidir, packable=("a",),
-                    wire_planner=_wire_planner_ring_c_bidir,
-                    msgs_per_step=4)   # a_fwd, a_bwd, b_left, b_right
-def _body_ring_c_bidir(a, b, geom: _Geom):
-    """Bidirectional stationary-C ring: C split into column half-panels.
-
-    The left half-panel's operands (the full A tile + the left half of the
-    dense B tile) ride the +1 ring computing ``k = i+j+t``; the right
-    half-panel's ride the -1 ring computing ``k = i+j-t``.  Both start from
-    the same skewed placement as ``ring_c``, so no new placement state is
-    materialized.  The two streams use opposite directions of the
-    full-duplex torus links concurrently, halving B's serialized wire time
-    at the cost of shipping A both ways — a genuinely different
-    comm/compute trade for ``algorithm="auto"`` (wins for sparse-A x wide-B
-    SpMM, loses when A's tile bytes dominate).
-    """
-    b = _densify_b(b, geom)
-    half = geom.tn // 2
-    b_fwd = {"dense": b["dense"][:, :half]}
-    b_bwd = {"dense": b["dense"][:, half:]}
-    c_l0 = _pvary(jnp.zeros((geom.tm, half), dtype=geom.out_dtype), geom)
-    c_r0 = _pvary(jnp.zeros((geom.tm, geom.tn - half), dtype=geom.out_dtype),
-                  geom)
-    n = _ring_steps(geom.g, geom.overlap)
-
-    def mm(c_l, c_r, a_f, a_b, b_f, b_b):
-        return c_l + _local_mm(a_f, b_f, geom), c_r + _local_mm(a_b, b_b, geom)
-
-    if geom.overlap:
-        # four streams, each with a two-slot buffer (see _body_ring_c)
-        a_ff = _tree_ppermute(a, geom.axc, geom.g, +1)
-        a_bf = _tree_ppermute(a, geom.axc, geom.g, -1)
-        b_ff = _tree_ppermute(b_fwd, geom.axr, geom.g, +1)
-        b_bf = _tree_ppermute(b_bwd, geom.axr, geom.g, -1)
-
-        def step(carry, _):
-            a_f, a_b, b_f, b_b, a_ff, a_bf, b_ff, b_bf, c_l, c_r = carry
-            a_fn = _tree_ppermute(a_ff, geom.axc, geom.g, +1)
-            a_bn = _tree_ppermute(a_bf, geom.axc, geom.g, -1)
-            b_fn = _tree_ppermute(b_ff, geom.axr, geom.g, +1)
-            b_bn = _tree_ppermute(b_bf, geom.axr, geom.g, -1)
-            c_l, c_r = mm(c_l, c_r, a_f, a_b, b_f, b_b)
-            return (a_ff, a_bf, b_ff, b_bf, a_fn, a_bn, b_fn, b_bn,
-                    c_l, c_r), None
-
-        (a_f, a_b, b_f, b_b, a_ff, a_bf, b_ff, b_bf, c_l, c_r), _ = lax.scan(
-            step, (a, a, b_fwd, b_bwd, a_ff, a_bf, b_ff, b_bf, c_l0, c_r0),
-            None, length=n)
-        c_l, c_r = mm(c_l, c_r, a_f, a_b, b_f, b_b)
-        if geom.g > 1:
-            c_l, c_r = mm(c_l, c_r, a_ff, a_bf, b_ff, b_bf)
-        return jnp.concatenate([c_l, c_r], axis=1)
-
-    def step(carry, _):
-        a_f, a_b, b_f, b_b, c_l, c_r = carry
-        # prefetch both directions before the local matmuls (paper SS3.3)
-        a_fn = _tree_ppermute(a_f, geom.axc, geom.g, +1)
-        a_bn = _tree_ppermute(a_b, geom.axc, geom.g, -1)
-        b_fn = _tree_ppermute(b_f, geom.axr, geom.g, +1)
-        b_bn = _tree_ppermute(b_b, geom.axr, geom.g, -1)
-        c_l, c_r = mm(c_l, c_r, a_f, a_b, b_f, b_b)
-        return (a_fn, a_bn, b_fn, b_bn, c_l, c_r), None
-
-    (a_f, a_b, b_f, b_b, c_l, c_r), _ = lax.scan(
-        step, (a, a, b_fwd, b_bwd, c_l0, c_r0), None, length=n)
-    c_l, c_r = mm(c_l, c_r, a_f, a_b, b_f, b_b)
-    return jnp.concatenate([c_l, c_r], axis=1)
+register_algorithm("summa_bcast", style="bsp",
+                   sparse_body=_schedules._sparse_body_summa_bcast,
+                   packed_body=_schedules._packed_body_summa_bcast,
+                   packable=("a", "b"),
+                   wire_planner=_wire_planner(_wire.tiles_summa_a,
+                                              _wire.tiles_summa_b),
+                   k_order=lambda i, j, t, g: t + 0 * (i + j),
+                   )(_schedules._body_summa_bcast)
+register_algorithm("summa_ag", style="bsp", wire_amortized=True,
+                   sparse_body=_schedules._sparse_body_summa_ag,
+                   packed_body=_schedules._packed_body_summa_ag,
+                   packable=("a", "b"),
+                   wire_planner=_wire_planner(_wire.tiles_summa_a,
+                                              _wire.tiles_summa_b,
+                                              pooled=True),
+                   k_order=lambda i, j, t, g: t + 0 * (i + j),
+                   )(_schedules._body_summa_ag)
+register_algorithm("ring_c", a_placement=SKEW_ROWS, b_placement=SKEW_COLS,
+                   sparse_body=_schedules._sparse_body_ring_c,
+                   packed_body=_schedules._packed_body_ring_c,
+                   packable=("a", "b"),
+                   wire_planner=_wire_planner(_wire.tiles_ring_c,
+                                              _wire.tiles_ring_c_b),
+                   k_order=lambda i, j, t, g: (i + j + t) % g,
+                   )(_schedules._body_ring_c)
+register_algorithm("ring_a", b_placement=STATIONARY_A, unskew_out="rows",
+                   wire=("b", "c"), balance_axis="cols",
+                   packed_body=_schedules._packed_body_ring_a,
+                   packable=("b",),
+                   wire_planner=_wire_planner(None, _wire.tiles_ring_a_b),
+                   )(_schedules._body_ring_a)
+register_algorithm("ring_c_bidir", a_placement=SKEW_ROWS,
+                   b_placement=SKEW_COLS, wire=("a", "a", "b"), duplex=2,
+                   packed_body=_schedules._packed_body_ring_c_bidir,
+                   packable=("a",),
+                   wire_planner=_wire_planner(
+                       _wire.tiles_ring_c, None,
+                       tiles_a_bwd=_wire.tiles_ring_c_bwd),
+                   msgs_per_step=4,   # a_fwd, a_bwd, b_left, b_right
+                   )(_schedules._body_ring_c_bidir)
 
 
 # ---------------------------------------------------------------------------
@@ -1313,122 +574,9 @@ def _steal3d_cost(alg: "Algorithm", geom: _Geom, a_h: "DistMatrix",
     """
     return dict(_steal_plan_for(a_h, b_h, geom, wire=wire).cost)
 
-
-def _steal3d_perm(g: int, delta: int):
-    return [(d, (d + delta) % g) for d in range(g)]
-
-
-@register_algorithm("steal3d", style="bsp", wire=("a", "b", "c"),
-                    static_planner=_steal_plan_for, cost_fn=_steal3d_cost,
-                    packable=("a",))
-def _body_steal3d(a, b, aux, geom: _Geom, splan: "_steal3d.StealPlan"):
-    """Static realization of the paper's SS3.4 locality-aware work stealing.
-
-    Executes the plan-time LPT assignment of (i, k, j) items: each device
-    all-gathers its A grid-row panel and (densified) B grid-column panel,
-    receives the moved tiles of its off-owner items in static ppermute
-    rounds, runs ONE packed pair-accumulate over its item list (length =
-    the stealing equilibrium's makespan, not the uniform g x capacity of
-    the owner-computes rings), and ships partial C tiles home in static
-    reduce rounds.  No scan: the whole dispatch is one flat program.
-
-    Under ``splan.wire == "packed"`` (sparse A) every A-side shipment
-    carries only real blocks: the panel gather rides at the packed wire
-    capacity, each moved-tile round is sliced to its own per-move real
-    max (the packed prefix makes that a slice, not a gather), and the
-    partial-C reduce rounds ship only the block-rows their items can
-    touch, scatter-added into the owner's tile outside any scan.
-    """
-    g = geom.g
-    packed = splan.wire == "packed"
-    if splan.a_kind == "bsr":
-        a_tiles = lax.all_gather(a["blocks"], geom.axc)  # [g, stride, bs, bs]
-    else:
-        a_tiles = lax.all_gather(a["dense"], geom.axc)   # [g, tm, tk]
-    b_dense = _densify_b(b, geom)["dense"]
-    b_tiles = lax.all_gather(b_dense, geom.axr)          # [g, tk, tn]
-    # moved tiles: one ppermute round per hop distance, source-side static
-    # gather indices select what each source packs (paper's "one moving
-    # tile" for locality-constrained steals).  Issued here, before any
-    # accumulate — on the overlap path (splan.overlap) the own-item
-    # segment depends only on the panel gathers, so these transfers fly
-    # while it computes.
-    if packed:
-        # flat segments: strides differ per round (per-move real max)
-        moved_a = [
-            lax.ppermute(a_tiles[aux[f"amk{delta}"]][:, :rcap], geom.axr,
-                         _steal3d_perm(g, delta))
-            .reshape((-1,) + a_tiles.shape[-2:])
-            for delta, rcap in zip(splan.a_deltas, splan.a_round_cap)]
-    else:
-        moved_a = [lax.ppermute(a_tiles[aux[f"amk{delta}"]], geom.axr,
-                                _steal3d_perm(g, delta))
-                   for delta in splan.a_deltas]
-    moved_b = [lax.ppermute(b_tiles[aux[f"bmk{delta}"]], geom.axc,
-                            _steal3d_perm(g, delta))
-               for delta in splan.b_deltas]
-    if packed:
-        panel_a = a_tiles.reshape((-1,) + a_tiles.shape[-2:])
-        zero_a = _pvary(jnp.zeros((1,) + a_tiles.shape[-2:],
-                                  a_tiles.dtype), geom)
-    else:
-        panel_a = a_tiles
-        zero_a = _pvary(jnp.zeros((1,) + a_tiles.shape[1:],
-                                  a_tiles.dtype), geom)
-    a_pool = jnp.concatenate([panel_a] + moved_a + [zero_a])
-    b_pool = jnp.concatenate([b_tiles] + moved_b) if moved_b else b_tiles
-
-    def _accum(a_p, b_p, pa, pb, ps):
-        if splan.a_kind == "bsr":
-            blocks = a_p if packed else a_p.reshape((-1,) + a_p.shape[-2:])
-            b_flat = b_p.reshape(-1, b_p.shape[-1])
-            cc = kops.steal_pair_accumulate(blocks, b_flat, pa, pb, ps,
-                                            n_slots=splan.n_slots,
-                                            impl=geom.impl)
-            return cc.reshape(splan.n_out, geom.tm, geom.tn)
-        prods = jnp.einsum("pij,pjk->pik", a_p[pa], b_p[pb],
-                           preferred_element_type=jnp.float32)
-        return jax.ops.segment_sum(prods, ps, num_segments=splan.n_out,
-                                   indices_are_sorted=True)
-
-    if splan.overlap:
-        # two-segment split: own items (panel-only pool, zero block right
-        # after the g panel tiles) accumulate while the moved-tile rounds
-        # are in flight; stolen items consume the full pools after
-        a_own = jnp.concatenate([panel_a, zero_a])
-        c = _accum(a_own, b_tiles, aux["pa0"], aux["pb0"], aux["ps0"]) \
-            + _accum(a_pool, b_pool, aux["pa1"], aux["pb1"], aux["ps1"])
-    else:
-        c = _accum(a_pool, b_pool, aux["pa"], aux["pb"], aux["ps"])
-    own = c[0]
-    if packed:
-        # row-packed reduce rounds: ship only the block-rows the sender's
-        # items can touch; receivers scatter-add them home (a dummy target
-        # row absorbs the padding).  This is outside any scan, so the
-        # hot-loop jaxpr invariants are unaffected.
-        nbr, bs = geom.a_nbr, geom.tm // geom.a_nbr
-        c_rows = c.reshape(splan.n_out, nbr, bs, geom.tn)
-        own_ext = jnp.concatenate(
-            [c_rows[0],
-             _pvary(jnp.zeros((1, bs, geom.tn), c.dtype), geom)])
-        for axis, deltas in ((geom.axc, splan.row_deltas),
-                             (geom.axr, splan.col_deltas)):
-            pre = "r" if axis == geom.axc else "c"
-            for delta in deltas:
-                part = c_rows[aux[f"{pre}send{delta}"],
-                              aux[f"{pre}row{delta}"]]
-                part = lax.ppermute(part, axis, _steal3d_perm(g, delta))
-                own_ext = own_ext.at[aux[f"{pre}tgt{delta}"]].add(part)
-        return own_ext[:nbr].reshape(geom.tm, geom.tn).astype(geom.out_dtype)
-    # reduce rounds: partial C tiles ride home to their owners; idle
-    # senders point at the guaranteed-zero dummy slot
-    for delta in splan.row_deltas:
-        part = jnp.take(c, aux[f"rsend{delta}"], axis=0)
-        own = own + lax.ppermute(part, geom.axc, _steal3d_perm(g, delta))
-    for delta in splan.col_deltas:
-        part = jnp.take(c, aux[f"csend{delta}"], axis=0)
-        own = own + lax.ppermute(part, geom.axr, _steal3d_perm(g, delta))
-    return own.astype(geom.out_dtype)
+register_algorithm("steal3d", style="bsp", wire=("a", "b", "c"),
+                   static_planner=_steal_plan_for, cost_fn=_steal3d_cost,
+                   packable=("a",))(_schedules._body_steal3d)
 
 
 # ---------------------------------------------------------------------------
@@ -2098,16 +1246,13 @@ def _tree_keys(abstract_key: tuple) -> Tuple[str, ...]:
         else ("dense",)
 
 
+# the unsharded dims of an operand leaf after its (row, col) grid dims:
+# blocks [bs, bs] after a slot dim, rows/cols/vals/idx a slot dim
+_LEAF_DIMS = {"dense": 0, "blocks": 3}
+
+
 def _specs_for_keys(keys: Tuple[str, ...], axr: str, axc: str) -> Dict:
-    out = {}
-    for k in keys:
-        if k == "dense":
-            out[k] = P(axr, axc)
-        elif k == "blocks":
-            out[k] = P(axr, axc, None, None, None)
-        else:  # rows / cols
-            out[k] = P(axr, axc, None)
-    return out
+    return {k: P(axr, axc, *(None,) * _LEAF_DIMS.get(k, 1)) for k in keys}
 
 
 def _local_view(tree: Dict) -> Dict:
@@ -2347,73 +1492,47 @@ class MatmulPlan:
         a_keys = _tree_keys(a_key) + (("vals", "idx") if entry_cap else ())
         specs = (_specs_for_keys(a_keys, geom.axr, geom.axc),
                  _specs_for_keys(_tree_keys(b_key), geom.axr, geom.axc))
-
-        if steal is not None:
-            # steal3d plan: the executable is specialized to the LPT
-            # assignment — pair lists, move-round gather indices and
-            # reduce-round slot selectors ride as a third operand tree
-            # (committed in their mesh sharding once, like sparse-output
-            # pair lists); only A's block data is sharded in for sparse A.
-            body = algorithm.body
+        blocks_spec = {"blocks": P(geom.axr, geom.axc, None, None, None)}
+        if steal is not None or (symbolic is None and wire_aux is not None):
+            # steal3d and packed-wire dense-output plans: the executable is
+            # specialized to plan-time index arrays riding as a third
+            # operand tree, committed in their mesh sharding once (like
+            # sparse-output pair lists).  steal3d's are the LPT
+            # assignment's pair lists, move-round gather indices and
+            # reduce-round slot selectors, and only A's block data is
+            # sharded in for sparse A.  A packed plan's are the consume
+            # maps (augmented-list gathers / densify-by-gather maps built
+            # by repro.core.wire), and a packed operand ships blocks-only
+            # at the wire capacity.
+            aux = steal.aux if steal is not None else wire_aux
             aux_specs = {k: P(geom.axr, geom.axc, *(None,) * (v.ndim - 2))
-                         for k, v in steal.aux.items()}
+                         for k, v in aux.items()}
             with _obs.span("plan_build.commit"):
                 self._aux = {
                     k: jax.device_put(
                         np.ascontiguousarray(v),
                         jax.sharding.NamedSharding(mesh, aux_specs[k]))
-                    for k, v in steal.aux.items()}
+                    for k, v in aux.items()}
+            if steal is not None:
+                a_blocks, b_blocks = a_key[0] == "bsr", False
+
+                def body(a, b, aux, geom):
+                    return algorithm.body(a, b, aux, geom, steal)
+            else:
+                a_blocks, b_blocks = "a" in packs, "b" in packs
+                body = algorithm.packed_body
 
             def fn(a, b, aux):
-                self.traces += 1          # runs at trace time only
-                for hook in list(_TRACE_HOOKS):
-                    hook(self)
                 return body(_local_view(a), _local_view(b),
-                            {k: v[0, 0] for k, v in aux.items()}, geom,
-                            steal)
+                            {k: v[0, 0] for k, v in aux.items()}, geom)
 
-            a_keys = ("blocks",) if a_key[0] == "bsr" else ("dense",)
-            in_specs = (_specs_for_keys(a_keys, geom.axr, geom.axc),
-                        specs[1], aux_specs)
-            out_specs = P(geom.axr, geom.axc)
-        elif symbolic is None and wire_aux is not None:
-            # Packed-wire dense-output plan: the executable is specialized
-            # to the packed operands' structures — the consume maps
-            # (augmented-list gathers / densify-by-gather maps built by
-            # repro.core.wire) ride as a third operand tree, committed in
-            # their mesh sharding once like steal3d aux; a packed operand
-            # ships blocks-only at the wire capacity.
-            packed_body = algorithm.packed_body
-            aux_specs = {k: P(geom.axr, geom.axc, *(None,) * (v.ndim - 2))
-                         for k, v in wire_aux.items()}
-            with _obs.span("plan_build.commit"):
-                self._aux = {
-                    k: jax.device_put(
-                        np.ascontiguousarray(v),
-                        jax.sharding.NamedSharding(mesh, aux_specs[k]))
-                    for k, v in wire_aux.items()}
-
-            def fn(a, b, aux):
-                self.traces += 1          # runs at trace time only
-                for hook in list(_TRACE_HOOKS):
-                    hook(self)
-                return packed_body(_local_view(a), _local_view(b),
-                                   {k: v[0, 0] for k, v in aux.items()},
-                                   geom)
-
-            blocks_spec = {"blocks": P(geom.axr, geom.axc, None, None,
-                                       None)}
-            in_specs = (blocks_spec if "a" in packs else specs[0],
-                        blocks_spec if "b" in packs else specs[1],
-                        aux_specs)
+            in_specs = (blocks_spec if a_blocks else specs[0],
+                        blocks_spec if b_blocks else specs[1], aux_specs)
             out_specs = P(geom.axr, geom.axc)
         elif symbolic is None:
             body = algorithm.body
 
             def fn(a, b):
-                self.traces += 1          # runs at trace time only
-                for hook in list(_TRACE_HOOKS):
-                    hook(self)
                 return body(_local_view(a), _local_view(b), geom)
 
             in_specs, out_specs = specs, P(geom.axr, geom.axc)
@@ -2462,14 +1581,10 @@ class MatmulPlan:
                     jnp.promote_types(a_key[-1], b_key[-1])))
 
             def fn(a, b, pairs):
-                self.traces += 1          # runs at trace time only
-                for hook in list(_TRACE_HOOKS):
-                    hook(self)
                 c = sparse_body(_local_view(a), _local_view(b),
                                 _local_view(pairs), geom)
                 return c[None, None]      # restore the (1, 1) grid dims
 
-            blocks_spec = {"blocks": P(geom.axr, geom.axc, None, None, None)}
             pair_spec = {k: P(geom.axr, geom.axc, None, None)
                          for k in ("pa", "pb", "ps")}
             in_specs = (blocks_spec, blocks_spec, pair_spec)
@@ -2483,6 +1598,9 @@ class MatmulPlan:
         scope_label = f"plan.{algorithm.name}.{wire}"
 
         def fn(*operands):
+            self.traces += 1          # runs at trace time only
+            for hook in list(_TRACE_HOOKS):
+                hook(self)
             with jax.named_scope(scope_label):
                 return inner_fn(*operands)
 
@@ -2966,7 +2084,8 @@ def _resolve_overlap(overlap: str) -> str:
     return overlap
 
 
-def _resolve_wire(wire: str, output: str) -> str:
+def _resolve_wire(wire: str, output: str, a_h: DistMatrix,
+                  b_h: DistMatrix) -> str:
     """Resolve the ``wire=`` request ("auto" | "padded" | "packed").
 
     ``"auto"`` keeps today's behaviour for dense-output plans (padded
@@ -2979,6 +2098,12 @@ def _resolve_wire(wire: str, output: str) -> str:
     if wire not in ("auto", "padded", "packed"):
         raise ValueError(f"unknown wire {wire!r}; one of "
                          "('auto', 'padded', 'packed')")
+    if wire == "packed" and not (isinstance(a_h, DistBSR)
+                                 or isinstance(b_h, DistBSR)):
+        raise ValueError(
+            "wire='packed' needs at least one block-sparse (DistBSR) "
+            "operand — dense operands have no packable structure; use "
+            "wire='padded'")
     if wire == "auto":
         return "packed" if output == "sparse" else "padded"
     return wire
@@ -3010,8 +2135,8 @@ def _entry_cap_for(alg: Algorithm, a_h: DistMatrix) -> int:
     tile's nonzero entries (counted on the devices,
     :meth:`DistBSR.entry_counts`) beats the exposed shift of its blocks
     (:func:`repro.core.entries.entries_win`)."""
-    if alg.body is not _body_ring_c or not isinstance(a_h, DistBSR) \
-            or a_h.g < 2:
+    if alg.body is not _schedules._body_ring_c \
+            or not isinstance(a_h, DistBSR) or a_h.g < 2:
         return 0
     cap = _entries.entry_capacity(a_h.entry_counts().max())
     t = a_h.tiled
@@ -3073,14 +2198,8 @@ def auto_select(a, b, *, machine: Optional["_roofline.Machine"] = None,
     a_h, b_h = _coerce_pair(a, b, g=g, allow_pad=allow_pad)
     machine = machine or _roofline.TPU_V5E
     registry = registry or REGISTRY
-    wire = _resolve_wire(wire, output)
+    wire = _resolve_wire(wire, output, a_h, b_h)
     overlap = _resolve_overlap(overlap)
-    if wire == "packed" and not (isinstance(a_h, DistBSR)
-                                 or isinstance(b_h, DistBSR)):
-        raise ValueError(
-            "wire='packed' needs at least one block-sparse (DistBSR) "
-            "operand — dense operands have no packable structure; use "
-            "wire='padded'")
     sym = None
     candidates = list(registry)
     if output == "sparse":
@@ -3237,13 +2356,7 @@ def _plan_matmul_impl(a, b, *, algorithm: str = "ring_c", mesh=None,
     requested = algorithm
     auto_scores = None
     wire_request = wire
-    wire = _resolve_wire(wire, output)
-    if wire == "packed" and not (isinstance(a_h, DistBSR)
-                                 or isinstance(b_h, DistBSR)):
-        raise ValueError(
-            "wire='packed' needs at least one block-sparse (DistBSR) "
-            "operand — dense operands have no packable structure; use "
-            "wire='padded'")
+    wire = _resolve_wire(wire, output, a_h, b_h)
     sym = _symbolic_for(a_h, b_h) if output == "sparse" else None
     if algorithm == "auto":
         with _obs.span("plan_build.auto_select"):

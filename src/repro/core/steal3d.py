@@ -193,7 +193,7 @@ def build_steal_plan(a_h, b_h, geom, *, locality: str = "locality",
                      ) -> StealPlan:
     """Compile the stealing equilibrium for ``a_h @ b_h`` into a StealPlan.
 
-    ``geom`` is the plan's :class:`repro.core.api._Geom`; handles are
+    ``geom`` is the plan's :class:`repro.core.schedules._Geom`; handles are
     :class:`DistBSR` / :class:`DistDense` (duck-typed via ``.kind``).
 
     ``wire="packed"`` (sparse A only) builds the packed-wire variant: the

@@ -42,7 +42,7 @@ def main() -> int:
     import jax.numpy as jnp
     import numpy as np
 
-    from repro.core import api
+    from repro.core import api, schedules
     from repro.core.api import DistBSR, DistDense
     from repro.core.bsr import random_sparse
     from repro.core.dist import make_grid_mesh
@@ -333,13 +333,13 @@ def main() -> int:
         # corrupted ring permutation at real grid size
         plan = api.plan_matmul(a_h, b_h, mesh=mesh, algorithm="ring_c",
                                impl="ref", cache=False)
-        orig_perm = api._ring_perm
-        api._ring_perm = lambda gg, sign=1: tuple(
+        orig_perm = schedules._ring_perm
+        schedules._ring_perm = lambda gg, sign=1: tuple(
             ((d + sign) % gg, 0) for d in range(gg))   # all -> device 0
         try:
             fs = analysis.check_plan(plan, a_h, b_h)
         finally:
-            api._ring_perm = orig_perm
+            schedules._ring_perm = orig_perm
         check_flag("analysis/corrupt_perm_caught",
                    any(f.rule == "schedule.ppermute-bijection"
                        for f in fs))

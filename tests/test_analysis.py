@@ -34,7 +34,7 @@ from repro import analysis
 from repro.analysis import source_rules
 from repro.analysis.jaxpr_lint import (check_collective_count,
                                        check_hot_loop, trace_plan)
-from repro.core import api
+from repro.core import api, schedules
 from repro.core import steal3d  # analysis: allow(source.import.repro.core.steal3d)
 from repro.core.api import Algorithm, DistBSR, DistDense, plan_matmul
 from repro.core.bsr import random_sparse
@@ -119,7 +119,7 @@ def test_mutation_invalid_ppermute_perm(operands, monkeypatch):
     a_h, b_h, _ = operands
     plan = plan_matmul(a_h, b_h, algorithm="ring_c", impl="ref",
                        cache=False)
-    monkeypatch.setattr(api, "_ring_perm", lambda g, sign=1: ((0, 1),))
+    monkeypatch.setattr(schedules, "_ring_perm", lambda g, sign=1: ((0, 1),))
     findings = analysis.check_plan(plan, a_h, b_h)
     assert "schedule.ppermute-bijection" in _rules_of(findings)
     msg = str([f for f in findings
@@ -212,13 +212,13 @@ def test_mutation_corrupt_sparse_pair_list(operands):
 
 def _overlap_taint_body(a, b, geom):
     """Broken overlap: computes on the in-flight ppermute output."""
-    bb = api._densify_b(b, geom)
-    acc0 = api._pvary(jnp.zeros((geom.tm, geom.tn), geom.out_dtype), geom)
+    bb = schedules._densify_b(b, geom)
+    acc0 = schedules._pvary(jnp.zeros((geom.tm, geom.tn), geom.out_dtype), geom)
 
     def step(carry, _):
         b_t, acc = carry
-        b_n = api._tree_ppermute(b_t, geom.axr, geom.g)
-        acc = acc + api._local_mm(a, b_n, geom)
+        b_n = schedules._tree_ppermute(b_t, geom.axr, geom.g)
+        acc = acc + schedules._local_mm(a, b_n, geom)
         return (b_n, acc), None
 
     (_, acc), _ = lax.scan(step, (bb, acc0), None, length=geom.g)
@@ -227,13 +227,13 @@ def _overlap_taint_body(a, b, geom):
 
 def _overlap_late_issue_body(a, b, geom):
     """Broken overlap: accumulates before issuing step t+1's transfer."""
-    bb = api._densify_b(b, geom)
-    acc0 = api._pvary(jnp.zeros((geom.tm, geom.tn), geom.out_dtype), geom)
+    bb = schedules._densify_b(b, geom)
+    acc0 = schedules._pvary(jnp.zeros((geom.tm, geom.tn), geom.out_dtype), geom)
 
     def step(carry, _):
         b_t, acc = carry
-        acc = acc + api._local_mm(a, b_t, geom)
-        b_n = api._tree_ppermute(b_t, geom.axr, geom.g)
+        acc = acc + schedules._local_mm(a, b_t, geom)
+        b_n = schedules._tree_ppermute(b_t, geom.axr, geom.g)
         return (b_n, acc), None
 
     (_, acc), _ = lax.scan(step, (bb, acc0), None, length=geom.g)
@@ -313,7 +313,7 @@ def test_validate_failing_plan_raises_and_is_not_cached(operands,
                                                         monkeypatch):
     a_h, b_h, _ = operands
     api.clear_plan_cache()
-    monkeypatch.setattr(api, "_ring_perm", lambda g, sign=1: ((0, 1),))
+    monkeypatch.setattr(schedules, "_ring_perm", lambda g, sign=1: ((0, 1),))
     with pytest.raises(analysis.PlanValidationError) as ei:
         plan_matmul(a_h, b_h, algorithm="ring_c", impl="ref",
                     validate="fast")

@@ -191,7 +191,7 @@ def test_entry_ring_sends_what_its_gauge_counts(topo):
     from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
     from repro import obs
-    from repro.core import api, entries
+    from repro.core import api, entries, schedules
     from repro.launch.hlo_analysis import analyze_hlo
 
     mesh = Mesh(np.array(topo.devices).reshape(2, 2), ("row", "col"))
@@ -199,7 +199,7 @@ def test_entry_ring_sends_what_its_gauge_counts(topo):
     store = cap + n // 2 // BS
     a_key = ("bsr", (n, n), (2, 2), BS, cap, "float32")
     b_key = ("dense", (n, w), 2, "float32")
-    geom = api._Geom(g=2, tm=n // 2, tn=w // 2, a_nbr=n // 2 // BS,
+    geom = schedules._Geom(g=2, tm=n // 2, tn=w // 2, a_nbr=n // 2 // BS,
                      b_nbr=0, b_nbc=0, impl="pallas", axr="row", axc="col",
                      out_dtype=jnp.float32, overlap=True)
 

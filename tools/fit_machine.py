@@ -154,7 +154,7 @@ def _balance_records(payload: Dict) -> List[Dict]:
     (capacity, block size, scale) — no R-MAT rebuild needed."""
     import jax.numpy as jnp
 
-    from repro.core import api
+    from repro.core import api, schedules
 
     section = payload.get("balance_rmat_4x4", {})
     if "balance" not in section:
@@ -168,7 +168,7 @@ def _balance_records(payload: Dict) -> List[Dict]:
         cap = entry["capacity"]
         a_key = ("bsr", (n, n), (g, g), bs, cap, "float32")
         b_key = ("dense", (n, n_cols), g, "float32")
-        geom = api._Geom(g=g, tm=n // g, tn=n_cols // g,
+        geom = schedules._Geom(g=g, tm=n // g, tn=n_cols // g,
                          a_nbr=(n // g) // bs, b_nbr=0, b_nbc=0, impl=None,
                          axr="row", axc="col", out_dtype=jnp.float32)
         for name, metrics in entry["algorithms"].items():
@@ -195,7 +195,7 @@ def _wire_records(payload: Dict) -> List[Dict]:
     """
     import jax.numpy as jnp
 
-    from repro.core import api
+    from repro.core import api, schedules
 
     section = payload.get("wire_rmat_4x4", {})
     algos = section.get("algorithms")
@@ -209,7 +209,7 @@ def _wire_records(payload: Dict) -> List[Dict]:
     wc = section["a_wire_capacity"]
     a_key = ("bsr", (n, n), (g, g), bs, cap, "float32")
     b_key = ("dense", (n, n_cols), g, "float32")
-    geom = api._Geom(g=g, tm=n // g, tn=n_cols // g,
+    geom = schedules._Geom(g=g, tm=n // g, tn=n_cols // g,
                      a_nbr=(n // g) // bs, b_nbr=0, b_nbc=0, impl=None,
                      axr="row", axc="col", out_dtype=jnp.float32)
     out = []
